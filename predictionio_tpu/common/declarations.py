@@ -156,9 +156,11 @@ ENV_VARS: Dict[str, str] = {
         "#12 ranking-parity contract)",
     "PIO_SERVE_FUSED":
         "fused Pallas score->mask->top-k kernel for quantized serving: "
-        "auto (default, TPU backends only) | 1/on (everywhere — "
-        "interpreter mode off-TPU, slow but bit-equivalent) | 0/off "
-        "(the XLA fallback kernel)",
+        "auto (default) and 0/off = the XLA int8 kernel on every "
+        "platform (the TPU compiler refuses the Pallas kernel's block "
+        "shapes) | 1/on = the Pallas kernel (compiled on TPU, where "
+        "the compiler's error propagates; interpreter mode off-TPU, "
+        "slow but bit-equivalent)",
     "PIO_SERVE_FUSED_TILE":
         "item-axis tile of the fused quantized top-k kernel "
         "(default 512 lanes)",
@@ -211,8 +213,11 @@ ENV_VARS: Dict[str, str] = {
     "PIO_AOT_THREADS":
         "AOT prebuild thread-pool width (default 4)",
     "PIO_COMPILE_CACHE_DIR":
-        "persistent XLA compile-cache directory; train exports its new "
-        "entries as a deploy artifact, deploy pre-seeds from it",
+        "where `pio train` snapshots its compile-cache deploy artifact "
+        "from and `pio deploy` pre-seeds it to; also places the "
+        "persistent XLA compile cache when JAX_COMPILATION_CACHE_DIR "
+        "is unset (default <checkout>/.jax_cache) and must agree with "
+        "that variable when it is set",
     "PIO_COMPILE_CACHE_MIN_S":
         "minimum compile seconds before a program is persisted to the "
         "compile cache (default 0)",

@@ -466,7 +466,7 @@ class QuantizedServing:
         vt[:, :n_items] = qf.v_q.T
         sv = np.zeros((n_pad,), dtype=np.float32)
         sv[:n_items] = qf.v_scale
-        return cls(
+        qs = cls(
             u_q=jax.device_put(qf.u_q),
             u_scale=jax.device_put(qf.u_scale),
             vt_q=jax.device_put(vt),
@@ -474,6 +474,14 @@ class QuantizedServing:
             n_users=qf.n_users, n_items=n_items, rank=qf.rank,
             tile=tile, fused=fused, interpret=interpret,
             recall=qf.recall, exact1=qf.exact1)
+        if fused and not interpret:
+            # an explicit PIO_SERVE_FUSED=on on a TPU: compile the
+            # kernel here, so the compiler's verdict fails the deploy
+            # instead of vanishing behind prebuild's "never raises" and
+            # the batcher's per-query fallback
+            jax.device_get(
+                qs.topk(np.zeros(1, np.int32), min(10, n_items)))
+        return qs
 
     def topk(self, user_ixs, k: int):
         ixs = np.asarray(user_ixs, dtype=np.int32)

@@ -24,9 +24,25 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-NEG_INF = jnp.float32(-3.4e38)
+#: a NumPy scalar, not a jnp one: a jnp constant would initialize the
+#: backend — and take the chip — in every process that imports this
+#: module, a daemon that never serves a query included
+NEG_INF = np.float32(-3.4e38)
+
+
+def fp32_matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """``a @ b`` in true float32. A TPU's default matmul precision
+    rounds float32 operands to bfloat16 on the MXU; scores then carry
+    ~1e-3 relative error that DIFFERS between the matvec and the
+    batched programs, so the same user got a different top-k alone and
+    inside a batch (first run on the v5e, PR 25: 3.75763 alone against
+    3.75782 in a bucket of 64, near-tied items re-ranked). A rank-length
+    contraction costs nothing at full precision; on the CPU backend the
+    flag changes nothing."""
+    return jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
 
 
 def stable_topk(scores: jnp.ndarray, k: int
@@ -55,7 +71,7 @@ def topk_scores(
     k: int = 10,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """scores = V @ q with ineligible items masked to -inf; returns (vals, idx)."""
-    scores = item_factors @ query_vec
+    scores = fp32_matmul(item_factors, query_vec)
     if mask is not None:
         scores = jnp.where(mask, scores, NEG_INF)
     return jax.lax.top_k(scores, k)
@@ -69,14 +85,14 @@ def topk_for_user(
     k: int = 10,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fused single-query serve: row gather + matvec + top_k in ONE
-    dispatch, so a remote/tunneled device costs one round-trip per query
+    dispatch, so the device costs one round-trip per query
     instead of four (gather, matmul, and two fetches). `user_ix` must be
     in-bounds — callers resolve it against the model's user vocabulary
     first (an OOB index would gather NaN, KNOWN_ISSUES.md #5).
     Tie-deterministic (stable_topk) so the inline path agrees bit-for-bit
     with the batched and sharded kernels on tied scores."""
     q = jnp.take(user_factors, user_ix, axis=0)
-    return stable_topk(item_factors @ q, k)
+    return stable_topk(fp32_matmul(item_factors, q), k)
 
 
 def host_masked_topk(factors, query_vec, mask, k: int, weights=None):
@@ -135,7 +151,7 @@ def topk_scores_batch(
     k: int = 10,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Batched variant for batchPredict/eval: one (b, r) x (r, n) matmul."""
-    scores = query_vecs @ item_factors.T
+    scores = fp32_matmul(query_vecs, item_factors.T)
     if mask is not None:
         scores = jnp.where(mask, scores, NEG_INF)
     return jax.lax.top_k(scores, k)
@@ -159,7 +175,7 @@ def topk_for_users(
     index — the contract the sharded serving path's cross-shard merge
     (parallel/serve_dist.py) reproduces bit-for-bit."""
     Q = jnp.take(user_factors, user_ixs, axis=0)
-    return stable_topk(Q @ item_factors.T, k)
+    return stable_topk(fp32_matmul(Q, item_factors.T), k)
 
 
 def host_masked_topk_batch(factors, query_vecs, masks, ks, weights=None):
@@ -189,7 +205,7 @@ def cosine_topk(
     """Cosine-similarity top-K (similarproduct template scoring)."""
     qn = query_vec / jnp.maximum(jnp.linalg.norm(query_vec), 1e-12)
     norms = jnp.linalg.norm(item_factors, axis=1)
-    scores = (item_factors @ qn) / jnp.maximum(norms, 1e-12)
+    scores = fp32_matmul(item_factors, qn) / jnp.maximum(norms, 1e-12)
     if mask is not None:
         scores = jnp.where(mask, scores, NEG_INF)
     return jax.lax.top_k(scores, k)

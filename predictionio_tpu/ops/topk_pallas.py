@@ -21,12 +21,19 @@ integer dot products are exact, the rescale is elementwise, and both
 selections realize the same total order. Asserted in tier-1 across
 bucket sizes, k above/below the tile, and constructed score ties.
 
-Platform resolution (``PIO_SERVE_FUSED``): "auto" (default) runs the
-Pallas kernel on TPU backends and the XLA fallback elsewhere; "1"/"on"
-forces the kernel everywhere — off-TPU it runs in ``interpret=True``
-mode, slowly but bit-equivalently, which is how tier-1 exercises the
-exact kernel code path on CPU; "0"/"off" forces the XLA fallback (the
-escape hatch for platforms where Pallas will not lower).
+Platform resolution (``PIO_SERVE_FUSED``): "auto" (default) resolves to
+the XLA int8 kernel (``ops.quant.topk_for_users_quant``) on EVERY
+platform. The TPU compiler refuses this kernel's output block shape at
+every serving bucket — "the last two dimensions of your block shape are
+divisible by 8 and 128 ... block shape (b, 10), array shape (b, 530)",
+the ``out_specs`` below (tests/test_chip_compile.py holds the refusal
+as a strict xfail) — so it is opt-in until a later PR repairs the block
+shapes and can claim something for it. "1"/"on" forces the kernel: on a
+TPU backend it is compiled and the compiler's error propagates (no
+interpret mode, no catch — nothing serves some other way); off-TPU it
+runs in ``interpret=True`` mode, slowly but bit-equivalently, which is
+how tier-1 exercises the exact kernel code path on CPU. "0"/"off"
+forces the XLA kernel (today the same as "auto").
 ``PIO_SERVE_FUSED_TILE`` sets the item-axis tile (default 512 lanes —
 4 x the 128-lane register width, same rationale as the Pallas ALS
 solver's batch tile in ops/solve_pallas.py).
@@ -75,20 +82,15 @@ def fused_mode() -> str:
 
 
 def fused_choice() -> Tuple[bool, bool]:
-    """-> (use_fused, interpret). "auto": the compiled kernel on TPU,
-    the XLA fallback elsewhere; "on": the kernel everywhere, in
-    interpreter mode off-TPU (bit-equivalent, slow — tier-1's CPU
-    coverage of the real kernel body); "off": always the fallback."""
-    mode = fused_mode()
-    if mode == "off":
+    """-> (use_fused, interpret). "auto" and "off": the XLA int8 kernel
+    on every platform (the TPU compiler refuses the Pallas kernel's
+    block shapes — module docstring); "on": the Pallas kernel, compiled
+    on a TPU backend (a compile error propagates) and in interpreter
+    mode elsewhere (bit-equivalent, slow — tier-1's CPU coverage of the
+    real kernel body)."""
+    if fused_mode() != "on":
         return False, False
-    try:
-        on_tpu = jax.default_backend() == "tpu"
-    except Exception:
-        on_tpu = False
-    if mode == "on":
-        return True, not on_tpu
-    return (True, False) if on_tpu else (False, False)
+    return True, jax.default_backend() != "tpu"
 
 
 def _score_mask_topk_kernel(q_ref, su_ref, v_ref, sv_ref,

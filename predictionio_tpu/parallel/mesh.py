@@ -66,20 +66,12 @@ def get_mesh(n_devices: Optional[int] = None,
 
 def shard_map_compat(f: Callable, mesh: Mesh, in_specs: Sequence[Any],
                      out_specs: Any) -> Callable:
-    """``shard_map`` across the jax versions this repo runs on.
-
-    Newer jax exposes ``jax.shard_map`` (replication checking via
-    ``check_vma``); 0.4.x ships it as ``jax.experimental.shard_map``
-    with the ``check_rep`` spelling. Checking is disabled either way:
-    the kernels here use collectives (all_gather/psum) whose replication
-    the checker cannot always infer, exactly why als_dist always ran
-    with ``check_vma=False``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=tuple(in_specs),
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=tuple(in_specs),
-                      out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with replication checking off: the kernels
+    here use collectives (all_gather/psum) whose replication the checker
+    cannot always infer, exactly why als_dist always ran with
+    ``check_vma=False``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=out_specs, check_vma=False)
 
 
 def pad_to_multiple(arr: np.ndarray, multiple: int, pad_value) -> np.ndarray:

@@ -26,8 +26,9 @@ Kernel (one fused device dispatch, same contract as ops.topk.topk_for_users):
      padding buckets carry over unchanged;
   2. local scores: (b, rank) x (rank, rows_dev) against the local item
      shard. The contraction axis (rank) is never split, so every score
-     is the SAME float32 dot product the replicated kernel computes —
-     bit-identical values, not approximately-equal ones;
+     is the same float32 dot product the replicated kernel computes —
+     up to the order in which the compiler adds its rank terms (see
+     Parity);
   3. local top-k: two-key sort by (-score, global index), exactly
      ops.topk.stable_topk's tie rule; padding rows are masked to
      NEG_INF and carry global ids >= n_items so they sort last;
@@ -43,12 +44,19 @@ KNOWN_ISSUES #3) are identical to the replicated path. A host merge would
 put an O(b·k·n_dev log) sort plus a second result reshape on the request
 thread and leak shard-count-dependent shapes into the protocol layer.
 
-Bit parity. For any model, batch, and k, the sharded result (values AND
-indices) is bit-identical to the replicated ``topk_for_users`` — ties
-break by lowest global index on both paths (ops/topk.py stable_topk is
-the shared contract). Asserted by tests/test_serve_dist.py at 1 and 8
-devices, including constructed score ties across shard boundaries, and
-by the multichip harness (__graft_entry__.dryrun_multichip).
+Parity. For any model, batch, and k, the sharded result has the SAME
+RANKING as the replicated ``topk_for_users`` — identical indices, ties
+included: ties break by lowest global index on both paths (ops/topk.py
+stable_topk is the shared contract) — and float32 scores within
+``SCORE_ATOL + SCORE_RTOL * |replicated|`` (1e-5 each). Not bit-identical
+scores: the two are different XLA programs, and the installed compiler
+orders the rank-length sum differently in them (1-3 ULP on the CPU
+backend of jaxlib 0.9; PR 8 claimed bit identity on the jaxlib of its
+day). The int8 layouts ARE bit-identical to each other — integer dot
+products are exact. Asserted by tests/test_serve_dist.py at 1 and 8
+devices, including constructed score ties across shard boundaries, by
+the multichip harness (__graft_entry__.dryrun_multichip), and on four
+real chips by ``chip_smoke.py --chips 4``.
 
 Mode resolution (`pio deploy --shard-serving auto/on/off`, env override
 ``PIO_SERVE_SHARD``): "on" always shards over all visible devices (even a
@@ -78,10 +86,15 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.common import devicewatch, telemetry
-from predictionio_tpu.ops.topk import NEG_INF
+from predictionio_tpu.ops.topk import NEG_INF, fp32_matmul
 from predictionio_tpu.parallel.mesh import shard_map_compat
 
 logger = logging.getLogger("predictionio_tpu.serve_dist")
+
+#: the sharded-vs-replicated score contract (module docstring, "Parity"):
+#: |sharded - replicated| <= SCORE_ATOL + SCORE_RTOL * |replicated|
+SCORE_RTOL = 1e-5
+SCORE_ATOL = 1e-5
 
 #: the merge strategy this module implements (doctor/status surface it)
 MERGE_STRATEGY = "all_gather"
@@ -228,8 +241,9 @@ def topk_for_users_sharded(
     mesh: Mesh,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Row-sharded batched top-k serve over ``mesh``: per-device local
-    top-k + one small all-gather merge; bit-identical (values, indices,
-    tie order) to ops.topk.topk_for_users on the replicated factors.
+    top-k + one small all-gather merge; the same ranking (indices, tie
+    order) as ops.topk.topk_for_users on the replicated factors, scores
+    within SCORE_RTOL/SCORE_ATOL of it (module docstring, "Parity").
     Compiles once per (mesh, shapes, bucket, k) — the AOT enumerator
     (serving/aot.py via ALSAlgorithm.aot_serving_programs) prebuilds
     every (bucket x k) program before /readyz flips ready."""
@@ -248,7 +262,7 @@ def topk_for_users_sharded(
         Q = lax.psum(Q * own[:, None].astype(U_blk.dtype), axis)
         # 2. local scores; the contraction axis (rank) is unsplit, so
         # each score is the same float32 dot product as replicated
-        scores = Q @ V_blk.T                          # (b, rows_dev_i)
+        scores = fp32_matmul(Q, V_blk.T)              # (b, rows_dev_i)
         gid = d * rows_dev_i + lax.broadcasted_iota(
             jnp.int32, (b, rows_dev_i), 1)
         scores = jnp.where(gid < n_items, scores, NEG_INF)

@@ -36,10 +36,15 @@ path:
   Compiled-executable handles are memoized process-wide so a /reload
   onto same-shape factors is instant.
 
-- **The compile cache as a deploy artifact.** ``pio train`` snapshots
-  the persistent compile cache (``.jax_cache``) around the run, AOT-
-  builds the model's serving programs, and exports the new cache
-  entries into the Models store next to the model blob
+- **The compile cache.** Always on, and placed from outside:
+  ``JAX_COMPILATION_CACHE_DIR`` where it is set (jax's own handling;
+  the code sets no directory), else ``<checkout>/.jax_cache``
+  (:func:`persistent_cache_dir`), so train and deploy share one cache.
+
+- **The compile cache as a deploy artifact.** With an explicit
+  ``pio train --compile-cache DIR`` the run snapshots that directory
+  around itself, AOT-builds the model's serving programs, and exports
+  the new cache entries into the Models store next to the model blob
   (workflow/model_io.py). ``pio deploy`` pre-seeds its cache from that
   artifact, so a warm replica's prebuild is a string of cache hits —
   seconds, not minutes. Cache keys include the jaxlib version and
@@ -498,40 +503,64 @@ def enabled(mode: str = "auto") -> bool:
     return mode != "off"
 
 
+#: <checkout>/.jax_cache — the fixed default (bench.py and
+#: diagnostics/ml20m_repro.py use the same path): never a temp name, a
+#: pid or a time, so every process of a checkout finds what the last
+#: one compiled.
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def artifact_cache_dir() -> str:
+    """The directory `pio train` snapshots its compile-cache deploy
+    artifact from: only an explicit ``--compile-cache`` /
+    ``PIO_COMPILE_CACHE_DIR`` — the default cache never starts shipping
+    hundreds of MB with every model."""
+    return os.environ.get("PIO_COMPILE_CACHE_DIR", "")
+
+
+def persistent_cache_dir() -> str:
+    """Where this process keeps jax's persistent compile cache.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: that directory, through jax's own
+    handling of the variable; ``PIO_COMPILE_CACHE_DIR`` then only names
+    where the deploy artifact is snapshotted from and must name the same
+    directory (ValueError otherwise). Not set: ``PIO_COMPILE_CACHE_DIR``
+    if given, else ``<checkout>/.jax_cache``."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    explicit = artifact_cache_dir()
+    if not placed:
+        return explicit or _DEFAULT_CACHE_DIR
+    if explicit and os.path.realpath(explicit) != os.path.realpath(placed):
+        raise ValueError(
+            f"--compile-cache / PIO_COMPILE_CACHE_DIR ({explicit}) "
+            f"disagrees with JAX_COMPILATION_CACHE_DIR ({placed}): the "
+            "compile cache is placed by JAX_COMPILATION_CACHE_DIR when "
+            "it is set, and the deploy artifact is snapshotted from "
+            "that same directory — name it, or drop one of the two")
+    return placed
+
+
 def ensure_persistent_cache() -> str:
-    """Point jax's persistent compile cache at the configured directory
-    (``PIO_COMPILE_CACHE_DIR`` falling back to
-    ``JAX_COMPILATION_CACHE_DIR``); returns the active directory or ""
-    when none is configured. Threshold 0 by default so even fast-
+    """Make sure jax's persistent compile cache is on at
+    :func:`persistent_cache_dir` and return that directory. With
+    ``JAX_COMPILATION_CACHE_DIR`` set jax has configured itself from it
+    and this sets no directory. Threshold 0 by default so even fast-
     compiling serving programs persist (``PIO_COMPILE_CACHE_MIN_S``
-    overrides). Never raises — a broken cache config degrades to lazy
-    in-memory compilation."""
+    overrides)."""
     import jax
-    try:
-        cur = jax.config.jax_compilation_cache_dir
-    except Exception:
-        cur = None
-    if cur:
-        return str(cur)
-    d = (os.environ.get("PIO_COMPILE_CACHE_DIR")
-         or os.environ.get("JAX_COMPILATION_CACHE_DIR") or "")
-    if not d:
-        return ""
-    try:
+    d = persistent_cache_dir()
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs",
+        float(os.environ.get("PIO_COMPILE_CACHE_MIN_S", "0")))
+    if (not os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            and jax.config.jax_compilation_cache_dir != d):
         jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            float(os.environ.get("PIO_COMPILE_CACHE_MIN_S", "0")))
-        # un-latch the cache module: any compile that ran before this
-        # config (e.g. ops/topk's module-level NEG_INF constant) left
-        # it initialized as "disabled"; without a reset the new dir is
-        # silently ignored for the rest of the process
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        logger.warning("could not configure the persistent compile cache "
-                       "at %s; continuing without it", d, exc_info=True)
-        return ""
+        # a compile that ran before this (a library user who trained in
+        # this process) latched the cache as "not in use"; un-latch it
+        from jax.experimental.compilation_cache import compilation_cache
+        compilation_cache.reset_cache()
     return d
 
 

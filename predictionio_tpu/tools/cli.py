@@ -132,8 +132,8 @@ def cmd_train(args) -> int:
     _apply_read_env(args)
     _apply_telemetry_env(args)
     if getattr(args, "compile_cache", ""):
-        # persistent compile cache: the run's new entries export with
-        # the model as a deploy artifact (serving/aot.py)
+        # the run's new compile-cache entries export with the model as
+        # a deploy artifact, snapshotted from here (serving/aot.py)
         os.environ["PIO_COMPILE_CACHE_DIR"] = args.compile_cache
     if getattr(args, "no_auto_resume", False):
         # disable the crashed-run checkpoint scan (workflow/core_workflow)
@@ -887,9 +887,13 @@ def build_parser() -> argparse.ArgumentParser:
                     help="seed for --synthetic (default 7; sets "
                          "PIO_SYNTHETIC_SEED)")
     sp.add_argument("--compile-cache", default="",
-                    help="persistent XLA compile-cache directory; the "
-                         "run's new entries export with the model as a "
-                         "deploy artifact (sets PIO_COMPILE_CACHE_DIR)")
+                    help="export the run's new compile-cache entries "
+                         "with the model as a deploy artifact, "
+                         "snapshotted from this directory (sets "
+                         "PIO_COMPILE_CACHE_DIR; must agree with "
+                         "JAX_COMPILATION_CACHE_DIR where that is set "
+                         "— the cache itself defaults to "
+                         "<checkout>/.jax_cache)")
     telemetry_flags(sp)
 
     sp = sub.add_parser("eval", help="run an evaluation")
@@ -937,7 +941,9 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--compile-cache", default="",
                     help="persistent XLA compile-cache directory to "
                          "pre-seed from the model's exported cache "
-                         "artifact (sets PIO_COMPILE_CACHE_DIR)")
+                         "artifact (sets PIO_COMPILE_CACHE_DIR; must "
+                         "agree with JAX_COMPILATION_CACHE_DIR where "
+                         "that is set)")
     sp.add_argument("--waterfall", action="store_true",
                     help="sample per-request latency waterfalls "
                          "(pio_serve_stage_seconds + /debug/slow.json; "

@@ -98,11 +98,14 @@ def run_train(
     from predictionio_tpu.common import devicewatch
     from predictionio_tpu.serving import aot
     devicewatch.install()
-    # compile-cache-as-artifact (serving/aot.py): when a persistent
-    # cache dir is configured, snapshot it now — every entry this run
-    # adds (trainer programs + the model's AOT-built serving programs)
-    # exports with the model so `pio deploy` pre-seeds a warm cache
-    cache_dir = aot.ensure_persistent_cache()
+    # the persistent compile cache is always on (JAX_COMPILATION_CACHE_DIR
+    # or <checkout>/.jax_cache). Compile-cache-as-artifact (serving/
+    # aot.py) stays tied to an explicit --compile-cache: snapshot that
+    # directory now — every entry this run adds (trainer programs + the
+    # model's AOT-built serving programs) exports with the model so
+    # `pio deploy` pre-seeds a warm cache
+    aot.ensure_persistent_cache()
+    cache_dir = aot.artifact_cache_dir()
     cache_before = (model_io.cache_snapshot(cache_dir)
                     if cache_dir else None)
     if jax.process_count() > 1:
@@ -205,8 +208,8 @@ def run_train(
         if cache_dir and os.environ.get("PIO_AOT", "") != "0":
             # AOT-build the model's serving programs from declared
             # shapes and export the run's compile-cache delta as the
-            # instance's deploy artifact (serving/aot.py). Only with a
-            # persistent cache configured — the built executables ARE
+            # instance's deploy artifact (serving/aot.py). Only with an
+            # explicit --compile-cache — the built executables ARE
             # the artifact's payload. Best-effort by contract:
             # export_train_artifact never raises, so a broken cache dir
             # cannot fail a finished training.

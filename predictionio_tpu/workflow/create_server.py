@@ -800,16 +800,15 @@ class QueryAPI:
             return None, None
         cache_dir = aot.ensure_persistent_cache()
         cache_import = None
-        if cache_dir:
-            artifact = self.storage.get_model_data_models().get(
-                model_io.cache_artifact_id(instance.id))
-            if artifact is not None:
-                cache_import = model_io.import_compile_cache(
-                    artifact.models, cache_dir)
-                if cache_import.get("reason"):
-                    logger.warning("compile-cache artifact for %s not "
-                                   "imported: %s", instance.id,
-                                   cache_import["reason"])
+        artifact = self.storage.get_model_data_models().get(
+            model_io.cache_artifact_id(instance.id))
+        if artifact is not None:
+            cache_import = model_io.import_compile_cache(
+                artifact.models, cache_dir)
+            if cache_import.get("reason"):
+                logger.warning("compile-cache artifact for %s not "
+                               "imported: %s", instance.id,
+                               cache_import["reason"])
         # this set is handed to the batcher, whose flush-scoped
         # installation makes every predict_batch pad onto exactly the
         # programs built below
@@ -870,7 +869,7 @@ class QueryAPI:
                 supplemented = [serving.supplement(q) for q in queries]
             # the batched device dispatch (ends in a real host transfer —
             # jax.device_get of the top-k — per KNOWN_ISSUES #3, so the
-            # span duration is honest on tunneled platforms). Waterfall:
+            # span duration is honest on every backend). Waterfall:
             # `dispatch` is the whole predict_batch; the algorithm
             # refines it with nested pad/execute stages.
             with tracing.span("dispatch", service="query-server"):
@@ -1372,7 +1371,7 @@ class QueryAPI:
         if telemetry.on():
             # end-to-end serve latency (parse -> batched/inline predict ->
             # serialize); the predict path ends in a host transfer, so
-            # this histogram is honest on tunneled devices (issue #3)
+            # this histogram is honest on every backend (issue #3)
             telemetry.registry().histogram(
                 "pio_serve_seconds",
                 "POST /queries.json end-to-end serve latency",

@@ -312,6 +312,35 @@ def test_hybrid_kernel_matches_csrb(implicit, monkeypatch):
         assert abs(r1 - r2) < 0.01 * max(r1, 1e-6)
 
 
+def test_hybrid_kernel_counts_repeated_pairs(monkeypatch):
+    """A (user, item) pair may repeat — a re-rating in the event log;
+    thousands of times for the heaviest pairs `pio train --synthetic`
+    draws. The dense-hot block must count every repeat: accumulated in
+    bf16 a running count stops at 256 while the rating sum keeps
+    growing, and the hybrid model of such data came out worse than the
+    global mean (training RMSE 1.54 against 1.03 at 20 M events)."""
+    from predictionio_tpu.data import synthetic
+
+    monkeypatch.setenv("PIO_ALS_HOT_K", "64")
+    n_u, n_i = 300, 200
+    src = synthetic.chunk_source(300_000, seed=7, n_users=n_u, n_items=n_i)
+    ui, ii, vals = src.chunk_codes(0)
+    pair = ui.astype(np.int64) * n_i + ii
+    assert np.bincount(pair).max() > 1000       # far past bf16's 256
+    data = als.prepare_ratings(ui, ii, vals, n_u, n_i, chunk=1 << 14)
+
+    def rmse(kernel):
+        U, V = map(np.asarray, als.train_explicit(
+            data, rank=6, iterations=4, lambda_=0.01, seed=3,
+            chunk=1 << 14, kernel=kernel))
+        pred = np.einsum("nr,nr->n", U[ui], V[ii])
+        return float(np.sqrt(np.mean((pred - vals) ** 2)))
+
+    exact, hybrid = rmse("csrb"), rmse("hybrid")
+    assert exact < float(vals.std())            # beats the global mean
+    assert abs(hybrid - exact) < 0.01 * exact
+
+
 def test_hybrid_small_item_set_falls_back(monkeypatch):
     """n_items < 2K: hybrid silently uses the csrb path (bit-identical)."""
     monkeypatch.setenv("PIO_ALS_HOT_K", "4096")

@@ -690,5 +690,101 @@ def test_time_to_ready_gauge_exported(memory_storage):
         api.close()
 
 
+# ---------------------------------------------------------------------------
+# the compile cache is placed from outside (PR 25)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture()
+def cache_spy(monkeypatch):
+    """Record what ensure_persistent_cache asks of jax, change nothing."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    calls = {"update": [], "reset": 0}
+    # an earlier run_train in this process may have placed the default
+    real_update, prev = jax.config.update, jax.config.jax_compilation_cache_dir
+    real_update("jax_compilation_cache_dir", None)
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls["update"].append(
+                            (name, value)))
+    monkeypatch.setattr(compilation_cache, "reset_cache",
+                        lambda: calls.__setitem__(
+                            "reset", calls["reset"] + 1))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("PIO_COMPILE_CACHE_DIR", raising=False)
+    yield calls
+    real_update("jax_compilation_cache_dir", prev)
+
+
+def test_cache_dir_from_jax_env_sets_no_directory(cache_spy, monkeypatch,
+                                                 tmp_path):
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    assert aot.ensure_persistent_cache() == placed
+    assert "jax_compilation_cache_dir" not in dict(cache_spy["update"])
+    assert cache_spy["reset"] == 0
+    # --compile-cache naming the same directory is fine, and is what
+    # the train artifact is then snapshotted from
+    monkeypatch.setenv("PIO_COMPILE_CACHE_DIR", placed + os.sep)
+    assert aot.ensure_persistent_cache() == placed
+    assert aot.artifact_cache_dir() == placed + os.sep
+    assert "jax_compilation_cache_dir" not in dict(cache_spy["update"])
+
+
+def test_cache_dir_disagreement_is_refused(cache_spy, monkeypatch,
+                                           tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "a"))
+    monkeypatch.setenv("PIO_COMPILE_CACHE_DIR", str(tmp_path / "b"))
+    with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+        aot.ensure_persistent_cache()
+    assert cache_spy["update"] == []
+
+
+@pytest.mark.parametrize("explicit", [False, True])
+def test_cache_dir_default_is_the_checkout(cache_spy, monkeypatch,
+                                          tmp_path, explicit):
+    """Neither variable set: <checkout>/.jax_cache — a fixed path, never
+    a temp name; an explicit --compile-cache places it instead."""
+    want = os.path.join(os.path.dirname(PKG), ".jax_cache")
+    if explicit:
+        want = str(tmp_path / "explicit")
+        monkeypatch.setenv("PIO_COMPILE_CACHE_DIR", want)
+    assert aot.ensure_persistent_cache() == want
+    assert dict(cache_spy["update"])["jax_compilation_cache_dir"] == want
+    assert cache_spy["reset"] == 1
+    assert aot.artifact_cache_dir() == (want if explicit else "")
+
+
+def test_default_cache_ships_no_artifact(memory_storage, monkeypatch):
+    """The train-time export of cache entries stays tied to an explicit
+    --compile-cache: a default cache does not start shipping hundreds
+    of MB with every model."""
+    monkeypatch.delenv("PIO_COMPILE_CACHE_DIR", raising=False)
+    _engine, iid = _train_engine(memory_storage)
+    assert memory_storage.get_model_data_models().get(
+        model_io.cache_artifact_id(iid)) is None
+
+
+def test_importing_the_package_initialises_no_backend():
+    """A process that imports any module of the package — a daemon that
+    never serves a query included — must not take the chip: no
+    import-time jnp constant, jax.devices() or jit call."""
+    import subprocess
+    import sys
+    code = (
+        "import importlib, pkgutil, predictionio_tpu\n"
+        "from jax._src import xla_bridge\n"
+        "for m in pkgutil.walk_packages(predictionio_tpu.__path__,\n"
+        "                               'predictionio_tpu.'):\n"
+        "    if m.name.endswith('._pio_native'):\n"
+        "        continue\n"
+        "    importlib.import_module(m.name)\n"
+        "    assert not xla_bridge._backends, m.name\n")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": os.path.dirname(PKG)}
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
 if __name__ == "__main__":
     pytest.main([__file__, "-q"])

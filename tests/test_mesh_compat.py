@@ -1,11 +1,11 @@
-"""`parallel/mesh.py::shard_map_compat` across both API spellings.
+"""`parallel/mesh.py::shard_map_compat` on the one installation.
 
-The shim picked up 29 tests in PR 8 by accepting whichever shard_map
-the running jax exposes — `jax.shard_map` (newer, `check_vma=`) or
-`jax.experimental.shard_map.shard_map` (0.4.x, `check_rep=`). Only the
-spelling the installed jax happens to ship was ever exercised; here the
-OTHER branch is forced via import-shim monkeypatching so a jax upgrade
-(or downgrade) can't silently break the path nobody ran.
+The shim once accepted whichever shard_map the running jax exposed —
+`jax.shard_map` (`check_vma=`) or the 0.4.x
+`jax.experimental.shard_map.shard_map` (`check_rep=`). The repo runs on
+one installation (jax 0.9), so the 0.4.x branch is gone; what stays
+asserted is the call shape the shim hands `jax.shard_map` and that the
+wrapped kernel computes across a real multi-device mesh.
 """
 
 import numpy as np
@@ -49,46 +49,31 @@ def test_shard_map_compat_native_spelling(monkeypatch):
     assert _psum_through(wrapped) == pytest.approx(28.0)
 
 
-def test_shard_map_compat_experimental_fallback(monkeypatch):
-    """No `jax.shard_map` -> the jax.experimental spelling, check_rep."""
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    assert not hasattr(jax, "shard_map")
-
+def test_shard_map_compat_never_needs_the_experimental_module(monkeypatch):
+    """The 0.4.x spelling is not a fallback any more: with
+    `jax.experimental.shard_map` unusable the shim still works."""
     import jax.experimental.shard_map as exp_mod
-    real = exp_mod.shard_map
-    calls = {}
 
-    def spying_shard_map(*args, **kwargs):
-        # jax re-enters shard_map positionally during tracing — record
-        # only the shim's call (check_rep passed by keyword), forward all
-        if "check_rep" in kwargs:
-            calls.update(kwargs)
-        return real(*args, **kwargs)
+    def boom(*_a, **_k):
+        raise AssertionError("the 0.4.x shard_map spelling was called")
 
-    monkeypatch.setattr(exp_mod, "shard_map", spying_shard_map)
+    monkeypatch.setattr(exp_mod, "shard_map", boom)
     m = mesh_mod.get_mesh(1)
     wrapped = mesh_mod.shard_map_compat(_kernel, m, [P("block")], P())
-    assert calls["check_rep"] is False          # the 0.4.x spelling
-    assert "check_vma" not in calls
     assert _psum_through(wrapped) == pytest.approx(28.0)
 
 
-def test_shard_map_compat_branches_agree(monkeypatch):
-    """Both spellings produce the same numbers for the same kernel."""
-    m = mesh_mod.get_mesh(1)
-    via_fallback = _psum_through(
+def test_shard_map_compat_across_the_virtual_mesh():
+    """The same kernel over all 8 virtual devices: each device sums its
+    slice, the psum gives every device the total; list and tuple
+    in_specs are the same call."""
+    m = mesh_mod.get_mesh(8)
+    via_tuple = _psum_through(
         mesh_mod.shard_map_compat(_kernel, m, (P("block"),), P()))
-
-    def native(f, mesh, in_specs, out_specs, check_vma):
-        from jax.experimental.shard_map import shard_map
-        assert check_vma is False
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-
-    monkeypatch.setattr(jax, "shard_map", native, raising=False)
-    via_native = _psum_through(
-        mesh_mod.shard_map_compat(_kernel, m, (P("block"),), P()))
-    np.testing.assert_array_equal(via_fallback, via_native)
+    via_list = _psum_through(
+        mesh_mod.shard_map_compat(_kernel, m, [P("block")], P()))
+    assert via_tuple == pytest.approx(28.0)
+    np.testing.assert_array_equal(via_tuple, via_list)
 
 
 if __name__ == "__main__":
