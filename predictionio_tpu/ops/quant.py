@@ -345,11 +345,13 @@ def topk_for_users_quant(
     stable_topk, in a single dispatch. ``user_ixs`` must be in-bounds —
     callers resolve them against the model's user vocabulary first
     (KNOWN_ISSUES #5). Item columns at/past ``n_items`` are layout
-    padding, masked to NEG_INF so they can never rank. Bit-identical
-    (values AND indices, ties included) to the fused Pallas kernel and
-    the sharded quant kernel — the integer scores are exact and the
-    rescale is elementwise, so there is no accumulation-order drift
-    between the paths."""
+    padding, masked to NEG_INF so they can never rank — under either
+    selection stable_topk makes over the n_pad columns (two stages on
+    a long catalog, the whole-row sort on a short one: the same bits).
+    Bit-identical (values AND indices, ties included) to the fused
+    Pallas kernel and the sharded quant kernel — the integer scores are
+    exact and the rescale is elementwise, so there is no
+    accumulation-order drift between the paths."""
     with jax.named_scope("gather"):
         Q = jnp.take(u_q, user_ixs, axis=0)                  # (b, r)
         su = jnp.take(u_scale, user_ixs, axis=0)             # (b,)

@@ -3,7 +3,13 @@
 The serving hot path: replaces `MatrixFactorizationModel.recommendProducts`
 (invoked at tests/pio_tests/engines/recommendation-engine/src/main/scala/
 ALSAlgorithm.scala:95-112) and the cosine-similarity scoring loops of the
-similarproduct/ecommerce templates with one fused matmul + mask + lax.top_k.
+similarproduct/ecommerce templates with one fused matmul + mask + top-k.
+
+The serving kernels select with :func:`stable_topk` (a total order: score
+descending, index ascending), which sorts a whole score row only when it
+is short. On the 2.44 M-item catalog that sort was 512 of the 526 ms of a
+64-query flush (ledger, PR 27); a long row is now reduced to chunk maxima,
+and only the k chunks that can hold the answer are sorted — same bits.
 
 Everything is jitted once per (n_items, rank, k) shape and reused across
 queries, so a deployed engine server answers from HBM with no recompile.
@@ -19,6 +25,7 @@ cliff — the lint makes that a test failure instead.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Optional, Tuple
 
@@ -45,6 +52,115 @@ def fp32_matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
 
 
+#: a TPU tile: 8 rows (sublanes) of 128 items (lanes). The chunk maxima
+#: are first taken over whole lanes of the score matrix through a view
+#: of it as (rows / 8, 8, n / 128, 128): on a TPU that view is the
+#: layout the matrix already has, so the one pass over the scores reads
+#: them where they lie. A view as (rows, chunks, L) would be a 625 MB
+#: relayout copy at 64 x 2,441,053 (8.0 of a 17.3 ms program), and a
+#: reduce-window over the row is accepted only 128 items wide and ran
+#: at 36 GB/s (17.3 of 22.8 ms; my chip runs, PR 28).
+_SUBLANES, _LANES = 8, 128
+
+#: items per chunk of the two-stage selection; see stable_topk
+CHUNK = 512
+
+
+def chunk_plan(n: int, k: int, chunk: int = CHUNK
+               ) -> Optional[Tuple[int, int]]:
+    """The shape test of :func:`stable_topk`, from static shapes alone:
+    ``(L, C)`` when a row of ``n`` scores is selected in two stages (C
+    whole chunks of L items; the n - C*L items past them join the second
+    stage as they are), ``None`` when the whole row is sorted. Two stages
+    need more chunks than k, or the first stage discards nothing, and pay
+    once the second stage sorts under half the row: k*L + L <= n/2."""
+    C = n // chunk
+    return (chunk, C) if k >= 1 and C >= 2 * (k + 1) else None
+
+
+def selection_name(n: int, k: int) -> str:
+    """What `GET /` says of a deployed top-k program: "sort", or
+    "chunked L=512 C=4767"."""
+    plan = chunk_plan(n, k)
+    return "sort" if plan is None else "chunked L=%d C=%d" % plan
+
+
+def _sort_topk(scores: jnp.ndarray, idx: jnp.ndarray, k: int
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """First k of ``scores`` along the last axis under the total order
+    (score descending, ``idx`` ascending; NaN after everything, as
+    lax.sort orders floats): one two-key sort of the whole axis. Every
+    (score, idx) pair is unique, so there is one sorted order and no
+    stability to ask for: asking cost a third operand and 27 s of
+    compile in place of 19 at 4,767 keys (described v5e, PR 28)."""
+    neg, sidx = lax.sort((-scores, idx), num_keys=2, dimension=-1,
+                         is_stable=False)
+    # -(-x) is a bitwise round-trip for floats (two sign flips)
+    return -neg[..., :k], sidx[..., :k]
+
+
+def _nanmax(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """max that reads NaN as "nothing here": NaN only when both are. With
+    NaN as its identity this is the order's own maximum (NaN is last),
+    so a chunk that holds a NaN is not lost with it and a chunk of
+    nothing but NaN still ranks after a chunk that holds a -inf."""
+    return jnp.where(jnp.isnan(a), b,
+                     jnp.where(jnp.isnan(b), a, jnp.maximum(a, b)))
+
+
+def _chunked_topk(scores: jnp.ndarray, k: int, L: int, C: int
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The two-stage selection of :func:`stable_topk` on rows of at
+    least C*L scores, C > k. Exact: see the proof there."""
+    *lead, n = scores.shape
+    # the selection reads a score matrix that is THERE: written once by
+    # whatever made it, read once in full by the chunk maxima and in k
+    # slices a row by the gather. Left free, the TPU compiler fuses the
+    # maxima into the int8 path's score fusion (ops/quant.py) and takes
+    # 153 s over that one program in place of 20 (described v5e, 64 x
+    # 27,136, PR 28); the float32 program is the same with and without.
+    rows = lax.optimization_barrier(scores.reshape(-1, n))
+    b = rows.shape[0]
+    lane = min(L, _LANES)
+    per = L // lane
+    assert lane * per == L and C * L <= n and C > k
+    nan = jnp.array(jnp.nan, scores.dtype)
+    with jax.named_scope("chunk_max"):
+        # one pass over the scores, and the only one: each chunk's best
+        # element under the total order (its maximum; NaN iff all NaN),
+        # lane by lane first (see _LANES), then `per` lanes to a chunk
+        g = math.gcd(b, _SUBLANES)
+        m = n // lane
+        tiles = rows[:, :m * lane].reshape(b // g, g, m, lane)
+        best = lax.reduce(tiles, nan, _nanmax, (3,)).reshape(b, m)
+        best = lax.reduce(best[:, :C * per].reshape(b, C, per), nan,
+                          _nanmax, (2,))
+    with jax.named_scope("pick"):
+        chunk = lax.broadcasted_iota(jnp.int32, (b, C), 1)
+        _, picked = _sort_topk(best, chunk, k)               # (b, k)
+    with jax.named_scope("merge"):
+        # the picked chunks' RAW scores, sliced out of the score matrix
+        row = lax.broadcasted_iota(jnp.int32, (b, k), 0)
+        cand = lax.gather(
+            rows, jnp.stack([row, picked * L], axis=-1),
+            lax.GatherDimensionNumbers(offset_dims=(2,),
+                                       collapsed_slice_dims=(0,),
+                                       start_index_map=(0, 1)),
+            slice_sizes=(1, L), unique_indices=True,
+            mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)    # (b, k, L)
+        gidx = picked[..., None] * L + lax.broadcasted_iota(
+            jnp.int32, (b, k, L), 2)
+        cand, gidx = cand.reshape(b, k * L), gidx.reshape(b, k * L)
+        if n > C * L:
+            # the ragged end joins the candidates as it is
+            tail = rows[:, C * L:]
+            cand = jnp.concatenate([cand, tail], axis=-1)
+            gidx = jnp.concatenate([gidx, C * L + lax.broadcasted_iota(
+                jnp.int32, tail.shape, 1)], axis=-1)
+        vals, idx = _sort_topk(cand, gidx, k)
+    return vals.reshape(*lead, k), idx.reshape(*lead, k)
+
+
 def stable_topk(scores: jnp.ndarray, k: int
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Deterministic top-k along the last axis: descending score, equal
@@ -55,12 +171,42 @@ def stable_topk(scores: jnp.ndarray, k: int
     (score, index) pair is unique — so the result is identical on every
     backend and, crucially, recomposable from per-shard partial top-ks
     (parallel/serve_dist.py): the sharded and replicated serving paths
-    can only be bit-identical if the tie rule is explicit. On TPU this
-    costs nothing — lax.top_k lowers to a full sort there anyway."""
+    can only be bit-identical if the tie rule is explicit.
+
+    That sort is not free on a TPU: over whole rows of a large catalog
+    it WAS the serving program — 512 of the 526 ms of a flush at 64 x
+    2,441,053, 99.2 % of the device's traced seconds (ledger, PR 27).
+    So a long row (:func:`chunk_plan`, a test of the static shape only)
+    is selected in two stages, to the same bits:
+
+      1. `chunk_max`: the row is cut into C contiguous chunks of L
+         items and each reduced to its best element's score, in one
+         pass over the scores.
+      2. `pick`: the chunks are ordered by (best descending, chunk
+         number ascending) and the first k kept. Chunks are contiguous
+         index ranges, so this is the order of each chunk's best
+         element under the total order above.
+      3. `merge`: the k chunks' raw scores are sliced out of the score
+         matrix with their global indices (and the n - C*L items past
+         the last whole chunk beside them), and the two-key sort runs
+         over those k*L-odd candidates alone.
+
+    Every element e of the true top-k lies in a picked chunk: were its
+    chunk not among the first k, each of k other chunks would hold an
+    element that beats e (its best scores higher, or the same from a
+    lower-numbered chunk and so at a lower index), and e would be
+    (k+1)-th at best. Sorting a set that holds the top-k under the same
+    order gives the same first k, values and indices, ties included.
+    Non-finite scores keep the whole-row sort's behaviour because the
+    chunk order is the element order (:func:`_nanmax`): NaN is never
+    selected while k other scores exist, and an all-NaN row still
+    returns NaN for the serving layer's non-finite gate to refuse."""
+    n = scores.shape[-1]
+    plan = chunk_plan(n, k)
+    if plan is not None:
+        return _chunked_topk(scores, k, *plan)
     idx = lax.broadcasted_iota(jnp.int32, scores.shape, scores.ndim - 1)
-    neg, sidx = lax.sort((-scores, idx), num_keys=2, dimension=-1)
-    # -(-x) is a bitwise round-trip for floats (two sign flips)
-    return -neg[..., :k], sidx[..., :k]
+    return _sort_topk(scores, idx, k)
 
 
 @partial(jax.jit, static_argnames=("k",))
@@ -90,7 +236,8 @@ def topk_for_user(
     in-bounds — callers resolve it against the model's user vocabulary
     first (an OOB index would gather NaN, KNOWN_ISSUES.md #5).
     Tie-deterministic (stable_topk) so the inline path agrees bit-for-bit
-    with the batched and sharded kernels on tied scores."""
+    with the batched and sharded kernels on tied scores; like them it
+    sorts k chunks of a long catalog's scores, never the whole row."""
     with jax.named_scope("gather"):
         q = jnp.take(user_factors, user_ix, axis=0)
     with jax.named_scope("score"):
@@ -177,11 +324,16 @@ def topk_for_users(
     compiles once per (bucket, k, shapes), not once per batch size.
     Tie-deterministic (stable_topk): equal scores break by lowest item
     index — the contract the sharded serving path's cross-shard merge
-    (parallel/serve_dist.py) reproduces bit-for-bit. The three stages
-    carry ``jax.named_scope`` names (`gather`, `score`, `select`) into
-    the ops' metadata, so a profiler capture groups the program's device
-    time by stage whatever XLA calls the fusions; the names change no
-    operation and no compile-cache key."""
+    (parallel/serve_dist.py) reproduces bit-for-bit. The scores are
+    written once, as one (b, n_items) matrix, and read once in full, by
+    the selection's chunk maxima; on a long catalog the sort then sees k
+    chunks a row (stable_topk), where sorting whole rows took 512 of a
+    flush's 526 ms (ledger, PR 27). The three stages carry
+    ``jax.named_scope`` names (`gather`, `score`, `select`; inside
+    `select`: `chunk_max`, `pick`, `merge`) into the ops' metadata, so a
+    profiler capture groups the program's device time by stage whatever
+    XLA calls the fusions; the names change no operation and no
+    compile-cache key."""
     with jax.named_scope("gather"):
         Q = jnp.take(user_factors, user_ixs, axis=0)
     with jax.named_scope("score"):

@@ -29,9 +29,12 @@ Kernel (one fused device dispatch, same contract as ops.topk.topk_for_users):
      is the same float32 dot product the replicated kernel computes —
      up to the order in which the compiler adds its rank terms (see
      Parity);
-  3. local top-k: two-key sort by (-score, global index), exactly
-     ops.topk.stable_topk's tie rule; padding rows are masked to
-     NEG_INF and carry global ids >= n_items so they sort last;
+  3. local top-k: a two-key sort of the WHOLE shard by (-score, global
+     index) — ops.topk.stable_topk's total order, not its mechanism:
+     stable_topk sorts only the k chunks of a long row that can hold
+     the answer (PR 28), this is still the whole-row sort, on
+     n_items / n_dev keys a row (ROADMAP S5); padding rows are masked
+     to NEG_INF and carry global ids >= n_items so they sort last;
   4. merge: ONE small all_gather of the k·n_dev candidates (~k·n_dev
      floats per query) + a final two-key sort, on device.
 
@@ -270,9 +273,10 @@ def topk_for_users_sharded(
             gid = d * rows_dev_i + lax.broadcasted_iota(
                 jnp.int32, (b, rows_dev_i), 1)
             scores = jnp.where(gid < n_items, scores, NEG_INF)
-        # 3. local top-k with the stable_topk tie rule (two-key sort by
-        # (-score, global index); contiguous blocks make local order ==
-        # global order, so shard ties break exactly like replicated)
+        # 3. local top-k with the stable_topk tie rule (a two-key sort
+        # of the whole shard by (-score, global index); contiguous
+        # blocks make local order == global order, so shard ties break
+        # exactly like replicated)
         with jax.named_scope("local_topk"):
             neg, sid = lax.sort((-scores, gid), num_keys=2, dimension=-1)
         # 4. merge: all-gather the k·n_dev candidates along the

@@ -297,6 +297,36 @@ def _partition_models(models: List[Any], index: int,
     return out, state
 
 
+def _topk_selection(models: List[Any]) -> Optional[Dict[str, str]]:
+    """Which selection the deployed top-k programs were built with, per
+    declared k (serving/aot.py serving_ks): "sort", or "chunked L=512
+    C=4767" — ops/topk.py selection_name, the kernel's own test of the
+    static shape, over the score row those programs select from: the
+    item rows of the first model of the ALSModel shape, padding and
+    fold-in headroom included (the int8 layout's padded columns where
+    that serves). None where no program of ops/topk.py or ops/quant.py
+    serves: no such model, host factors, or the row-sharded layout
+    (parallel/serve_dist.py sorts each whole shard)."""
+    import numpy as np
+
+    from predictionio_tpu.ops import topk
+    from predictionio_tpu.serving import aot
+    for m in models:
+        fac = getattr(m, "item_factors", None)
+        if fac is None or getattr(m, "sharding", None) is not None:
+            continue
+        quant = getattr(m, "quant", None)
+        if quant is not None:
+            n = int(np.shape(quant.vt_q)[1])
+        elif isinstance(fac, np.ndarray):
+            continue
+        else:
+            n = int(np.shape(fac)[0])
+        return {str(k): topk.selection_name(n, k)
+                for k in aot.serving_ks(n)}
+    return None
+
+
 class QueryAPI:
     """Pure route handler for the engine server (ServerActor routes,
     CreateServer.scala:384-693)."""
@@ -1036,6 +1066,11 @@ class QueryAPI:
         batcher = self._batcher
         out["batching"] = ({"enabled": True, **batcher.stats()}
                            if batcher is not None else {"enabled": False})
+        if batcher is not None:
+            # read-only, a fact of the compiled programs: whether the
+            # flushes above sort whole score rows or k chunks of them
+            # (null: no program of ops/topk.py serves this deploy)
+            out["batching"]["topkSelection"] = _topk_selection(self.models)
         if self._aot_state is not None:
             # only with AOT active: a PIO_AOT=0 deploy keeps the exact
             # legacy key set (wire parity, asserted by test)

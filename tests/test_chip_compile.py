@@ -123,6 +123,27 @@ def test_topk_for_user_compiles(one_chip, no_compile_cache):
         k=K).compile()
 
 
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_topk_for_users_compiles_at_the_benchmark_cells_shape(
+        one_chip, no_compile_cache, bucket):
+    """The serving cells' program (BENCHMARK.json, rec-als-amazon-r128:
+    6,643,669 x 128 users, 2,441,053 x 128 items) at its real size. What
+    the chip's compiler must not be given back by the two-stage
+    selection: a sort as long as the catalog, or a second copy of the
+    score matrix (a view of it as rows x chunks x L is one: a 625 MB
+    relayout) — the temporaries are the scores and small change."""
+    from predictionio_tpu.ops import topk
+    n_users, n_items, rank = 6_643_669, 2_441_053, 128
+    compiled = topk.topk_for_users.lower(
+        _s((n_users, rank), jnp.float32, one_chip),
+        _s((n_items, rank), jnp.float32, one_chip),
+        _s((bucket,), jnp.int32, one_chip), k=K).compile()
+    assert not [line for line in compiled.as_text().splitlines()
+                if " sort(" in line and f",{n_items}]" in line]
+    scores_bytes = 4 * max(bucket, 8) * n_items     # 8 sublanes a tile
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * scores_bytes
+
+
 @pytest.mark.xfail(strict=True, reason=(
     "the TPU compiler refuses the fused kernel's output block shape "
     "(b, 10) — 'the last two dimensions of your block shape are "

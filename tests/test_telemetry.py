@@ -392,7 +392,8 @@ def test_batcher_stats_are_registry_backed(memory_storage):
         assert set(b) == {"enabled", "maxBatchSize", "maxDelayMs",
                           "maxQueue", "buckets", "queueDepth", "batches",
                           "queries", "rejected", "batchSizeHist",
-                          "bucketHist", "avgQueueWaitMs", "avgFlushMs"}
+                          "bucketHist", "avgQueueWaitMs", "avgFlushMs",
+                          "topkSelection"}
         assert b["queries"] == 3
         # the same numbers, straight from the registry instruments
         assert int(api._batcher._m_queries.value) == 3
@@ -404,6 +405,67 @@ def test_batcher_stats_are_registry_backed(memory_storage):
         got = [v for labels, v in samples["pio_batcher_queries_total"]
                if f'batcher="{inst}"' in labels]
         assert got == [3.0]
+    finally:
+        api.close()
+
+
+def test_status_names_the_topk_selection_from_the_kernels_shape_test(
+        memory_storage, monkeypatch):
+    """`GET /`'s batching block says which selection the deployed top-k
+    programs were built with, from ops/topk.py's own test of the static
+    shape: `sort` for the six-item catalog, `chunked` with its L and C
+    once the same instance holds a catalog long enough for two stages;
+    null when the CPU harness moved serving to host arrays."""
+    import numpy as np
+
+    # the CPU backend times a query at deploy and serves from the host
+    # when it is slow (a loaded test machine): pin each layout in turn
+    monkeypatch.setenv("PIO_SERVE_DEVICE_MS", "1e9")
+
+    from predictionio_tpu.data.bimap import BiMap
+    from predictionio_tpu.data.storage import Model
+    from predictionio_tpu.models.recommendation.als_algorithm import ALSModel
+    from predictionio_tpu.ops import topk
+    from predictionio_tpu.workflow import model_io
+
+    api, _ = _trained_query_api(memory_storage)
+    try:
+        _, info = api.handle("GET", "/")
+        assert info["batching"]["topkSelection"] == {"6": "sort"}
+        assert topk.chunk_plan(6, 6) is None
+        instance_id = api.engine_instance.id
+    finally:
+        api.close()
+    n_items = 2 * 11 * topk.CHUNK + 5
+    rng = np.random.default_rng(0)
+    long_model = ALSModel(
+        rank=4,
+        user_factors=rng.normal(size=(8, 4)).astype(np.float32),
+        item_factors=rng.normal(size=(n_items, 4)).astype(np.float32),
+        user_vocab=BiMap({f"u{k}": k for k in range(8)}),
+        item_vocab=BiMap({f"i{k}": k for k in range(n_items)}))
+    memory_storage.get_model_data_models().insert(Model(
+        id=instance_id, models=model_io.serialize_models([long_model])))
+    api = QueryAPI(storage=memory_storage, engine=RecommendationEngine(),
+                   config=ServerConfig(batching="on"))
+    try:
+        assert api.engine_instance.id == instance_id
+        _, info = api.handle("GET", "/")
+        plan = topk.chunk_plan(n_items, 10)
+        assert plan == (topk.CHUNK, 22)
+        assert info["batching"]["topkSelection"] == {
+            "10": "chunked L=%d C=%d" % plan}
+        st, body = api.handle("POST", "/queries.json", body=json.dumps(
+            {"user": "u1", "num": 10}).encode())
+        assert st == 200 and len(body["itemScores"]) == 10
+    finally:
+        api.close()
+    monkeypatch.setenv("PIO_SERVE_DEVICE_MS", "0")
+    api = QueryAPI(storage=memory_storage, engine=RecommendationEngine(),
+                   config=ServerConfig(batching="on"))
+    try:
+        _, info = api.handle("GET", "/")
+        assert info["batching"]["topkSelection"] is None
     finally:
         api.close()
 

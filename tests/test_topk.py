@@ -1,6 +1,7 @@
 """Top-K scoring ops (serving hot path)."""
 
 import numpy as np
+import pytest
 
 from predictionio_tpu.ops import topk
 
@@ -118,3 +119,160 @@ def test_host_topk_nonpositive_k_returns_empty():
     for k in (0, -1, -3):
         vals, idx = host_topk(scores, k)
         assert vals.size == 0 and idx.size == 0
+
+
+# ---------------------------------------------------------------------------
+# the two-stage selection against the whole-row two-key sort, bit for bit
+# ---------------------------------------------------------------------------
+
+def _whole_row(scores, k):
+    """stable_topk as it was before the two stages: one two-key sort of
+    the whole row. The reference every chunked case is held to."""
+    import jax.numpy as jnp
+    from jax import lax
+    scores = jnp.asarray(scores)
+    idx = lax.broadcasted_iota(jnp.int32, scores.shape, scores.ndim - 1)
+    return topk._sort_topk(scores, idx, k)
+
+
+def _same_bits(got, want):
+    gv, gi = (np.asarray(x) for x in got)
+    wv, wi = (np.asarray(x) for x in want)
+    np.testing.assert_array_equal(gi, wi)
+    # values bit for bit (so -0.0 is not 0.0), any NaN standing for NaN
+    nan = np.isnan(wv)
+    np.testing.assert_array_equal(np.isnan(gv), nan)
+    np.testing.assert_array_equal(gv.view(np.int32)[~nan],
+                                  wv.view(np.int32)[~nan])
+
+
+#: (b, n, k, L): n a multiple of L and not; C = n // L exactly at the
+#: threshold 2 (k + 1), one item under it, and at or under k; every
+#: serving bucket; L under a lane, one lane (128), several lanes (512)
+_SHAPES = [
+    (1, 96, 1, 8),          # n = 12 L
+    (4, 100, 1, 8),         # ragged: 12 chunks and 4 items
+    (16, 176, 10, 8),       # C = 22 = 2 (k + 1): the threshold
+    (16, 175, 10, 8),       # one item under it: the plan says sort
+    (64, 1003, 10, 8),
+    (4, 2100, 64, 16),      # k = 64, C = 131
+    (4, 700, 64, 16),       # k >= C = 43: nothing to discard
+    (1, 4173, 10, 128),     # one lane a chunk
+    (2, 12005, 10, 512),    # four lanes a chunk, the serving L
+]
+
+
+def _fill(kind, b, n, k, L, rng):
+    s = rng.normal(size=(b, n)).astype(np.float32)
+    top = np.float32(9.0)
+    if kind == "random":
+        pass
+    elif kind == "few_values":            # ties everywhere
+        s = rng.integers(-3, 3, size=(b, n)).astype(np.float32)
+    elif kind == "ties_inside_chunk":
+        s[:, L + 1:L + 4] = top
+    elif kind == "ties_across_boundary":
+        s[:, 2 * L - 2:2 * L + 2] = top
+    elif kind == "tie_at_kth_place":
+        # k - 1 clear winners, then one value held by items of many
+        # chunks: the k-th reply is the lowest index among them
+        s[:, 3:3 + 5 * (k - 1):5] = top
+        s[:, n // 2::L // 2 + 1] = np.float32(5.0)
+    elif kind == "ties_in_tail":
+        s[:, n - 3:] = top
+        s[:, L:L + 2] = top
+    elif kind == "neg_inf_masked":        # ops/quant.py's layout padding
+        s[:, n - n // 3:] = topk.NEG_INF
+        s[0, :] = topk.NEG_INF
+    elif kind == "infinities":
+        s[:, 5] = np.inf
+        s[:, n - 1] = np.inf
+        s[:, L:3 * L] = -np.inf
+        s[-1, :] = -np.inf
+    elif kind == "some_nan":
+        s[:, 0:2 * L + 3] = np.nan        # whole chunks and part of one
+        s[:, n - 2] = np.nan
+        s[0, :] = np.nan                  # fewer than k left in row 0:
+        s[0, [n // 2, n - 1]] = [1.0, -np.inf]   # NaN fills the reply
+    elif kind == "all_nan":
+        s[:] = np.nan
+    elif kind == "signed_zeros":
+        s[:] = 0.0
+        s[:, 1::3] = -0.0
+    else:
+        raise AssertionError(kind)
+    return s
+
+
+_FILLS = ["random", "few_values", "ties_inside_chunk",
+          "ties_across_boundary", "tie_at_kth_place", "ties_in_tail",
+          "neg_inf_masked", "infinities", "some_nan", "all_nan",
+          "signed_zeros"]
+
+
+
+@pytest.mark.parametrize("kind", _FILLS)
+@pytest.mark.parametrize("b,n,k,L", _SHAPES)
+def test_chunked_topk_is_the_whole_row_sort(b, n, k, L, kind):
+    """Values and indices, ties and non-finite scores included. The
+    shape test alone decides which of the two runs, so wherever the two
+    stages CAN run (C > k) they are held to the sort, on both sides of
+    the threshold; where they cannot, the plan must say sort."""
+    rng = np.random.default_rng(hash((b, n, k, L)) % 2 ** 32)
+    scores = _fill(kind, b, n, k, L, rng)
+    C = n // L
+    plan = topk.chunk_plan(n, k, chunk=L)
+    assert plan == ((L, C) if C >= 2 * (k + 1) else None)
+    want = _whole_row(scores, k)
+    if kind == "all_nan":
+        # the serving layer's non-finite gate must still see them
+        assert np.isnan(np.asarray(want[0])).all()
+    if C > k:
+        _same_bits(topk._chunked_topk(np.asarray(scores), k, L, C), want)
+    else:
+        assert plan is None
+
+
+@pytest.mark.parametrize("n,chunked", [
+    (2 * 11 * topk.CHUNK, True),          # k = 10: the threshold itself
+    (2 * 11 * topk.CHUNK - 1, False),
+    (26_744, True),                       # the ML-20M catalog
+    (topk.CHUNK * 10, False),
+])
+def test_stable_topk_takes_the_branch_the_shape_test_names(n, chunked):
+    """The public function at the serving L, 1-D (the inline path) and
+    2-D (a bucket): the same bits from whichever branch the plan names,
+    and `GET /`'s name for it from the same test."""
+    k = 10
+    rng = np.random.default_rng(n)
+    scores = rng.integers(-50, 50, size=(4, n)).astype(np.float32)
+    assert (topk.chunk_plan(n, k) is not None) == chunked
+    assert topk.selection_name(n, k) == (
+        f"chunked L={topk.CHUNK} C={n // topk.CHUNK}" if chunked else "sort")
+    _same_bits(topk.stable_topk(scores, k), _whole_row(scores, k))
+    _same_bits(topk.stable_topk(scores[0], k), _whole_row(scores[0], k))
+
+
+def test_cell_shape_lowers_without_a_whole_row_sort():
+    """`topk_for_users` at the benchmark cell's shape (6,643,669 x 128
+    users, 2,441,053 x 128 items, bucket 64, k 10), lowered from shapes
+    alone: no sort, and no iota, as long as the catalog. The 2.44 M-key
+    sort was 99.2 % of the device's time there (ledger, PR 27)."""
+    import re
+
+    import jax
+    S = jax.ShapeDtypeStruct
+    n_items = 2_441_053
+    text = topk.topk_for_users.lower(
+        S((6_643_669, 128), np.float32), S((n_items, 128), np.float32),
+        S((64,), np.int32), k=10).as_text()
+    sorts = re.findall(r"stablehlo\.sort.*?\}\) : \((.*?)\) ->", text, re.S)
+    L, C = topk.chunk_plan(n_items, 10)
+    assert sorts == [
+        f"tensor<64x{C}xf32>, tensor<64x{C}xi32>",
+        f"tensor<64x{10 * L + n_items - C * L}xf32>, "
+        f"tensor<64x{10 * L + n_items - C * L}xi32>"]
+    assert not re.search(rf"stablehlo\.iota[^\n]*x{n_items}x", text)
+    # the scores are still one materialised (64, n_items) matrix that
+    # the selection reads: nothing recomputes the winners' dot products
+    assert len(re.findall(r"stablehlo\.dot_general", text)) == 1
