@@ -435,18 +435,26 @@ def _rows_dev(n: int, n_dev: int) -> int:
 
 
 def _shard_rows(arr: np.ndarray, rows_dev: int, spec: NamedSharding):
-    """Pad axis 0 to rows_dev * n_dev with zero rows and place each
-    contiguous block on its device (every process holds the full host
-    array, so each one donates its addressable shards — the same
-    strategy als_dist._shard_put uses)."""
-    n_dev = spec.mesh.devices.size
-    n_pad = rows_dev * n_dev
-    if arr.shape[0] != n_pad:
-        out = np.zeros((n_pad,) + arr.shape[1:], dtype=arr.dtype)
-        out[:arr.shape[0]] = arr
-        arr = out
-    return jax.make_array_from_callback(arr.shape, spec,
-                                        lambda idx: arr[idx])
+    """Place each contiguous block of ``rows_dev`` rows on its device, as
+    if axis 0 were padded to rows_dev * n_dev with zero rows (every
+    process holds the full host array, so each one donates its
+    addressable shards — the same strategy als_dist._shard_put uses).
+    A block goes to its device as a view of ``arr``; only the block
+    that reaches past the last real row is copied, to pad it — a
+    matrix that fills most of the host's memory is never held twice."""
+    n, n_pad = arr.shape[0], rows_dev * spec.mesh.devices.size
+
+    def block(idx):
+        lo, hi, _ = idx[0].indices(n_pad)
+        part = arr[lo:min(hi, n)]
+        if part.shape[0] == hi - lo:
+            return part
+        out = np.zeros((hi - lo,) + arr.shape[1:], dtype=arr.dtype)
+        out[:part.shape[0]] = part
+        return out
+
+    return jax.make_array_from_callback((n_pad,) + arr.shape[1:], spec,
+                                        block)
 
 
 @dataclasses.dataclass

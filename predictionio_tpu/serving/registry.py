@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import os
 import threading
 import time
@@ -180,7 +181,10 @@ def model_hbm_bytes(models: Iterable[Any]) -> int:
     deep) and sum ``.nbytes`` of every distinct array found. This is
     the load-time estimate the budget is enforced against — it sees
     factor matrices and vocab arrays, not XLA scratch or fold-in
-    growth (KNOWN_ISSUES #16)."""
+    growth (KNOWN_ISSUES #16). A budget is one device's HBM, so a
+    device array counts for what its fullest device holds of it: all
+    of a replicated array, one block of a row-sharded one
+    (parallel/serve_dist.py)."""
     total = 0
     seen: set = set()
 
@@ -190,6 +194,11 @@ def model_hbm_bytes(models: Iterable[Any]) -> int:
         if isinstance(n, (int, float)) and not isinstance(x, (str, bytes)):
             if id(x) not in seen:
                 seen.add(id(x))
+                sharding = getattr(x, "sharding", None)
+                if sharding is not None:
+                    # shape arithmetic only: the array is not touched
+                    n = (math.prod(sharding.shard_shape(x.shape))
+                         * x.dtype.itemsize)
                 total += int(n)
 
     for model in models:

@@ -327,6 +327,36 @@ def _topk_selection(models: List[Any]) -> Optional[Dict[str, str]]:
     return None
 
 
+def _serving_layout(models: List[Any]) -> Dict[str, Any]:
+    """Where the factors that answer the flushes live, for the first
+    model of the ALSModel shape: "row-sharded" over ``shards`` devices
+    (parallel/serve_dist.py ShardedFactors),
+    "replicated" device arrays on one, or "host"; null where no model
+    has that shape. ``perShardBytes`` is what one device holds of them:
+    a shard's factor bytes, or the registry's estimate of the model."""
+    import numpy as np
+
+    for m in models:
+        fac = getattr(m, "item_factors", None)
+        if fac is None:
+            continue
+        sharding = getattr(m, "sharding", None)
+        if sharding is not None:
+            # ShardedFactors.summary()'s shards and perShardFactorBytes
+            return {"layout": "row-sharded", "shards": sharding.n_shards,
+                    "perShardBytes": sharding.per_shard_bytes()}
+        quant = getattr(m, "quant", None)
+        if quant is not None:
+            # the int8 blocks are the only device copy (ops/quant.py)
+            return {"layout": "replicated", "shards": 1,
+                    "perShardBytes": quant.int8_bytes()}
+        if isinstance(fac, np.ndarray):
+            return {"layout": "host", "shards": 0, "perShardBytes": 0}
+        return {"layout": "replicated", "shards": 1,
+                "perShardBytes": registry_mod.model_hbm_bytes([m])}
+    return {"layout": None, "shards": 0, "perShardBytes": 0}
+
+
 class QueryAPI:
     """Pure route handler for the engine server (ServerActor routes,
     CreateServer.scala:384-693)."""
@@ -1071,6 +1101,8 @@ class QueryAPI:
             # flushes above sort whole score rows or k chunks of them
             # (null: no program of ops/topk.py serves this deploy)
             out["batching"]["topkSelection"] = _topk_selection(self.models)
+            # likewise a fact of the deploy: the layout that answers them
+            out["batching"].update(_serving_layout(self.models))
         if self._aot_state is not None:
             # only with AOT active: a PIO_AOT=0 deploy keeps the exact
             # legacy key set (wire parity, asserted by test)

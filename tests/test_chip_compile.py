@@ -263,3 +263,37 @@ def test_sharded_serve_compiles_on_four_chips(mesh4, no_compile_cache,
     text = compiled.as_text()
     assert "all-reduce" in text or "all-gather" in text
     assert compiled.input_shardings[0][0].is_equivalent_to(rows, 2)
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_sharded_serve_compiles_at_the_benchmark_cells_shape(
+        mesh4, no_compile_cache, bucket):
+    """The four-chip cell's program (BENCHMARK.json,
+    rec-als-amazon14-r128: 20,980,320 x 128 users and 9,350,000 x 128
+    items, 15.5 GB of float32 no one chip can hold) at its real size on
+    the described 2x2 host: the compiler accepts it, what a device
+    holds of it — its blocks of both matrices, the temporaries of a
+    flush, the results — fits the chip's 16 GB, and the factors stay
+    row-sharded (no device is handed a whole matrix)."""
+    from predictionio_tpu.parallel import serve_dist
+    n_users, n_items, rank = 20_980_320, 9_350_000, 128
+    n_dev = mesh4.devices.size
+    rows_u = serve_dist._rows_dev(n_users, n_dev)
+    rows_i = serve_dist._rows_dev(n_items, n_dev)
+    assert (rows_u * n_dev, rows_i * n_dev) == (n_users, n_items)
+    rows = NamedSharding(mesh4, P(serve_dist.AXIS, None))
+    compiled = serve_dist.topk_for_users_sharded.lower(
+        _s((n_users, rank), jnp.float32, rows),
+        _s((n_items, rank), jnp.float32, rows),
+        _s((bucket,), jnp.int32, NamedSharding(mesh4, P())),
+        k=K, n_items=n_items, rows_dev_u=rows_u, rows_dev_i=rows_i,
+        mesh=mesh4).compile()
+    mem = compiled.memory_analysis()
+    # per device: a quarter of the factors, never the whole
+    assert mem.argument_size_in_bytes < 1.01 * (n_users + n_items) * rank
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 16e9
+    text = compiled.as_text()
+    assert "all-reduce" in text or "all-gather" in text
+    for sharding in compiled.input_shardings[0][:2]:
+        assert sharding.is_equivalent_to(rows, 2)

@@ -386,3 +386,207 @@ def test_doctor_sharding_line_states():
     state, detail = {c: (s, d) for c, s, d in doctor.diagnose(
         _scrape_stub("", {"telemetry": True}))}["sharding"]
     assert state == doctor.NA and "replicated" in detail
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's four-chip deployment (rec-als-amazon14-r128) at a small
+# size: four shards, the benchmark's own reference and comparison
+# ---------------------------------------------------------------------------
+
+def _benchmark_path(*parts):
+    import os
+    return os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", *parts)
+
+
+def _benchmark_module(name, *sub):
+    """A module of benchmark/ by path, so tests/ keeps its sys.path."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        f"_bench_{name}", _benchmark_path(*sub, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _amazon14_config():
+    with open(_benchmark_path("configs",
+                              "rec-als-amazon14-r128.json")) as f:
+        return json.load(f)
+
+
+@pytest.fixture()
+def four_devices(monkeypatch):
+    """The deployment's host: `--shard-serving on` shards over every
+    visible device, so the harness's eight are cut to four."""
+    devs = jax.devices()[:4]
+    assert len(devs) == 4
+    monkeypatch.setattr(jax, "devices", lambda *a, **kw: devs)
+    return devs
+
+
+def _deploy(storage, U, V, **config):
+    """A completed instance holding an ALSModel of these factors, made
+    as benchmark/child_serve.py makes its own, behind a QueryAPI."""
+    from predictionio_tpu.data.bimap import BiMap
+    from predictionio_tpu.data.storage import EngineInstance, Model
+    from predictionio_tpu.models.recommendation.als_algorithm import ALSModel
+    from predictionio_tpu.workflow import model_io
+
+    algos = [{"name": "als", "params": {
+        "rank": int(U.shape[1]), "numIterations": 1, "lambda": 0.01,
+        "seed": 3}}]
+    now = dt.datetime.now(dt.timezone.utc)
+    instance_id = storage.get_meta_data_engine_instances().insert(
+        EngineInstance(
+            id="", status="COMPLETED", start_time=now, end_time=now,
+            engine_id="default", engine_version="NOT_USED",
+            engine_variant="default", engine_factory="shard-test",
+            data_source_params=json.dumps({"params": {"appName": "x"}}),
+            preparator_params="{}", algorithms_params=json.dumps(algos),
+            serving_params="{}"))
+    model = ALSModel(
+        rank=int(U.shape[1]), user_factors=U, item_factors=V,
+        user_vocab=BiMap({f"u{k}": k for k in range(U.shape[0])}),
+        item_vocab=BiMap({f"i{k}": k for k in range(V.shape[0])}))
+    storage.get_model_data_models().insert(Model(
+        id=instance_id, models=model_io.serialize_models([model])))
+    return QueryAPI(storage=storage, engine=RecommendationEngine(),
+                    config=ServerConfig(batching="on", **config))
+
+
+def _spectrum_factors(n_users, n_items, rank, seed):
+    """The benchmark's factors at a small size (gen_factors: column d
+    scaled by (d+1)^-0.5), with clones of one item in the first, a
+    middle and the last shard so that every user's scores hold ties."""
+    gen = _benchmark_module("gen_factors")
+    U = gen.matrix(seed, "user", n_users, rank, 0.5)
+    V = gen.matrix(seed, "item", n_items, rank, 0.5)
+    V[n_items // 2] = V[2]
+    V[n_items - 1] = V[2]
+    return U, V
+
+
+@pytest.mark.parametrize("n_users,n_items", [(203, 1_031), (17, 94)])
+def test_sharded4_replies_equal_the_benchmarks_reference(
+        memory_storage, four_devices, n_users, n_items):
+    """Through QueryAPI with `--shard-serving on` over four devices, for
+    row counts that do not divide by 4 (the last shard is padded): the
+    replies are benchmark/reference/topk_reference.py's top-10 — same
+    items in the same order, ties by lowest index — and the benchmark's
+    own comparison reads them inside the new configuration's limits.
+    The users asked include the first and the last of the last shard."""
+    compare = _benchmark_module("compare")
+    reference = _benchmark_module("topk_reference", "reference")
+    config = _amazon14_config()
+    k = config["query"]["num"]
+    U, V = _spectrum_factors(n_users, n_items, 16, seed=2**31 + 7)
+    rows_dev_u = -(-n_users // 4)
+    users = [0, 1, rows_dev_u - 1, rows_dev_u, 3 * rows_dev_u,
+             n_users - 2, n_users - 1]
+    api = _deploy(memory_storage, U, V, shard_serving="on")
+    try:
+        sh = api.models[0].sharding
+        assert sh.n_shards == 4
+        assert sh.rows_dev_u * 4 > n_users and sh.rows_dev_i * 4 > n_items
+        replies = []
+        for u in users:
+            body = json.loads(_post(api, f"u{u}", k))
+            replies.append((u, [(int(s["item"][1:]), s["score"])
+                                for s in body["itemScores"]]))
+    finally:
+        api.close()
+    ref = reference.scores(U[users], reference.prepare(V))
+    lookup = dict(zip(users, ref))
+    for (u, items), row in zip(replies, ref):
+        assert [i for i, _ in items] == list(reference.topk(row, k)), u
+    numbers = compare.topk_numbers(replies, lookup.__getitem__, k)
+    ok, compared = compare.judge(
+        {**numbers, "compiles_in_window": 0.0}, config["limits"])
+    assert ok, compared
+    # the clones tie exactly, so the order among them is the rule's
+    clones = [2, n_items // 2, n_items - 1]
+    full = reference.topk(ref[0], n_items)
+    pos = [int(np.flatnonzero(full == c)[0]) for c in clones]
+    assert pos == sorted(pos) and pos[2] - pos[0] == 2
+
+
+@pytest.mark.parametrize("n_items", [1_031, 94])
+def test_the_four_shares_merged_are_the_unsharded_answer(n_items):
+    """Each shard's own candidate list — the program run on that
+    shard's item rows alone — with the shard's base offset added, merged
+    by merge_candidates, is the reference's answer over the whole
+    catalog: the shares add up to the whole, ties included."""
+    reference = _benchmark_module("topk_reference", "reference")
+    k, n_users = 10, 29
+    U, V = _spectrum_factors(n_users, n_items, 16, seed=5)
+    ixs = np.asarray([0, 7, n_users - 1], dtype=np.int32)
+    rows_dev_i = serve_dist._rows_dev(n_items, 4)
+    vals, gids = [], []
+    for d in range(4):
+        lo, hi = d * rows_dev_i, min((d + 1) * rows_dev_i, n_items)
+        share = serve_dist.shard_factors(U, V[lo:hi], n_shards=1)
+        v, i = jax.device_get(share.topk(ixs, k))
+        vals.append(v)
+        gids.append(i + lo)
+    vals, gids = np.concatenate(vals, 1), np.concatenate(gids, 1)
+    whole = serve_dist.shard_factors(U, V, n_shards=4)
+    wv, wi = jax.device_get(whole.topk(ixs, k))
+    ref = reference.scores(U[ixs], reference.prepare(V))
+    for r in range(len(ixs)):
+        mv, mg, _ = serve_dist.merge_candidates(vals[r], gids[r], k)
+        np.testing.assert_array_equal(mg, reference.topk(ref[r], k))
+        np.testing.assert_array_equal(mg, wi[r])
+        _assert_same_scores(mv, wv[r])
+
+
+def test_model_bytes_of_a_sharded_model_are_one_devices(four_devices):
+    """model_hbm_bytes and the sharding summary both state what ONE
+    device holds: rows_dev x rank x 4 bytes a matrix, padding rows
+    included — not the whole model."""
+    from predictionio_tpu.data.bimap import BiMap
+    from predictionio_tpu.models.recommendation.als_algorithm import ALSModel
+    from predictionio_tpu.serving.registry import model_hbm_bytes
+
+    n_users, n_items, rank = 203, 1_031, 16
+    U, V = _spectrum_factors(n_users, n_items, rank, seed=9)
+    sharded = serve_dist.shard_factors(U, V)
+    assert (sharded.rows_dev_u, sharded.rows_dev_i) == (51, 258)
+    per_device = (51 + 258) * rank * 4
+    assert sharded.summary()["perShardFactorBytes"] == per_device
+    telemetry.set_enabled(True)     # /debug/device.json's payload
+    assert devicewatch.debug_snapshot()["sharding"][
+        "perShardFactorBytes"] == per_device
+    model = ALSModel(rank=rank, user_factors=sharded.user_shards,
+                     item_factors=sharded.item_shards,
+                     user_vocab=BiMap({}), item_vocab=BiMap({}),
+                     sharding=sharded)
+    assert model_hbm_bytes([model]) == per_device
+    # a replicated device array counts whole
+    import types
+    replicated = types.SimpleNamespace(item_factors=jnp.asarray(V))
+    assert model_hbm_bytes([replicated]) == V.nbytes
+    for shard in sharded.item_shards.addressable_shards:
+        assert shard.data.nbytes == 258 * rank * 4
+
+
+@pytest.mark.parametrize("mode,layout,shards", [
+    ("on", "row-sharded", 4), ("off", "replicated", 1)])
+def test_status_batching_block_names_the_layout(
+        memory_storage, four_devices, monkeypatch, mode, layout, shards):
+    from predictionio_tpu.serving.registry import model_hbm_bytes
+
+    monkeypatch.setenv("PIO_SERVE_DEVICE_MS", "1e9")   # stay on the device
+    U, V = _spectrum_factors(203, 1_031, 16, seed=11)
+    api = _deploy(memory_storage, U, V, shard_serving=mode)
+    try:
+        b = api.handle("GET", "/")[1]["batching"]
+        assert (b["layout"], b["shards"]) == (layout, shards)
+        if mode == "on":
+            assert b["perShardBytes"] == (51 + 258) * 16 * 4
+            assert b["topkSelection"] is None
+        else:
+            assert b["perShardBytes"] == U.nbytes + V.nbytes
+        assert b["perShardBytes"] == model_hbm_bytes(api.models)
+    finally:
+        api.close()

@@ -393,7 +393,8 @@ def test_batcher_stats_are_registry_backed(memory_storage):
                           "maxQueue", "buckets", "queueDepth", "batches",
                           "queries", "rejected", "batchSizeHist",
                           "bucketHist", "avgQueueWaitMs", "avgFlushMs",
-                          "topkSelection"}
+                          "topkSelection", "layout", "shards",
+                          "perShardBytes"}
         assert b["queries"] == 3
         # the same numbers, straight from the registry instruments
         assert int(api._batcher._m_queries.value) == 3
