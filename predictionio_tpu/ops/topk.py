@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -108,7 +108,8 @@ def _nanmax(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
                      jnp.where(jnp.isnan(b), a, jnp.maximum(a, b)))
 
 
-def _chunked_topk(scores: jnp.ndarray, k: int, L: int, C: int
+def _chunked_topk(scores: jnp.ndarray, k: int, L: int, C: int,
+                  fetch: Optional[Callable] = None
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The two-stage selection of :func:`stable_topk` on rows of at
     least C*L scores, C > k. Exact: see the proof there."""
@@ -140,14 +141,17 @@ def _chunked_topk(scores: jnp.ndarray, k: int, L: int, C: int
         _, picked = _sort_topk(best, chunk, k)               # (b, k)
     with jax.named_scope("merge"):
         # the picked chunks' RAW scores, sliced out of the score matrix
-        row = lax.broadcasted_iota(jnp.int32, (b, k), 0)
-        cand = lax.gather(
-            rows, jnp.stack([row, picked * L], axis=-1),
-            lax.GatherDimensionNumbers(offset_dims=(2,),
-                                       collapsed_slice_dims=(0,),
-                                       start_index_map=(0, 1)),
-            slice_sizes=(1, L), unique_indices=True,
-            mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)    # (b, k, L)
+        if fetch is not None:
+            cand = fetch(rows, picked, L)                    # (b, k, L)
+        else:
+            row = lax.broadcasted_iota(jnp.int32, (b, k), 0)
+            cand = lax.gather(
+                rows, jnp.stack([row, picked * L], axis=-1),
+                lax.GatherDimensionNumbers(offset_dims=(2,),
+                                           collapsed_slice_dims=(0,),
+                                           start_index_map=(0, 1)),
+                slice_sizes=(1, L), unique_indices=True,
+                mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
         gidx = picked[..., None] * L + lax.broadcasted_iota(
             jnp.int32, (b, k, L), 2)
         cand, gidx = cand.reshape(b, k * L), gidx.reshape(b, k * L)
@@ -161,7 +165,8 @@ def _chunked_topk(scores: jnp.ndarray, k: int, L: int, C: int
     return vals.reshape(*lead, k), idx.reshape(*lead, k)
 
 
-def stable_topk(scores: jnp.ndarray, k: int
+def stable_topk(scores: jnp.ndarray, k: int,
+                fetch: Optional[Callable] = None
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Deterministic top-k along the last axis: descending score, equal
     scores broken by LOWEST index.
@@ -200,11 +205,17 @@ def stable_topk(scores: jnp.ndarray, k: int
     Non-finite scores keep the whole-row sort's behaviour because the
     chunk order is the element order (:func:`_nanmax`): NaN is never
     selected while k other scores exist, and an all-NaN row still
-    returns NaN for the serving layer's non-finite gate to refuse."""
+    returns NaN for the serving layer's non-finite gate to refuse.
+
+    ``fetch(rows, picked, L) -> (b, k, L)`` replaces the `merge` stage's
+    XLA gather of chunk ``picked[r, j]`` of row r: a copy, so the same
+    bits by another route. parallel/serve_dist.py hands in a kernel
+    (_fetch_chunks, which says why); without one, every program of this
+    module is what it was before the argument existed, op for op."""
     n = scores.shape[-1]
     plan = chunk_plan(n, k)
     if plan is not None:
-        return _chunked_topk(scores, k, *plan)
+        return _chunked_topk(scores, k, *plan, fetch=fetch)
     idx = lax.broadcasted_iota(jnp.int32, scores.shape, scores.ndim - 1)
     return _sort_topk(scores, idx, k)
 

@@ -29,12 +29,25 @@ Kernel (one fused device dispatch, same contract as ops.topk.topk_for_users):
      is the same float32 dot product the replicated kernel computes —
      up to the order in which the compiler adds its rank terms (see
      Parity);
-  3. local top-k: a two-key sort of the WHOLE shard by (-score, global
-     index) — ops.topk.stable_topk's total order, not its mechanism:
-     stable_topk sorts only the k chunks of a long row that can hold
-     the answer (PR 28), this is still the whole-row sort, on
-     n_items / n_dev keys a row (ROADMAP S5); padding rows are masked
-     to NEG_INF and carry global ids >= n_items so they sort last;
+  3. local top-k: ops.topk.stable_topk on the shard's own (b,
+     rows_dev) scores, then ``d * rows_dev + local index``. A long
+     shard (ops.topk.chunk_plan, a test of the static shape) is
+     selected in stable_topk's two stages — chunk maxima in the one
+     pass over the scores, a sort of the k chunks that can hold the
+     answer — a short one by one whole sort; the whole-shard sort was
+     597.85 of a flush's 602.2 ms at 64 x 2,337,500 (ledger, PR 29).
+     On a mesh of TPUs the k chunks are copied out of the scores by
+     one kernel (_fetch_chunks) where stable_topk's own XLA gather is
+     a loop of b*k slices: the same bits, a quarter of the time, and
+     a profiler capture of four chips that can be stopped.
+     Same candidates, to the bit, as a two-key sort of the whole shard
+     by (-score, global index): contiguous blocks make ascending local
+     index ascending global index, so stable_topk's total order (score
+     descending, local index ascending) IS that sort's order, non-finite
+     scores included (the same negation and lax.sort comparator). The
+     padding rows of a last shard are masked to NEG_INF before the
+     selection; they lie at the shard's highest local indices, so they
+     lose every tie among NEG_INFs and map to global ids >= n_items;
   4. merge: ONE small all_gather of the k·n_dev candidates (~k·n_dev
      floats per query) + a final two-key sort, on device.
 
@@ -89,7 +102,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.common import devicewatch, telemetry
-from predictionio_tpu.ops.topk import NEG_INF, fp32_matmul
+from predictionio_tpu.ops.topk import NEG_INF, fp32_matmul, stable_topk
 from predictionio_tpu.parallel.mesh import shard_map_compat
 
 logger = logging.getLogger("predictionio_tpu.serve_dist")
@@ -230,6 +243,80 @@ def merge_candidates(values, gids, k: int):
 # the sharded serving kernel
 # ---------------------------------------------------------------------------
 
+def _fetch_chunks(rows: jnp.ndarray, picked: jnp.ndarray, L: int,
+                  interpret: bool = False) -> jnp.ndarray:
+    """``out[r, j] = rows[r, picked[r, j] * L:][:L]``: stable_topk's
+    `merge` stage's fetch of the picked chunks, as ONE kernel on the
+    device's op line. The XLA gather it stands in for is a `while` of
+    b*k = 640 iterations of five ops at bucket 64, 0.83 ms and 3,200
+    trace events a program; on four chips at 25 flushes a second a 5 s
+    profiler capture of them took 134 s to stop, past the 120 s the
+    benchmark's harness waits (one chip, the same loop: 37 s; my chip
+    runs, PR 30). A copy, so the bits are the gather's. The grid walks
+    (row, rank); the chunk numbers ride in as a scalar-prefetch operand
+    and pick the block: the row's sublane tile of 8 rows (the whole
+    batch under 8) by L lanes, of which the kernel keeps the row's own
+    sublane. A chunk is whole (picked < C, C * L <= n), so the ragged
+    last block of a row is never addressed."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, k = picked.shape
+    g = min(b, 8)
+
+    def kernel(_picked, x_ref, o_ref):
+        o_ref[0] = x_ref[pl.ds(pl.program_id(0) % g, 1), :]
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, k),
+            in_specs=[pl.BlockSpec(
+                (g, L), lambda r, j, p: (r // g, p[r * k + j]))],
+            # (1, 1, L): the last two dims of a block are whole dims
+            out_specs=pl.BlockSpec(
+                (1, 1, L), lambda r, j, p: (r * k + j, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((b * k, 1, L), rows.dtype),
+        interpret=interpret,
+    )(picked.reshape(b * k), rows)
+    return out.reshape(b, k, L)
+
+
+def _select_and_merge(scores: jnp.ndarray, mesh: Mesh, *, k: int,
+                      n_items: int, rows_dev_i: int
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Steps 3 and 4 of both sharded kernels (module docstring), from
+    one shard's (b, rows_dev_i) scores inside the shard_map body: the
+    shard's top-k by stable_topk, its local indices made global by the
+    shard's base offset, and the all-gather merge. On a mesh of TPUs
+    stable_topk fetches its chunks through :func:`_fetch_chunks`; on
+    the CPU's virtual devices through its own XLA gather."""
+    axis, n_dev = mesh.axis_names[0], int(mesh.devices.size)
+    on_tpu = mesh.devices.flat[0].platform == "tpu"
+    base = lax.axis_index(axis) * rows_dev_i
+    k_local = min(int(k), int(rows_dev_i))
+    with jax.named_scope("local_topk"):
+        if n_dev * rows_dev_i > n_items:
+            # only a layout with padding rows pays for the mask, and it
+            # is a row vector against a scalar: no (b, rows_dev_i) index
+            # is written (two iotas, 1.7 ms a flush, while the sort
+            # carried one; ledger, PR 29)
+            real = lax.broadcasted_iota(
+                jnp.int32, (1, rows_dev_i), 1) < n_items - base
+            scores = jnp.where(real, scores, NEG_INF)
+        vals, idx = stable_topk(scores, k_local,
+                                fetch=_fetch_chunks if on_tpu else None)
+        gids = base + idx
+    # any global top-k element is inside its own shard's top-k_local,
+    # so the candidate set always covers the answer (k_local = rows_dev
+    # when k exceeds a shard, hence n_dev * k_local >= min(k, n_items))
+    with jax.named_scope("merge"):
+        cand_v = lax.all_gather(vals, axis, axis=1, tiled=True)
+        cand_g = lax.all_gather(gids, axis, axis=1, tiled=True)
+        mneg, mg = lax.sort((-cand_v, cand_g), num_keys=2, dimension=-1)
+        return -mneg[:, :k], mg[:, :k]
+
+
 @partial(jax.jit, static_argnames=("k", "n_items", "rows_dev_u",
                                    "rows_dev_i", "mesh"))
 def topk_for_users_sharded(
@@ -251,8 +338,6 @@ def topk_for_users_sharded(
     (serving/aot.py via ALSAlgorithm.aot_serving_programs) prebuilds
     every (bucket x k) program before /readyz flips ready."""
     axis = mesh.axis_names[0]
-    b = user_ixs.shape[0]
-    k_local = min(int(k), int(rows_dev_i))
 
     def step(U_blk, V_blk, ixs):
         # the steps carry jax.named_scope names into the ops' metadata, so
@@ -270,28 +355,12 @@ def topk_for_users_sharded(
         # each score is the same float32 dot product as replicated
         with jax.named_scope("score"):
             scores = fp32_matmul(Q, V_blk.T)          # (b, rows_dev_i)
-            gid = d * rows_dev_i + lax.broadcasted_iota(
-                jnp.int32, (b, rows_dev_i), 1)
-            scores = jnp.where(gid < n_items, scores, NEG_INF)
-        # 3. local top-k with the stable_topk tie rule (a two-key sort
-        # of the whole shard by (-score, global index); contiguous
-        # blocks make local order == global order, so shard ties break
-        # exactly like replicated)
-        with jax.named_scope("local_topk"):
-            neg, sid = lax.sort((-scores, gid), num_keys=2, dimension=-1)
-        # 4. merge: all-gather the k·n_dev candidates along the
-        # candidate axis + final two-key sort. Any global top-k element
-        # is inside its own shard's top-k_local, so the candidate set
-        # always covers the answer (k_local = rows_dev when k exceeds
-        # a shard, hence n_dev * k_local >= min(k, n_items) >= k).
-        with jax.named_scope("merge"):
-            cand_v = lax.all_gather(-neg[:, :k_local], axis, axis=1,
-                                    tiled=True)
-            cand_g = lax.all_gather(sid[:, :k_local], axis, axis=1,
-                                    tiled=True)
-            mneg, mg = lax.sort((-cand_v, cand_g), num_keys=2,
-                                dimension=-1)
-            return -mneg[:, :k], mg[:, :k]
+        # 3. the shard's top-k by stable_topk (contiguous blocks make
+        # local order == global order, so shard ties break exactly like
+        # replicated) and 4. the all-gather of the k·n_dev candidates +
+        # final two-key sort
+        return _select_and_merge(scores, mesh, k=k, n_items=n_items,
+                                 rows_dev_i=rows_dev_i)
 
     return shard_map_compat(
         step, mesh,
@@ -323,8 +392,6 @@ def topk_for_users_sharded_quant(
     (values, indices, ties) to the replicated quantized kernels —
     there is no accumulation-order drift for sharding to introduce."""
     axis = mesh.axis_names[0]
-    b = user_ixs.shape[0]
-    k_local = min(int(k), int(rows_dev_i))
 
     def step(U_blk, su_blk, V_blk, sv_blk, ixs):
         d = lax.axis_index(axis)
@@ -348,22 +415,11 @@ def topk_for_users_sharded_quant(
         with jax.named_scope("rescale"):
             scores = s32.astype(jnp.float32) * (su[:, None]
                                                 * sv_blk[None, :])
-            gid = d * rows_dev_i + lax.broadcasted_iota(
-                jnp.int32, (b, rows_dev_i), 1)
-            scores = jnp.where(gid < n_items, scores, NEG_INF)
-        # 3.+4. local top-k + all-gather merge: identical to the fp32
-        # sharded kernel (the tie rule and candidate-coverage argument
-        # carry over unchanged)
-        with jax.named_scope("local_topk"):
-            neg, sid = lax.sort((-scores, gid), num_keys=2, dimension=-1)
-        with jax.named_scope("merge"):
-            cand_v = lax.all_gather(-neg[:, :k_local], axis, axis=1,
-                                    tiled=True)
-            cand_g = lax.all_gather(sid[:, :k_local], axis, axis=1,
-                                    tiled=True)
-            mneg, mg = lax.sort((-cand_v, cand_g), num_keys=2,
-                                dimension=-1)
-            return -mneg[:, :k], mg[:, :k]
+        # 3.+4. local top-k + all-gather merge: the fp32 sharded
+        # kernel's (the tie rule and candidate-coverage argument carry
+        # over unchanged)
+        return _select_and_merge(scores, mesh, k=k, n_items=n_items,
+                                 rows_dev_i=rows_dev_i)
 
     return shard_map_compat(
         step, mesh,

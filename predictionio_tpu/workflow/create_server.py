@@ -304,18 +304,25 @@ def _topk_selection(models: List[Any]) -> Optional[Dict[str, str]]:
     static shape, over the score row those programs select from: the
     item rows of the first model of the ALSModel shape, padding and
     fold-in headroom included (the int8 layout's padded columns where
-    that serves). None where no program of ops/topk.py or ops/quant.py
-    serves: no such model, host factors, or the row-sharded layout
-    (parallel/serve_dist.py sorts each whole shard)."""
+    that serves; ONE shard's rows, rowsPerShard.items, where the layout
+    is row-sharded: parallel/serve_dist.py selects on each shard's own
+    scores). None where no program of ops/topk.py, ops/quant.py or
+    parallel/serve_dist.py serves: no such model, or host factors."""
     import numpy as np
 
     from predictionio_tpu.ops import topk
     from predictionio_tpu.serving import aot
     for m in models:
         fac = getattr(m, "item_factors", None)
-        if fac is None or getattr(m, "sharding", None) is not None:
+        if fac is None:
             continue
+        sharding = getattr(m, "sharding", None)
         quant = getattr(m, "quant", None)
+        if sharding is not None:
+            # the declared ks are clamped to the whole model, as
+            # sharded_program_specs is handed them
+            return {str(k): topk.selection_name(sharding.rows_dev_i, k)
+                    for k in aot.serving_ks(sharding.n_items)}
         if quant is not None:
             n = int(np.shape(quant.vt_q)[1])
         elif isinstance(fac, np.ndarray):

@@ -274,7 +274,12 @@ def test_sharded_serve_compiles_at_the_benchmark_cells_shape(
     the described 2x2 host: the compiler accepts it, what a device
     holds of it — its blocks of both matrices, the temporaries of a
     flush, the results — fits the chip's 16 GB, and the factors stay
-    row-sharded (no device is handed a whole matrix)."""
+    row-sharded (no device is handed a whole matrix). And what its
+    one-chip twin holds, a shard for the catalog: no sort as long as
+    the shard, no global index written out beside the scores (an
+    s32[b, rows_dev_i]: the whole-shard sort carried one), temporaries
+    of the scores and small change (that sort's were three times it);
+    and the compiler takes the chunk-fetch kernel at both buckets."""
     from predictionio_tpu.parallel import serve_dist
     n_users, n_items, rank = 20_980_320, 9_350_000, 128
     n_dev = mesh4.devices.size
@@ -297,3 +302,11 @@ def test_sharded_serve_compiles_at_the_benchmark_cells_shape(
     assert "all-reduce" in text or "all-gather" in text
     for sharding in compiled.input_shardings[0][:2]:
         assert sharding.is_equivalent_to(rows, 2)
+    assert not [line for line in text.splitlines()
+                if (" sort(" in line and f",{rows_i}]" in line)
+                or f"s32[{bucket},{rows_i}]" in line]
+    scores_bytes = 4 * max(bucket, 8) * rows_i      # 8 sublanes a tile
+    assert mem.temp_size_in_bytes < 1.1 * scores_bytes
+    # the picked chunks come through serve_dist._fetch_chunks, one op on
+    # the device's line, not through a gather's `while` of b*k slices
+    assert _has_kernel(compiled) and " while(" not in text

@@ -136,6 +136,154 @@ def test_more_users_and_items_than_one_shard_row():
 
 
 # ---------------------------------------------------------------------------
+# the shard's own selection: stable_topk's two stages on a long shard, one
+# whole sort on a short one, the same candidates either way
+# ---------------------------------------------------------------------------
+
+K_SEL = 3                           # two stages from 2 (k + 1) = 8 chunks
+_LONG = 2 * (K_SEL + 1) * topk.CHUNK
+#: items over four shards -> the branch of chunk_plan a shard takes
+SHARD_SHAPES = {
+    "short": 4 * 300,                       # one whole sort a shard
+    "chunked": 4 * _LONG,                   # 8 whole chunks, no ragged end
+    "ragged": 4 * (_LONG + 37),             # 37 items past the last chunk
+    "padded": 4 * (_LONG + 37) - 3,         # + 3 padding rows, last shard
+}
+TOP, OVERFLOW = 120, 4              # the tied level; the row of +-inf
+
+
+def _levels(n_items, rows_dev):
+    """(6, n_items) small integers of magnitude 2 and up, so that every
+    score is exact in float32 and in int8 and equal levels are exact
+    ties; the items whose scores are NaN for every user, a whole chunk
+    of a shard among them; and per row the expected top-K_SEL (None:
+    whatever the order gives). Rows: 0 ties inside one chunk, 1 across
+    a chunk boundary, 2 across shard boundaries and in the last item of
+    a shard (its ragged end), 3 all equal, 4 the row whose user scale
+    overflows float32 (-inf under a positive level, +inf under a
+    negative one), 5 random."""
+    rng = np.random.default_rng(n_items)
+    L = rng.integers(2, 100, size=(6, n_items)) * rng.choice(
+        [-1, 1], size=(6, n_items))
+    step = min(topk.CHUNK, rows_dev // 4)
+    inside = [rows_dev + 3, rows_dev + 4, rows_dev + 9, rows_dev + 11]
+    chunks = [rows_dev + step - 1, rows_dev + step,
+              rows_dev + 3 * step + 7, rows_dev + 3 * step + 8]
+    shards = [rows_dev - 1, rows_dev, 3 * rows_dev - 1, 3 * rows_dev + 1]
+    for row, at in enumerate((inside, chunks, shards)):
+        L[row, at] = TOP
+    L[3] = 7
+    plus_inf = [rows_dev - 2, 2 * rows_dev + step + 5]
+    L[OVERFLOW] = np.abs(L[OVERFLOW])
+    L[OVERFLOW, plus_inf] = -2
+    nan_items = np.r_[5, 6, rows_dev + 2,
+                      2 * rows_dev:2 * rows_dev + step]
+    expected = [inside[:3], chunks[:3], shards[:3], [0, 1, 2],
+                plus_inf + [0], None]
+    return L, nan_items, expected
+
+
+def _lexsort_topk(scores, k):
+    """First k of each row by (score descending, index ascending), NaN
+    after everything: lax.sort's order on the negated scores."""
+    ix = np.arange(scores.shape[1])
+    return np.stack([np.lexsort((ix, -row))[:k] for row in scores])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+@pytest.mark.parametrize("shape", list(SHARD_SHAPES))
+def test_shard_selection_is_the_whole_shard_sorts(shape, dtype):
+    """Four shards whose shape takes each branch of ops.topk.chunk_plan,
+    fp32 and int8, at k = 3 and at a k past one shard's rows: the
+    indices are a NumPy lexsort's over the padded address space (what
+    the two-key sort of the whole shard returned: a padding row scores
+    NEG_INF under a global id >= n_items) and the replicated kernel's;
+    the int8 layouts agree to the bit. Only an answer that reaches
+    below NEG_INF (the -inf and NaN scores of a poisoned model) can
+    tell a padded layout from an unpadded one, as it always could."""
+    from predictionio_tpu.ops import quant
+
+    n_items = SHARD_SHAPES[shape]
+    rows_dev = serve_dist._rows_dev(n_items, 4)
+    assert (topk.chunk_plan(rows_dev, K_SEL) is None) == (shape == "short")
+    assert (rows_dev % topk.CHUNK != 0) == (shape != "chunked")
+    assert (4 * rows_dev > n_items) == (shape == "padded")
+    L, nan_items, expected = _levels(n_items, rows_dev)
+    rank = L.shape[0]
+    u_scale = np.ones(rank, np.float32)
+    u_scale[OVERFLOW] = -3e38           # times a level of 2 and up: -inf
+    v_scale = np.ones(n_items, np.float32)
+    v_scale[nan_items] = np.nan
+    if dtype == "int8":
+        qf = quant.QuantizedFactors(
+            u_q=np.eye(rank, dtype=np.int8), u_scale=u_scale,
+            v_q=L.T.astype(np.int8), v_scale=v_scale)
+        sharded = serve_dist.shard_factors(None, None, n_shards=4, quant=qf)
+        replicated = quant.QuantizedServing.build(qf)
+        assert not replicated.fused
+        unpadded = (4 * rows_dev == n_items
+                    and int(replicated.vt_q.shape[1]) == n_items)
+    else:
+        U = np.diag(u_scale)
+        V = L.T.astype(np.float32) * v_scale[:, None]
+        sharded = serve_dist.shard_factors(U, V, n_shards=4)
+        unpadded = 4 * rows_dev == n_items
+    assert sharded.n_shards == 4 and sharded.rows_dev_i == rows_dev
+    with np.errstate(over="ignore"):
+        S = L.astype(np.float32) * u_scale[:, None] * v_scale[None, :]
+    assert np.isinf(S[OVERFLOW]).sum() == n_items - nan_items.size
+    S_pad = np.full((rank, 4 * rows_dev), topk.NEG_INF, np.float32)
+    S_pad[:, :n_items] = S
+    ixs = np.arange(rank, dtype=np.int32)
+    for k in (K_SEL, rows_dev + 2):
+        sv, si = jax.device_get(sharded.topk(ixs, k))
+        np.testing.assert_array_equal(si, _lexsort_topk(S_pad, k))
+        np.testing.assert_array_equal(
+            np.take_along_axis(S_pad, si, axis=1), sv)
+        if k == K_SEL:
+            for row, want in enumerate(expected):
+                if want is not None and (unpadded or row != OVERFLOW):
+                    assert list(si[row]) == want, (row, si[row])
+        if dtype == "int8":
+            rv, ri = jax.device_get(replicated.topk(ixs, k))
+        else:
+            rv, ri = _replicated(U, V, ixs, k)
+        # a NaN is not above NEG_INF either
+        rows = (slice(None) if unpadded
+                else (sv > topk.NEG_INF).all(axis=1))
+        np.testing.assert_array_equal(si[rows], ri[rows])
+        if dtype == "int8":
+            np.testing.assert_array_equal(sv[rows], rv[rows])
+        else:
+            _assert_same_scores(sv[rows], rv[rows])
+
+
+@pytest.mark.parametrize("b", [1, 4, 16, 64])
+def test_chunk_fetch_kernel_copies_what_the_gather_copies(b):
+    """serve_dist._fetch_chunks (what a mesh of TPUs hands stable_topk
+    for its `merge` stage), in Pallas's interpreter at every serving
+    bucket: the chunks' own bits, NaN and -inf among them, on rows
+    with a ragged end; and stable_topk through it is stable_topk."""
+    n, k, L = 9 * topk.CHUNK + 37, K_SEL, topk.CHUNK
+    rng = np.random.default_rng(b)
+    rows = rng.integers(-5, 5, size=(b, n)).astype(np.float32)
+    rows[0, 5], rows[-1, 700], rows[b // 2, n - 40] = np.nan, -np.inf, 9.0
+    picked = rng.integers(0, n // L, size=(b, k)).astype(np.int32)
+    picked[0, 0], picked[-1, 1] = 0, n // L - 1
+    want = np.stack([[rows[r, c * L:(c + 1) * L] for c in picked[r]]
+                     for r in range(b)])
+    fetch = lambda r, p, l: serve_dist._fetch_chunks(      # noqa: E731
+        r, p, l, interpret=True)
+    got = fetch(jnp.asarray(rows), jnp.asarray(picked), L)
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  want.view(np.uint32))
+    assert topk.chunk_plan(n, k) == (L, 9)
+    for a, w in zip(topk.stable_topk(jnp.asarray(rows), k, fetch=fetch),
+                    topk.stable_topk(jnp.asarray(rows), k)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(w))
+
+
+# ---------------------------------------------------------------------------
 # mode resolution
 # ---------------------------------------------------------------------------
 
@@ -570,21 +718,31 @@ def test_model_bytes_of_a_sharded_model_are_one_devices(four_devices):
         assert shard.data.nbytes == 258 * rank * 4
 
 
-@pytest.mark.parametrize("mode,layout,shards", [
-    ("on", "row-sharded", 4), ("off", "replicated", 1)])
+@pytest.mark.parametrize("mode,layout,shards,n_items,selection", [
+    ("on", "row-sharded", 4, 1_031, "sort"),
+    # 22 = 2 (10 + 1) whole chunks a shard, and a ragged end
+    ("on", "row-sharded", 4, 4 * 22 * topk.CHUNK + 6, "chunked L=512 C=22"),
+    ("off", "replicated", 1, 1_031, "sort")],
+    ids=["sharded-short", "sharded-long", "replicated"])
 def test_status_batching_block_names_the_layout(
-        memory_storage, four_devices, monkeypatch, mode, layout, shards):
+        memory_storage, four_devices, monkeypatch, mode, layout, shards,
+        n_items, selection):
+    """... and the selection its programs were built with: on the
+    row-sharded layout ops.topk.selection_name over ONE shard's rows,
+    the static shape serve_dist hands stable_topk."""
     from predictionio_tpu.serving.registry import model_hbm_bytes
 
     monkeypatch.setenv("PIO_SERVE_DEVICE_MS", "1e9")   # stay on the device
-    U, V = _spectrum_factors(203, 1_031, 16, seed=11)
+    U, V = _spectrum_factors(203, n_items, 16, seed=11)
     api = _deploy(memory_storage, U, V, shard_serving=mode)
     try:
         b = api.handle("GET", "/")[1]["batching"]
         assert (b["layout"], b["shards"]) == (layout, shards)
+        assert b["topkSelection"] == {"10": selection}
         if mode == "on":
-            assert b["perShardBytes"] == (51 + 258) * 16 * 4
-            assert b["topkSelection"] is None
+            rows_dev_i = -(-n_items // 4)
+            assert b["perShardBytes"] == (51 + rows_dev_i) * 16 * 4
+            assert selection == topk.selection_name(rows_dev_i, 10)
         else:
             assert b["perShardBytes"] == U.nbytes + V.nbytes
         assert b["perShardBytes"] == model_hbm_bytes(api.models)

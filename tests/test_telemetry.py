@@ -416,6 +416,7 @@ def test_status_names_the_topk_selection_from_the_kernels_shape_test(
     programs were built with, from ops/topk.py's own test of the static
     shape: `sort` for the six-item catalog, `chunked` with its L and C
     once the same instance holds a catalog long enough for two stages;
+    the same test over one shard's rows where the layout is row-sharded;
     null when the CPU harness moved serving to host arrays."""
     import numpy as np
 
@@ -459,6 +460,19 @@ def test_status_names_the_topk_selection_from_the_kernels_shape_test(
         st, body = api.handle("POST", "/queries.json", body=json.dumps(
             {"user": "u1", "num": 10}).encode())
         assert st == 200 and len(body["itemScores"]) == 10
+    finally:
+        api.close()
+    # row-sharded over the harness's devices: the same shape test, over
+    # the rows of ONE shard, which is what each device selects from
+    api = QueryAPI(storage=memory_storage, engine=RecommendationEngine(),
+                   config=ServerConfig(batching="on", shard_serving="on"))
+    try:
+        sharding = api.models[0].sharding
+        rows_dev_i = sharding.rows_dev_i
+        assert rows_dev_i == -(-n_items // sharding.n_shards) < n_items
+        _, info = api.handle("GET", "/")
+        assert info["batching"]["topkSelection"] == {
+            "10": topk.selection_name(rows_dev_i, 10)}
     finally:
         api.close()
     monkeypatch.setenv("PIO_SERVE_DEVICE_MS", "0")
