@@ -79,7 +79,7 @@ REHEARSE = int(os.environ.get("CHIP_SMOKE_REHEARSE", "0") or 0)
 # lambda 0.01, and there the bf16 rounding alone moves a prediction by
 # 0.09 stars (0.077 over all random pairs on the chip).
 FACTOR_RMSE_TOL = 0.05
-LAYOUT_INT8 = "replicated int8 (XLA)"
+LAYOUT_INT8 = "replicated int8"
 
 
 def say(tag, **fields):
@@ -118,13 +118,14 @@ def run_child(tag, argv, env):
     """Run one child to its end; its stdout lines that are JSON objects
     come back as a list. A non-zero exit fails the smoke."""
     before = cache_stats()
-    t0 = time.time()
+    t0 = time.perf_counter()
     proc = subprocess.run(argv, env=env, cwd=WORK, stdout=subprocess.PIPE,
                           text=True)
     sys.stdout.write(proc.stdout)
     sys.stdout.flush()
     after = cache_stats()
-    say(f"{tag}:child", rc=proc.returncode, seconds=round(time.time() - t0, 2),
+    say(f"{tag}:child", rc=proc.returncode,
+        seconds=round(time.perf_counter() - t0, 2),
         compile_cache={"dir": cache_dir(), "before": before, "after": after})
     if proc.returncode != 0:
         fail(f"{tag} child exited {proc.returncode}")
@@ -341,7 +342,7 @@ class Deploy:
         self.tag, self.port = tag, free_port()
         self.log = os.path.join(WORK, f"{tag}.log")
         self.before = cache_stats()
-        self.t0 = time.time()
+        self.t0 = time.perf_counter()
         self.proc = subprocess.Popen(
             [sys.executable, "-m", "predictionio_tpu.tools.cli", "deploy",
              "--engine-dir", edir, "--telemetry", "--ip", "127.0.0.1",
@@ -362,12 +363,13 @@ class Deploy:
                     break
             except (urllib.error.URLError, OSError):
                 pass
-            if time.time() > deadline:
+            if time.perf_counter() > deadline:
                 self.stop()
                 self.tail()
                 fail(f"{self.tag}: not ready in {READY_TIMEOUT_S}s")
             time.sleep(0.5)
-        say(f"{self.tag}:ready", seconds=round(time.time() - self.t0, 2))
+        say(f"{self.tag}:ready",
+            seconds=round(time.perf_counter() - self.t0, 2))
         return self
 
     def tail(self):
@@ -386,7 +388,7 @@ class Deploy:
     def __exit__(self, *exc):
         self.stop()
         say(f"{self.tag}:child", rc=self.proc.returncode,
-            seconds=round(time.time() - self.t0, 2),
+            seconds=round(time.perf_counter() - self.t0, 2),
             compile_cache={"dir": cache_dir(), "before": self.before,
                            "after": cache_stats()})
 
@@ -405,7 +407,7 @@ def inspect_deploy(dep, want_shards, fp32_bytes):
     AOT program built."""
     dev = dep.get("/debug/device.json")
     if not dev.get("telemetry"):
-        fail("/debug/device.json says telemetry is off")
+        fail("telemetry is off, says /debug/device.json")
     devices = dev["devices"]
     platform, kind = devices[0]["platform"], devices[0]["kind"]
     if platform == "cpu" and not REHEARSE:
@@ -420,9 +422,6 @@ def inspect_deploy(dep, want_shards, fp32_bytes):
     if f"pio_compile_cache_entries {cache_stats()['entries']}" not in metrics:
         fail("the deploy's compile cache is not " + cache_dir())
     quant, shard = info.get("quant") or {}, info.get("sharding") or {}
-    if quant.get("enabled") and (quant.get("fused")
-                                 or quant.get("interpret")):
-        fail(f"the fused kernel served on the default path: {quant}")
     if shard.get("enabled"):
         layout = (f"row-sharded x{shard['shards']} "
                   f"{shard.get('dtype', 'float32')}")
@@ -625,14 +624,14 @@ def main():
         fail(f"{ENGINE_JSON} is missing: the repo is not beside this file")
     shutil.rmtree(WORK, ignore_errors=True)
     os.makedirs(WORK)
-    t0 = time.time()
+    t0 = time.perf_counter()
     device = four_chips() if args.chips == 4 else one_chip()
     leaked = sorted(m for m in sys.modules
                     if m.split(".")[0] in ("jax", "jaxlib",
                                            "predictionio_tpu"))
     if leaked:
         fail(f"the parent imported {leaked[:5]}")
-    say("done", seconds=round(time.time() - t0, 2), chips=args.chips)
+    say("done", seconds=round(time.perf_counter() - t0, 2), chips=args.chips)
     if REHEARSE:
         print("chip_smoke: rehearsal only, no result", file=sys.stderr)
         return 3

@@ -154,16 +154,6 @@ ENV_VARS: Dict[str, str] = {
         "recall@k floor below which auto-mode quantized serving falls "
         "back to fp32 at deploy time (default 0.99 — the KNOWN_ISSUES "
         "#12 ranking-parity contract)",
-    "PIO_SERVE_FUSED":
-        "fused Pallas score->mask->top-k kernel for quantized serving: "
-        "auto (default) and 0/off = the XLA int8 kernel on every "
-        "platform (the TPU compiler refuses the Pallas kernel's block "
-        "shapes) | 1/on = the Pallas kernel (compiled on TPU, where "
-        "the compiler's error propagates; interpreter mode off-TPU, "
-        "slow but bit-equivalent)",
-    "PIO_SERVE_FUSED_TILE":
-        "item-axis tile of the fused quantized top-k kernel "
-        "(default 512 lanes)",
     "PIO_SERVE_WARMUP_FLUSHES":
         "flush count that ends the recompile watchdog's warmup when no "
         "explicit AOT-complete mark arrives (default 32)",

@@ -46,7 +46,6 @@ device programs — and turns its two silent failure modes into counters:
                                 jax.live_arrays() census
       pio_compile_cache_entries / pio_compile_cache_bytes
                                 the persistent compile cache dir
-                                (promoted from bench's one-off detail)
 
   plus a human-readable ``GET /debug/device.json`` on every daemon
   (served by telemetry.handle_route).
@@ -77,8 +76,8 @@ logger = logging.getLogger("predictionio_tpu.devicewatch")
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-#: compile durations: 10 ms CPU re-traces through the bench's measured
-#: ~400 s cold remote-compile of the full hybrid trainer
+#: compile durations: 10 ms CPU re-traces through the minutes a cold
+#: compile of the full hybrid trainer takes (PERF.md section 6, PR 25)
 _COMPILE_BUCKETS = (0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 10.0, 30.0, 60.0,
                     120.0, 300.0, 600.0)
 
@@ -165,7 +164,7 @@ def mark_serving_warmup_done() -> None:
     """Arm the steady-state detector now. The AOT deploy path
     (serving/aot.py) calls this the moment its prebuild completes —
     warmup end is an explicit AOT-complete mark, not a flush count —
-    and the bench/tests call it after a deliberate warmup burst."""
+    and tests call it after a deliberate warmup burst."""
     global _warmup_done
     with _lock:
         _warmup_done = True
@@ -317,7 +316,7 @@ def watch_jit(fn: Any, name: str, phase: str = "other") -> Any:
 
 
 # ---------------------------------------------------------------------------
-# readback (doctor / bench / tests)
+# readback (doctor / tests)
 # ---------------------------------------------------------------------------
 
 def _family_sum(name: str) -> float:
@@ -360,8 +359,7 @@ def compile_cache_dir() -> str:
 
 
 def compile_cache_stats() -> Dict[str, int]:
-    """{entries, bytes} of the persistent compile cache directory (the
-    bench's one-off `compile_cache` detail, promoted to a live gauge)."""
+    """{entries, bytes} of the persistent compile cache directory."""
     d = compile_cache_dir()
     if not d:
         return {"entries": 0, "bytes": 0}
@@ -444,8 +442,8 @@ def host_rss_bytes() -> Optional[int]:
 
 
 class RssWatcher:
-    """Sampling thread for peak-memory claims (the bench train-stream
-    leg and the 1 B-rating soak): records the peak RSS and the peak of
+    """Sampling thread for peak-memory claims (the streamed-train tests
+    and the 1 B-rating soak): records the peak RSS and the peak of
     RSS minus live jax array bytes — the latter is what isolates the
     HOST pipeline's footprint on CPU backends, where device buffers
     live in the same RSS (KNOWN_ISSUES #14). Timing uses sleep

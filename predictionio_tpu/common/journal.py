@@ -25,8 +25,7 @@ read a cheap incremental tail (``pio events --follow`` polls it);
 
 Cost model: events are RARE by construction (breaker transitions, crash
 repairs, deploys — not requests), so ``emit`` can afford a lock + a
-deque append unconditionally. The serving hot path never emits, which is
-what the bench's journal leg proves (journal-on p99 within 5% of off).
+deque append unconditionally. The serving hot path never emits.
 ``PIO_JOURNAL=0`` disables recording outright — existing endpoints'
 bytes are unchanged either way (the journal only ever ADDS a new
 surface), asserted by test.
@@ -133,11 +132,6 @@ _journal = _Journal()
 def clear() -> None:
     """Drop every record and reset seq (tests)."""
     _journal.clear()
-
-
-def events_total() -> int:
-    """Events emitted since process start (bench/benchtrend detail)."""
-    return _journal.next_seq - 1
 
 
 def emit(category: str, message: str, level: str = INFO,

@@ -29,9 +29,8 @@ pieces plus a request-scoped degradation flag:
   transport boundary, driven by ``PIO_FAULT_SPEC`` or the programmatic
   :func:`install`. Supported faults: connection drops (before send and
   after send / before response), added latency, synthetic 5xx, and
-  truncated payloads. This is how the chaos suite and the bench
-  robustness leg exercise every failure path without root privileges or
-  packet filters.
+  truncated payloads. This is how the chaos suite exercises every
+  failure path without root privileges or packet filters.
 
 - :func:`note_degraded` / :func:`pop_degraded` — a thread-local flag a
   serving-path side-channel lookup sets when it fails soft (answering
@@ -535,8 +534,8 @@ _install_lock = threading.Lock()
 
 
 def install(spec: str, seed: Optional[int] = None) -> FaultInjector:
-    """Programmatically install a process-wide fault injector (tests,
-    bench). Returns it; undo with :func:`clear`."""
+    """Programmatically install a process-wide fault injector (tests).
+    Returns it; undo with :func:`clear`."""
     global _installed
     inj = FaultInjector(spec, seed=seed)
     with _install_lock:

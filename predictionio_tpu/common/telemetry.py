@@ -55,7 +55,7 @@ def on() -> bool:
     """Is optional (new-site) telemetry recording enabled?
 
     ``PIO_TELEMETRY=1`` turns it on; :func:`set_enabled` overrides for
-    tests and the bench. One dict lookup — cheap enough to call on every
+    tests. One dict lookup — cheap enough to call on every
     request without caching games."""
     if _override is not None:
         return _override

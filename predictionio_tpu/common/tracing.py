@@ -230,7 +230,7 @@ def pin_current(reason: str) -> None:
 
 
 def tail_retained() -> int:
-    """Traces currently pinned in the tail ring (bench detail)."""
+    """Traces currently pinned in the tail ring."""
     return _tail.retained()
 
 

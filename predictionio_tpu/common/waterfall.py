@@ -96,7 +96,7 @@ _override: Optional[bool] = None
 
 def enabled() -> bool:
     """Is waterfall sampling on? ``PIO_WATERFALL=1`` turns it on;
-    :func:`set_enabled` overrides for tests and the bench."""
+    :func:`set_enabled` overrides for tests."""
     if _override is not None:
         return _override
     return os.environ.get("PIO_WATERFALL", "0") == "1"
@@ -347,7 +347,7 @@ def end(rec: Optional[RequestRecord]) -> None:
 
 
 def clear() -> None:
-    """Drop every slow-ring entry (tests/bench legs)."""
+    """Drop every slow-ring entry (tests)."""
     _ring.clear()
 
 

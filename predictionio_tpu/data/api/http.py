@@ -54,7 +54,7 @@ from predictionio_tpu.common import devicewatch, resilience, telemetry, tracing
 def transport_mode(explicit: Optional[str] = None) -> str:
     """Resolve the transport: explicit argument > ``PIO_TRANSPORT`` env >
     ``threaded``. Unknown values raise — a typo'd transport silently
-    falling back to threaded would invalidate every async bench claim."""
+    falling back to threaded would invalidate every async claim."""
     mode = (explicit or os.environ.get("PIO_TRANSPORT", "threaded")).lower()
     if mode not in ("threaded", "async"):
         raise ValueError(
@@ -373,7 +373,7 @@ class AsyncHTTPServer:
     """asyncio transport with the ThreadingHTTPServer lifecycle surface
     (``serve_forever`` / ``shutdown`` / ``server_close`` /
     ``server_address``) so every existing call site — the daemons'
-    serve loops, the bench, the tests — runs unmodified on either
+    serve loops, the tests — runs unmodified on either
     transport.
 
     The listening socket binds in the constructor (callers read

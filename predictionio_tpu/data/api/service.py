@@ -70,8 +70,7 @@ def batch_bulk_insert() -> bool:
     call (default) or one at a time (``PIO_BATCH_BULK_INSERT=0``). Bulk
     is the ingest hot path — one storage-lock round trip and one WAL
     group-commit wait per request; per-item keeps the pre-bulk behavior
-    where a storage failure mid-batch isolates to that item (and is the
-    configuration the bench's threaded baseline leg reproduces)."""
+    where a storage failure mid-batch isolates to that item."""
     return os.environ.get("PIO_BATCH_BULK_INSERT", "1") != "0"
 
 

@@ -36,8 +36,7 @@ Multi-writer topology (the HBase-parity story, HBEventsUtil.scala:84-131:
 MD5-prefixed rowkeys let many region servers ingest one app's events):
 
 - WITHIN one event-server process, appends are RLock-serialized and any
-  number of HTTP connections share the writer — `bench.py` measures
-  POST /batch/events.json at 1/8/32/128 parallel connections. Ingestion
+  number of HTTP connections share the writer. Ingestion
   is parse-bound (GIL), so connections add concurrency headroom, not
   linear throughput; the lock itself is not the bottleneck. Concurrent
   appends GROUP-COMMIT: inserts enlisting within one bounded window
@@ -126,7 +125,7 @@ def _wal_fsync_mode() -> str:
 
 
 #: unconditional (legacy-tier) group-commit counters, mutated only under
-#: the events lock; the bench ingest leg reads deltas of these, and the
+#: the events lock; tests read deltas of these, and the
 #: registry histograms below mirror them when PIO_TELEMETRY=1
 WAL_GROUP_STATS: Dict[str, float] = {
     "commits": 0, "events": 0, "flush_s": 0.0, "max_events": 0}

@@ -605,8 +605,8 @@ class StorageClient:
       breaker shared by every client in the process; when open, calls
       fast-fail with CircuitOpenError instead of queueing on a dead
       endpoint.
-    - PIO_FAULT_SPEC — transport-boundary fault injection (chaos tests
-      and the bench robustness leg; common/resilience.py).
+    - PIO_FAULT_SPEC — transport-boundary fault injection (chaos
+      tests; common/resilience.py).
     """
 
     def __init__(self, config):

@@ -567,9 +567,8 @@ def find_columnar(
     """Single-pass events → columnar buffers + vocabs.
 
     `timings`, when given, receives {"read_io": s, "read_encode": s} on the
-    columnar fast path (store scan vs vocab-encode split — the bench
-    reports these as read sub-phases; under the overlapped pipeline,
-    read_io is the time actually spent *waiting* on chunk decode).
+    columnar fast path (store scan vs vocab-encode split; under the
+    overlapped pipeline, read_io is the time actually spent *waiting* on chunk decode).
 
     `stage=True` additionally asks for device-resident mirrors of the
     encoded arrays (`ColumnarEvents.staged`, ops/staging.py): each chunk is

@@ -18,13 +18,12 @@ that
   event_code / rating / time_ms against a synthesized string pool), so
   the generator composes with the streaming train path exactly like the
   event log does;
-- matches the bench's workload family: zipf-ish item popularity
-  (``1/rank^a``), log-normal user activity, half-star ratings — the
-  profile ``bench.py synth_codes`` established, now seeded and chunked.
+- one workload family: zipf-ish item popularity (``1/rank^a``),
+  log-normal user activity, half-star ratings, seeded and chunked.
 
 Surfaces:
 
-- :func:`chunk_source` — the library surface the bench and the
+- :func:`chunk_source` — the library surface chip_smoke.py and the
   streaming pipeline consume: ``(pool, re-iterable chunk iterator)``;
 - :func:`training_data` — synthetic events straight to a recommendation
   ``TrainingData`` through the real columnar-encode pipeline (streamed
@@ -48,7 +47,7 @@ import numpy as np
 #: fixed so event ids/timestamps are reproducible)
 _BASE_MS = 1_600_000_000_000
 
-#: pool layout mirrors bench.seed_event_store: fixed strings first so
+#: pool layout: fixed strings first so
 #: code 0 is always "rate" and entity/target codes are offset by 3
 _FIXED_POOL = ("rate", "user", "item")
 
@@ -86,9 +85,8 @@ def query_keys(n: int, seed: int, exponent: float = 1.1,
                pool: int = 1024) -> np.ndarray:
     """``n`` seeded zipfian key indices in [0, pool) — rank 0 hottest.
 
-    The bench pumps these through the router so cache-hit-ratio and
-    hot-key legs measure the skewed workload real front doors see,
-    instead of uniform-random keys that defeat any cache. Same draw as
+    The skewed workload real front doors see, instead of
+    uniform-random keys that defeat any cache. Same draw as
     ``chunk_codes``: a counter-derived Philox stream + searchsorted over
     ``_zipf_cdf``, so every (n, seed, exponent, pool) is reproducible
     across hosts."""

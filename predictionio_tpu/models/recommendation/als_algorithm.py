@@ -137,11 +137,10 @@ def _big_layout_store(td, use_mesh: bool, data, crc=None) -> None:
 
 #: layout-reuse instrumentation: hits = a train (or prepare_layout) served
 #: its device layout from either cache tier; builds = prepare_ratings ran.
-#: The bench's eval-grid leg reports the delta as `eval_grid_reuse_hits`.
 #: Registry-backed (common/telemetry.py): the counters live in the
 #: process metrics registry (`pio_layout_cache_total{result=...}` on
 #: GET /metrics); this dict-like view keeps every existing call site
-#: (`LAYOUT_STATS["hits"] += 1`, the bench's delta reads) byte-compatible.
+#: (`LAYOUT_STATS["hits"] += 1`, the tests' delta reads) byte-compatible.
 from predictionio_tpu.common import telemetry as _telemetry
 
 LAYOUT_STATS = _telemetry.RegistryDict(
@@ -194,7 +193,7 @@ def _ensure_layout(ctx, td, use_mesh: bool):
     of re-sorting the same ratings per variant. Eval-scale data caches on
     the TrainingData object; FULL-scale data (td.n > 2M) caches ONE entry
     process-wide keyed on a content fingerprint, so repeat trains over an
-    unchanged event store (the bench's slope passes; retrain-on-deploy)
+    unchanged event store (retrain-on-deploy)
     skip the transfer + in-HBM sorts entirely. The retained HBM (~0.5 GB
     at 20M) is bounded at one entry; PIO_ALS_LAYOUT_CACHE=0 disables
     retention."""
@@ -545,12 +544,11 @@ class ALSAlgorithm(Algorithm):
         persistent compile cache still amortizes them per machine).
 
         A QUANTIZED replicated model enumerates the (bucket x k)
-        quantized programs (fused Pallas or XLA fallback, whichever the
-        deploy resolved) plus the per-k inline quant programs, so
-        `post_warmup_recompiles == 0` holds with quant (+fused) on.
-        Quant programs depend on the deploy environment's mode/fused
-        resolution, so — like sharded — the declared train-time export
-        skips them."""
+        quantized programs plus the per-k inline quant programs, so
+        `post_warmup_recompiles == 0` holds with quant on. Quant
+        programs depend on the deploy environment's mode resolution,
+        so — like sharded — the declared train-time export skips
+        them."""
         from predictionio_tpu.serving import aot
 
         sharding = getattr(model, "sharding", None)
@@ -664,8 +662,7 @@ class ALSAlgorithm(Algorithm):
             waterfall.note("shards", sharding.n_shards)
         elif quant is not None:
             # quantized device path (ops/quant.py): ONE dequantize-free
-            # dispatch — int8 x int8 scores + fused rescale + top-k (the
-            # fused Pallas kernel when the deploy resolved it). The
+            # dispatch — int8 x int8 scores + fused rescale + top-k. The
             # quant note turns "execute is slow" into "it's the int8
             # path", one hop from /debug/slow.json.
             rows = _device_rows(quant.topk, ixs, valid, k)

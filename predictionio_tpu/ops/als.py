@@ -261,9 +261,9 @@ def prepare_ratings(
 # kernel= param / PIO_ALS_KERNEL env var):
 #
 #   "hybrid" (default) — dense-hot head on the MXU + csrb tail; see the
-#       hybrid section below. Measured 88 ms/iter at ML-20M rank 10 on a
-#       v5e (vs 150 for csrb, 1351 for round-3 scan), identical RMSE.
-#       Falls back to csrb when the item set is too small to split.
+#       hybrid section below (its time on this chip: PERF.md section
+#       5; csrb and scan are not measured there). Falls back to csrb
+#       when the item set is too small to split.
 #
 #   "csrb" — row-aligned mini-block layout + wide-row gather.
 #       Each row's entries are padded to a multiple of b (=32) so every
@@ -275,9 +275,7 @@ def prepare_ratings(
 #       vs 8% when gathering bare (r,) factor rows), scales by the two
 #       per-entry coefficients, and block-reduces to one partial per
 #       mini-block. The only scatter left is the mini-block combine:
-#       ~nnz/b sorted segment-sum updates instead of nnz. Measured on a
-#       v5e at 20M nnz / rank 10: 78 ms per side vs 390 ms for "scan"
-#       (and vs ~1.35 s/iter end-to-end in round 3).
+#       ~nnz/b sorted segment-sum updates instead of nnz.
 #
 #   "scan" — the round-2/3 kernel: chunked gather + in-loop flattened
 #       outer products + per-entry sorted segment_sum with the full
@@ -747,8 +745,7 @@ def solve_factors(A: jnp.ndarray, b: jnp.ndarray, reg: jnp.ndarray) -> jnp.ndarr
                                                        solver_choice)
         if solver_choice() == "pallas":
             # all sweeps in VMEM: one tile read + solution write per block
-            # (measured 8.2 -> 4.4 ms at the bench's 138k x 10 shape; the
-            # XLA sweep materializes every elimination step to HBM)
+            # (the XLA sweep materializes every elimination step to HBM)
             return solve_factors_pallas(A, b, reg)
     A = A + reg[:, None, None] * jnp.eye(r, dtype=A.dtype)[None]
     if r > 32:

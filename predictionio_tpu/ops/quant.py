@@ -12,16 +12,16 @@ int8 ``dot_general`` with ``preferred_element_type=int32`` (EXACT
 integer arithmetic — no accumulation-order nondeterminism), then one
 fused elementwise rescale ``s32 * (scale_u[u] * scale_v)`` recovers
 fp32 scores. Because the integer part is exact and the rescale is
-elementwise, every quantized serving path — the XLA fallback here, the
-fused Pallas kernel (ops/topk_pallas.py), and the row-sharded shard_map
-kernel (parallel/serve_dist.py) — produces BIT-IDENTICAL (values,
-indices), ties included (stable_topk's lowest-index rule).
+elementwise, both quantized serving paths — the replicated kernel here
+and the row-sharded shard_map kernel (parallel/serve_dist.py) — produce
+BIT-IDENTICAL (values, indices), ties included (stable_topk's
+lowest-index rule).
 
 Contract: bit-parity against the fp32 path is off the table for int8,
 so the gate is RANKING parity — recall@k >= 0.99 and exact-match@1 >=
-0.999 on the trained model (tier-1 + the bench's strict gate;
-KNOWN_ISSUES #12). :func:`ranking_parity` measures it at deploy time on
-a deterministic user sample; "auto" mode falls back to fp32 serving
+0.999 on the trained model (tier-1; KNOWN_ISSUES #12).
+:func:`ranking_parity` measures it at deploy time on a deterministic
+user sample; "auto" mode falls back to fp32 serving
 (and says so on the `pio doctor` quant line) when the model misses the
 bar, "on" keeps quantizing and records the value.
 
@@ -57,6 +57,10 @@ logger = logging.getLogger("predictionio_tpu.quant")
 
 #: symmetric int8 range: round(row / scale) lands in [-127, 127]
 QMAX = 127.0
+
+#: the transposed item layout pads its item axis up to a multiple of
+#: this many columns (4 x the 128-lane register width)
+ITEM_TILE = 512
 
 #: the fp32 itemsize quantization is measured against
 _F32 = 4
@@ -325,8 +329,7 @@ def serving_enabled(mode: Optional[str] = None) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# the dequantize-free serving kernels (XLA fallback; ops/topk_pallas.py
-# holds the fused Pallas variant, bit-identical to these)
+# the dequantize-free serving kernels
 # ---------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("k", "n_items"))
@@ -348,10 +351,10 @@ def topk_for_users_quant(
     padding, masked to NEG_INF so they can never rank — under either
     selection stable_topk makes over the n_pad columns (two stages on
     a long catalog, the whole-row sort on a short one: the same bits).
-    Bit-identical (values AND indices, ties included) to the fused
-    Pallas kernel and the sharded quant kernel — the integer scores are
-    exact and the rescale is elementwise, so there is no
-    accumulation-order drift between the paths."""
+    Bit-identical (values AND indices, ties included) to the sharded
+    quant kernel — the integer scores are exact and the rescale is
+    elementwise, so there is no accumulation-order drift between the
+    paths."""
     with jax.named_scope("gather"):
         Q = jnp.take(u_q, user_ixs, axis=0)                  # (b, r)
         su = jnp.take(u_scale, user_ixs, axis=0)             # (b,)
@@ -446,12 +449,7 @@ class QuantizedServing:
     ``topk_for_users`` / ``topk_for_user`` calls.
 
     The item matrix lives TRANSPOSED, ``(rank, n_pad)`` with n_pad
-    rounded up to the fused kernel's tile — one layout serves both the
-    XLA fallback and the Pallas kernel, so enabling/disabling the fused
-    path never re-lays-out HBM. ``fused``/``interpret`` are resolved
-    ONCE at build (PIO_SERVE_FUSED; ops/topk_pallas.fused_choice) so
-    the jit statics — and therefore the AOT-prebuilt programs — are
-    stable for the lifetime of the deploy."""
+    rounded up to :data:`ITEM_TILE`."""
     u_q: Any                 # (n_users, r) int8, device
     u_scale: Any             # (n_users,) fp32, device
     vt_q: Any                # (r, n_pad) int8, device
@@ -459,51 +457,27 @@ class QuantizedServing:
     n_users: int
     n_items: int
     rank: int
-    tile: int
-    fused: bool
-    interpret: bool
     recall: Optional[float] = None
     exact1: Optional[float] = None
 
     @classmethod
     def build(cls, qf: QuantizedFactors) -> "QuantizedServing":
-        from predictionio_tpu.ops import topk_pallas
-
-        tile = topk_pallas.serve_tile()
-        fused, interpret = topk_pallas.fused_choice()
         n_items = qf.n_items
-        n_pad = -(-max(n_items, 1) // tile) * tile
+        n_pad = -(-max(n_items, 1) // ITEM_TILE) * ITEM_TILE
         vt = np.zeros((qf.rank, n_pad), dtype=np.int8)
         vt[:, :n_items] = qf.v_q.T
         sv = np.zeros((n_pad,), dtype=np.float32)
         sv[:n_items] = qf.v_scale
-        qs = cls(
+        return cls(
             u_q=jax.device_put(qf.u_q),
             u_scale=jax.device_put(qf.u_scale),
             vt_q=jax.device_put(vt),
             v_scale=jax.device_put(sv),
             n_users=qf.n_users, n_items=n_items, rank=qf.rank,
-            tile=tile, fused=fused, interpret=interpret,
             recall=qf.recall, exact1=qf.exact1)
-        if fused and not interpret:
-            # an explicit PIO_SERVE_FUSED=on on a TPU: compile the
-            # kernel here, so the compiler's verdict fails the deploy
-            # instead of vanishing behind prebuild's "never raises" and
-            # the batcher's per-query fallback
-            jax.device_get(
-                qs.topk(np.zeros(1, np.int32), min(10, n_items)))
-        return qs
 
     def topk(self, user_ixs, k: int):
         ixs = np.asarray(user_ixs, dtype=np.int32)
-        if self.fused:
-            from predictionio_tpu.ops.topk_pallas import (
-                topk_for_users_quant_fused,
-            )
-            return topk_for_users_quant_fused(
-                self.u_q, self.u_scale, self.vt_q, self.v_scale, ixs,
-                k=int(k), n_items=self.n_items, tile=self.tile,
-                interpret=self.interpret)
         return topk_for_users_quant(
             self.u_q, self.u_scale, self.vt_q, self.v_scale, ixs,
             k=int(k), n_items=self.n_items)
@@ -555,9 +529,7 @@ class QuantizedServing:
     def summary(self) -> Dict[str, Any]:
         return {
             "dtype": "int8",
-            "fused": bool(self.fused),
-            "interpret": bool(self.interpret),
-            "tile": int(self.tile),
+            "tile": ITEM_TILE,
             "int8Bytes": self.int8_bytes(),
             "fp32Bytes": self.fp32_bytes(),
             "recall": self.recall,
@@ -572,23 +544,20 @@ class QuantizedServing:
 def quant_program_specs(qs: QuantizedServing, buckets: Iterable[int],
                         ks: Iterable[int]) -> List[Any]:
     """One ProgramSpec per (bucket x k) quantized serving program —
-    the batched kernel the micro-batcher flushes onto (fused or XLA
-    fallback, whichever this deploy resolved) — plus one per k for the
-    inline single-query path. Prime closures dispatch the live jitted
-    entry points so deploy prebuild warms the exact dispatch cache the
-    flush hits; post-warmup recompiles stay 0 with quant (+fused) on."""
+    the batched kernel the micro-batcher flushes onto — plus one per k
+    for the inline single-query path. Prime closures dispatch the live
+    jitted entry points so deploy prebuild warms the exact dispatch
+    cache the flush hits; post-warmup recompiles stay 0 with quant on."""
     from predictionio_tpu.serving.aot import ProgramSpec
 
     out: List[Any] = []
-    kernel = ("topk_for_users_quant_fused" if qs.fused
-              else "topk_for_users_quant")
     n_pad = int(np.shape(qs.vt_q)[1])
     for b in sorted({int(x) for x in buckets}):
         for k in ks:
             out.append(ProgramSpec(
-                name=kernel,
-                key=(kernel, qs.n_users, qs.n_items, qs.rank, n_pad,
-                     qs.tile if qs.fused else 0, int(b), int(k)),
+                name="topk_for_users_quant",
+                key=("topk_for_users_quant", qs.n_users, qs.n_items,
+                     qs.rank, n_pad, int(b), int(k)),
                 lower=_quant_users_lowerer(qs, int(b), int(k)),
                 prime=_quant_users_primer(qs, int(b), int(k))))
     for k in ks:
@@ -613,13 +582,6 @@ def _quant_users_lowerer(qs: QuantizedServing, bucket: int, k: int):
     def lower():
         uq, su, vt, sv = _quant_shapes(qs)
         ix = jax.ShapeDtypeStruct((bucket,), np.int32)
-        if qs.fused:
-            from predictionio_tpu.ops.topk_pallas import (
-                topk_for_users_quant_fused,
-            )
-            return topk_for_users_quant_fused.lower(
-                uq, su, vt, sv, ix, k=k, n_items=qs.n_items,
-                tile=qs.tile, interpret=qs.interpret)
         return topk_for_users_quant.lower(
             uq, su, vt, sv, ix, k=k, n_items=qs.n_items)
     return lower
@@ -778,8 +740,7 @@ def _register() -> None:
     aot.register_jit(
         "topk_for_users_quant", topk_for_users_quant, kind="serving",
         note="enumerated per (bucket, k) by quant_program_specs when "
-             "prepare_serving chose the quantized replicated layout "
-             "with the fused kernel off")
+             "prepare_serving chose the quantized replicated layout")
     aot.register_jit(
         "topk_for_user_quant", topk_for_user_quant, kind="serving",
         note="enumerated per k by quant_program_specs (inline / "

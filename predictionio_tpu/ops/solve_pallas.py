@@ -2,8 +2,8 @@
 
 `ops.als.solve_factors`'s unrolled Gauss-Jordan is r functional sweeps
 over an (n, r, r+1) tensor; XLA materializes every sweep to HBM, so the
-bench-shape solve (138k rows, r=10) moves ~600 MB and measures ~9.6 ms
-against a ~0.8 ms roofline — and it runs twice per ALS iteration.
+ML-20M-shape solve (138k rows, r=10) moves ~600 MB — and it runs twice
+per ALS iteration.
 
 This kernel runs ALL sweeps in VMEM: the augmented systems are laid out
 batch-as-lanes ((r*(r+1), n) — row-major (i, j) system coordinates in
@@ -12,12 +12,12 @@ is an elementwise op over 512-lane vectors), each grid block reads its
 (r*(r+1), 512) tile once, eliminates in registers/VMEM, and writes only
 the (r, 512) solution rows.
 
-MEASURED OUTCOME (v5e, ML-20M): standalone the kernel is 1.8x the XLA
-sweep (8.2 -> 4.4 ms), but the END-TO-END training iteration is
-unchanged (85.1/84.3 ms/iter gj vs 83.6/85.7 pallas, bench-methodology
-A/B) — inside the fused fori_loop the solve overlaps other work and is
-off the critical path. The solver therefore stays OPT-IN
-(PIO_ALS_SOLVER=pallas) as an A/B instrument rather than the default.
+OUTCOME (previous backend, before PR 25; not measured on this chip):
+standalone the kernel was faster than the XLA sweep, but the END-TO-END
+training iteration was unchanged — inside the fused fori_loop the solve
+overlaps other work and is off the critical path. The solver therefore
+stays OPT-IN (PIO_ALS_SOLVER=pallas) as an A/B instrument rather than
+the default (ROADMAP D2).
 
 Unpivoted elimination is safe for the ALS systems (PSD + ridge > 0
 keeps Schur diagonals positive — see solve_factors).
@@ -102,7 +102,7 @@ def solve_factors_pallas(A: jnp.ndarray, b: jnp.ndarray, reg: jnp.ndarray,
 
 
 def solver_choice() -> str:
-    """gj (the default — see MEASURED OUTCOME above) unless
+    """gj (the default — see OUTCOME above) unless
     PIO_ALS_SOLVER=pallas explicitly opts in ON A TPU backend; elsewhere
     the opt-in downgrades with a warning instead of failing to lower."""
     if os.environ.get("PIO_ALS_SOLVER") != "pallas":

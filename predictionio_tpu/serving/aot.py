@@ -2,9 +2,8 @@
 
 The warmup cliff: every jitted serving kernel compiles lazily on its
 first dispatch, so a cold `pio deploy` spends its first minutes paying
-(padding buckets x templates x k) XLA compiles on the latency path —
-BENCH_r02→r05 watched `warmup_compile_s` grow 27 s → ~400 s as that
-product multiplied. This module moves the whole product off the request
+(padding buckets x templates x k) XLA compiles on the latency path, a
+cost that grows with that product. This module moves the whole product off the request
 path:
 
 - **Program registry.** Every ``@jax.jit`` entry point on the serving
@@ -379,7 +378,7 @@ def algorithm_programs(algo: Any, model: Any,
 @dataclasses.dataclass
 class AOTReport:
     """What the prebuild did; ``GET /`` and /debug/device.json serve
-    the summary, the bench records it."""
+    the summary."""
     programs: List[Tuple[str, str, float]]   # (key, status, seconds)
     seconds: float
 
@@ -503,7 +502,7 @@ def enabled(mode: str = "auto") -> bool:
     return mode != "off"
 
 
-#: <checkout>/.jax_cache — the fixed default (bench.py and
+#: <checkout>/.jax_cache — the fixed default (chip_smoke.py and
 #: diagnostics/ml20m_repro.py use the same path): never a temp name, a
 #: pid or a time, so every process of a checkout finds what the last
 #: one compiled.

@@ -8,7 +8,7 @@ it from test files, each gated on a hand-maintained module list that new
 files silently escaped. This package is the single home for all of it:
 
 - :mod:`walker` discovers and parses every analyzed module ONCE
-  (``predictionio_tpu/`` + ``bench.py`` + ``diagnostics/``) — coverage
+  (``predictionio_tpu/`` + ``chip_smoke.py`` + ``diagnostics/``) — coverage
   is automatic for every future module, opt-OUT instead of opt-in.
 - :mod:`findings` defines the finding record (rule id, file:line, fix
   hint, stable baseline key) and the checked-in suppression baseline

@@ -4,7 +4,7 @@ Each pass module defines ``PASS = Pass(name, rules, doc, run)`` where
 ``run(modules) -> list[Finding]`` walks the shared parsed module set
 from :mod:`..walker`. Passes are pure functions of the source tree —
 no jax import, no device, no network — so ``pio lint`` is safe to run
-anywhere a checkout exists (CI, a laptop, the bench's strict leg).
+anywhere a checkout exists (CI, a laptop).
 """
 
 from __future__ import annotations

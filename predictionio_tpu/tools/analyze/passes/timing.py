@@ -7,7 +7,7 @@ walker and drops their hand-maintained scope lists:
   from ``time.perf_counter()`` (monotonic; a wall-clock delta can go
   NEGATIVE mid-measurement under NTP steps), wall-clock timestamps from
   timezone-aware ``datetime``. Was already package-wide; now also
-  covers ``bench.py`` and ``diagnostics/``.
+  covers ``chip_smoke.py`` and ``diagnostics/``.
 - ``timing-block-until-ready``: no ``block_until_ready`` anywhere — on
   the early rounds' remote backend it returned before results landed on
   host, silently under-reporting any clock stopped behind it; timed

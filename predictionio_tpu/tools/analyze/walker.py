@@ -3,7 +3,7 @@
 The pre-framework lints each re-walked and re-parsed the tree (and two
 of them only looked at hand-maintained module lists). Here discovery is
 centralized and coverage is the WHOLE repo-of-record — the
-``predictionio_tpu`` package, ``bench.py`` and ``diagnostics/`` — so a
+``predictionio_tpu`` package, ``chip_smoke.py`` and ``diagnostics/`` — so a
 new module is analyzed the moment it exists. Passes receive the same
 parsed :class:`Module` list; nothing re-reads the filesystem.
 
@@ -23,14 +23,14 @@ from typing import Dict, Iterator, List, Optional, Sequence, Set, Tuple
 #: top-level entries under the repo root that are analyzed, beyond the
 #: package itself (tests/ is deliberately excluded: tests seed defects
 #: on purpose and assert on lint internals)
-_EXTRA_FILES = ("bench.py",)
+_EXTRA_FILES = ("chip_smoke.py",)
 _EXTRA_DIRS = ("diagnostics",)
 
 _PRAGMA = "pio-lint:"
 
 
 def repo_root() -> str:
-    """The directory holding ``predictionio_tpu/`` (and ``bench.py``)."""
+    """The directory holding ``predictionio_tpu/`` (and ``chip_smoke.py``)."""
     pkg = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     return os.path.dirname(pkg)

@@ -23,8 +23,7 @@ alarm -> timeline link; drill down with `pio events` / `pio trace`):
       sharding    ok    8 shard(s), all_gather merge, 2.1 MiB
                         factors/shard, min per-device HBM headroom 84%
       quant       ok    int8 factors + per-row scales: 3.7 MiB vs
-                        13.2 MiB fp32 (0.28x), fused Pallas kernel,
-                        last recall gate 0.9975
+                        13.2 MiB fp32 (0.28x), last recall gate 0.9975
       hbm         --    no device memory stats (CPU / unsupported)
       traces      ok    512 spans buffered
     VERDICT: OK
@@ -58,8 +57,7 @@ _HBM_RED = 0.95
 #: is WARN
 _FAST_BURN_RED = 14.4
 _SLOW_BURN_WARN = 6.0
-#: fold-in event-to-servable freshness gate (the bench's
-#: foldin_freshness_p99 bound): a router response cache fronting a
+#: fold-in event-to-servable freshness gate: a router response cache fronting a
 #: fold-in backend with a TTL above this can serve staler than the
 #: speed layer promises (KNOWN_ISSUES #17)
 _FOLDIN_FRESHNESS_GATE_MS = 2000.0
@@ -670,10 +668,6 @@ def diagnose(scraped: Dict[str, Any]) -> List[Tuple[str, str, str]]:
                        f"fp32 ({i8 / f32:.2f}x)")
         if quant_info.get("sharded"):
             detail += f", sharded over {quant_info.get('shards', '?')}"
-        elif quant_info.get("fused"):
-            detail += (", fused Pallas kernel"
-                       + (" (interpret)" if quant_info.get("interpret")
-                          else ""))
         recall = quant_info.get("recall")
         if recall is None:
             recall = metric_max(samples, "pio_serve_quant_recall")

@@ -95,8 +95,7 @@ class FastEvalEngineWorkflow:
         TrainingData-object cache instead of racing to rebuild it first;
         identical train shapes then hit one compiled program via the
         process-wide jit cache. Reuse is observable in
-        models/recommendation/als_algorithm.LAYOUT_STATS (the bench's
-        `eval_grid_reuse_hits`)."""
+        models/recommendation/als_algorithm.LAYOUT_STATS."""
         seen_prefix = set()
         for ep in engine_params_list:
             pk = _key(ep.data_source_params, ep.preparator_params)

@@ -91,7 +91,7 @@ def non_finite_report(obj: Any, limit: int = 8) -> List[str]:
 def factor_bytes_by_dtype(obj: Any) -> dict:
     """Array bytes in a model tree, summed per dtype name — the storage
     / serving-footprint accounting the quantized-serving surfaces
-    (ops/quant.py summary, the bench's HBM-ratio leg) report. Walks the
+    (ops/quant.py summary) report. Walks the
     same structure serialization walks, so quantized int8 blocks and
     their fp32 scale vectors (which ride the pickle container like any
     other dataclass leaves) are each counted under their own dtype."""

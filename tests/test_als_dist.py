@@ -20,7 +20,7 @@ def make_problem(n_u=60, n_i=40, rank=4, seed=0):
 
 
 def zipf_problem(n_u=200, n_i=80, nnz=4000, seed=0):
-    """Power-law skew like the bench's synthetic ML-20M (bench.py:31-33)."""
+    """Power-law skew like a synthetic ML-20M."""
     rng = np.random.default_rng(seed)
     user_w = rng.lognormal(0.0, 1.2, n_u)
     item_w = 1.0 / np.arange(1, n_i + 1) ** 0.8

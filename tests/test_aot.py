@@ -613,7 +613,7 @@ def test_deploy_installs_pruned_buckets(memory_storage, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# doctor + benchtrend satellites
+# doctor satellites
 # ---------------------------------------------------------------------------
 
 def _scraped(metrics_body="", device=None):
@@ -654,24 +654,6 @@ def test_doctor_aot_line():
     # over the 10 s warm-replica target: WARN
     slow = body.replace("3.25", "45.0")
     assert _aot_check(doctor.diagnose(_scraped(slow)))[1] == doctor.WARN
-
-
-def test_benchtrend_absolute_time_to_ready_gate():
-    from predictionio_tpu.tools import benchtrend
-
-    def rnd(ttr, entries_before):
-        return {"label": "rX", "path": "x", "metric": "m", "value": 1.0,
-                "detail": {"time_to_ready_s": ttr,
-                           "compile_cache": {
-                               "before": {"entries": entries_before}}}}
-
-    # warm cache + breach: gated even with NO prior round
-    failures = benchtrend.gate([rnd(12.5, 3)])
-    assert failures and "time_to_ready_s" in failures[0]
-    # warm cache, inside the ceiling: green
-    assert benchtrend.gate([rnd(4.0, 3)]) == []
-    # cold cache legitimately pays compiles: not gated
-    assert benchtrend.gate([rnd(120.0, 0)]) == []
 
 
 def test_time_to_ready_gauge_exported(memory_storage):

@@ -1,0 +1,456 @@
+"""What benchmark/ reads of the program, one case per thing read.
+
+The benchmark (BENCHMARK.json, benchmark/) names parts of the program:
+`pio deploy` flags in a configuration's `deploy_args`, an engine factory
+in each engine.json, keys of the `GET /` batching block, device programs
+by their module name in benchmark/metrics/*.json, `/readyz`, the compile
+counter. A rename on the program's side shows first as a null under
+`per_layer` in the ledger; here it fails a test. Nothing under
+benchmark/ is edited or copied: the names come from its files, and what
+it reads with is called in place (modules loaded by path).
+"""
+
+import contextlib
+import datetime as dt
+import glob
+import importlib.util
+import json
+import os
+import re
+import sys
+import threading
+
+import numpy as np
+import pytest
+
+import jax
+
+from predictionio_tpu.common import devicewatch, telemetry
+from predictionio_tpu.data.storage import reset_storage, use_memory_storage
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+#: benchmark/child_train.py's `pio train` call for the parked cell
+PARKED_TRAIN_ARGV = ("--telemetry",)
+
+
+def _json(*parts):
+    with open(os.path.join(*parts)) as f:
+        return json.load(f)
+
+
+BENCHMARK = _json(ROOT, "BENCHMARK.json")
+_CONFIG_FILE = {c["name"]: c["file"] for c in BENCHMARK["configs"]}
+
+
+def _cell_configs():
+    """(cell id, pio command, its configuration's file as read): the
+    serving cells of BENCHMARK.json, then the training cell parked
+    beside them."""
+    out = []
+    for w in BENCHMARK["workloads"]:
+        out.append((w["name"], "deploy",
+                    _json(ROOT, _CONFIG_FILE[w["config"]])))
+    for path in sorted(glob.glob(os.path.join(BENCH, "parked", "*.json"))):
+        parked = _json(path)["BENCHMARK.json"]
+        files = {c["name"]: c["file"] for c in parked["configs"]}
+        for w in parked["workloads"]:
+            out.append(("parked:" + w["name"], "train",
+                        _json(ROOT, files[w["config"]])))
+    return out
+
+
+def _cells():
+    """(pio command, its flags, engine dir) per cell."""
+    return [pytest.param(
+        command,
+        tuple(cfg["deploy_args"]) if command == "deploy"
+        else PARKED_TRAIN_ARGV,
+        cfg["engine_dir"], id=cell)
+        for cell, command, cfg in _cell_configs()]
+
+
+def _first_engine_dir(command):
+    """The engine directory of the first cell `pio <command>` runs."""
+    for _cell, cmd, cfg in _cell_configs():
+        if cmd == command:
+            return os.path.join(ROOT, cfg["engine_dir"])
+    pytest.skip(f"the benchmark has no `pio {command}` cell")
+
+
+def _batching_keys():
+    """The `GET /` batching keys cell_serve.py's reader indexes."""
+    with open(os.path.join(BENCH, "cell_serve.py")) as f:
+        src = f.read()
+    body = src[src.index("    def batching(self):"):
+               src.index("    def stop(self):")]
+    return sorted(set(re.findall(r'\bb\["(\w+)"\]', body)))
+
+
+def _program_names():
+    """Every device program a metric file names (`program_s`,
+    `program_n`, a roofline's `program`), alternatives split."""
+    names = set()
+
+    def walk(node):
+        if isinstance(node, dict):
+            for k, v in node.items():
+                if k in ("program_s", "program_n", "program"):
+                    names.update(v.split("|"))
+                else:
+                    walk(v)
+        elif isinstance(node, list):
+            for v in node:
+                walk(v)
+
+    for path in (glob.glob(os.path.join(BENCH, "metrics", "*.json"))
+                 + glob.glob(os.path.join(BENCH, "parked", "*.json"))):
+        walk(_json(path))
+    return sorted(names)
+
+
+def _phase_facts():
+    """The training phases the parked cell's metrics read as facts
+    (`phase.persist_s`, `traced.phase.train_s`)."""
+    phases = set()
+    for path in glob.glob(os.path.join(BENCH, "metrics", "*.json")):
+        with open(path) as f:
+            phases.update(re.findall(r'"(?:traced\.)?phase\.(\w+)_s"',
+                                     f.read()))
+    return sorted(phases)
+
+
+@contextlib.contextmanager
+def _benchmark_on_path():
+    """benchmark/ importable as its own children see it (harness.
+    child_env's PYTHONPATH), and gone again afterwards with every module
+    it brought, so tests/ keeps its sys.path."""
+    before_path, before_mods = list(sys.path), set(sys.modules)
+    sys.path[:0] = [BENCH, os.path.join(BENCH, "engines")]
+    try:
+        yield
+    finally:
+        sys.path[:] = before_path
+        for name in set(sys.modules) - before_mods:
+            mod_file = getattr(sys.modules[name], "__file__", "") or ""
+            if mod_file.startswith(BENCH):
+                del sys.modules[name]
+
+
+def _load(name):
+    spec = importlib.util.spec_from_file_location(
+        f"_bench_{name}", os.path.join(BENCH, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+# ---------------------------------------------------------------------------
+# (a) a cell's flags parse, and its engine.json builds an engine
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("command,flags,engine_dir", _cells())
+def test_cell_flags_parse_and_engine_builds(command, flags, engine_dir):
+    from predictionio_tpu.controller import Engine
+    from predictionio_tpu.controller.engine import engine_params_from_json
+    from predictionio_tpu.tools import cli
+    from predictionio_tpu.workflow.workflow_utils import (
+        get_engine, read_engine_variant,
+    )
+
+    edir = os.path.join(ROOT, engine_dir)
+    argv = [command, "--engine-dir", edir, *flags]
+    if command == "deploy":     # child_serve.py's own part of the call
+        argv += ["--ip", "127.0.0.1", "--port", "0", "--telemetry"]
+    args = cli.build_parser().parse_args(argv)
+    assert args.command == command and args.telemetry
+    for flag, value in zip(flags[::2], flags[1::2]):
+        assert getattr(args, flag.lstrip("-").replace("-", "_")) == value
+    variant = read_engine_variant(edir, args.variant)
+    with _benchmark_on_path():
+        engine = get_engine(variant["engineFactory"])
+        assert isinstance(engine, Engine)
+        params = engine_params_from_json(engine, variant)
+    (name, algo), = params.algorithm_params_list
+    assert name == "als" and algo.rank == variant[
+        "algorithms"][0]["params"]["rank"]
+
+
+# ---------------------------------------------------------------------------
+# (b), (d), (e) a tiny deploy, read with the benchmark's own readers
+# ---------------------------------------------------------------------------
+
+K, N_USERS = 10, 40
+
+
+@pytest.fixture(scope="module")
+def served():
+    """child_serve.py's set-up at a tiny size (a COMPLETED instance of
+    seeded factors in a memory store), `pio deploy`'s QueryAPI with
+    batching and telemetry on behind a real socket, and what
+    cell_serve.py reads of it: the batching block before the load, after
+    one round of loadgen's closed loop and after a second, `/readyz`,
+    and the compile counter round a query that has to compile."""
+    from predictionio_tpu.data.api.http import make_server
+    from predictionio_tpu.data.bimap import BiMap
+    from predictionio_tpu.data.storage import EngineInstance, Model
+    from predictionio_tpu.models.recommendation import RecommendationEngine
+    from predictionio_tpu.models.recommendation.als_algorithm import ALSModel
+    from predictionio_tpu.workflow import model_io
+    from predictionio_tpu.workflow.create_server import QueryAPI, ServerConfig
+
+    variant = _json(_first_engine_dir("deploy"), "engine.json")
+    mp = pytest.MonkeyPatch()
+    # the CPU harness would else keep host arrays for a model this small
+    mp.setenv("PIO_SERVE_DEVICE_MS", "1e9")
+    telemetry.set_enabled(True)
+    devicewatch.install()
+    storage = use_memory_storage()
+    rng = np.random.default_rng(5)
+    U = rng.standard_normal((N_USERS, 8), dtype=np.float32)
+    V = rng.standard_normal((300, 8), dtype=np.float32)
+    now = dt.datetime.now(dt.timezone.utc)
+    instance_id = storage.get_meta_data_engine_instances().insert(
+        EngineInstance(
+            id="", status="COMPLETED", start_time=now, end_time=now,
+            engine_id=variant["id"], engine_version="NOT_USED",
+            engine_variant=variant["id"],
+            engine_factory=variant["engineFactory"],
+            data_source_params=json.dumps(variant["datasource"]),
+            preparator_params="{}",
+            algorithms_params=json.dumps(variant["algorithms"]),
+            serving_params="{}"))
+    model = ALSModel(
+        rank=8, user_factors=U, item_factors=V,
+        user_vocab=BiMap({f"u{k}": k for k in range(N_USERS)}),
+        item_vocab=BiMap({f"i{k}": k for k in range(300)}))
+    storage.get_model_data_models().insert(Model(
+        id=instance_id,
+        models=model_io.serialize_models([model], check_finite=True)))
+    api = QueryAPI(storage=storage, engine=RecommendationEngine(),
+                   config=ServerConfig(batching="on", serve_quant="off"))
+    server = make_server(api, "127.0.0.1", 0)
+    port = server.server_address[1]
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    try:
+        with _benchmark_on_path():
+            cell_serve = _load("cell_serve")
+            loadgen = sys.modules["loadgen"]
+            dep = cell_serve.Deploy.__new__(cell_serve.Deploy)
+            dep.port = port
+            out = {"ready": cell_serve.get_json(port, "/readyz", 5),
+                   "raw": cell_serve.get_json(port, "/")["batching"],
+                   "b": [dep.batching()], "records": []}
+            users = np.arange(N_USERS)
+            for _round in range(2):
+                records, _t0, _t1 = loadgen.closed_loop(
+                    port, users, K, 4, 60.0)
+                out["records"].append(records)
+                out["b"].append(dep.batching())
+            out["diffs"] = [cell_serve.diff(a, b)
+                            for a, b in zip(out["b"], out["b"][1:])]
+            # child_serve.py's `stats` reply round a query whose k no
+            # program was prebuilt for
+            c0 = devicewatch.compiles_total()
+            status, data = loadgen.Client("127.0.0.1", port, 60.0).post(
+                loadgen._body(0, 7))
+            out["lazy"] = loadgen.parse_reply(status, data)
+            out["compiles"] = (c0, devicewatch.compiles_total())
+        yield out
+    finally:
+        server.shutdown()
+        api.close()
+        reset_storage()
+        telemetry.set_enabled(None)
+        mp.undo()
+
+
+@pytest.mark.parametrize("key", _batching_keys())
+def test_status_page_reports_the_batching_key(served, key):
+    """A key cell_serve.py's `batching()` indexes is on `GET /`, a
+    number (or a histogram of counts), and never runs backwards; the
+    reader itself ran in the fixture, so a missing key is its KeyError
+    there."""
+    assert key in served["raw"], sorted(served["raw"])
+    asked = 2 * N_USERS
+    b0, b1, b2 = served["b"]
+    if key.endswith("Hist"):
+        field = {"batchSizeHist": "sizes", "bucketHist": "buckets"}[key]
+        for b in (b1, b2):
+            assert b[field] and all(
+                isinstance(v, int) for v in b[field].values())
+            assert sum(b[field].values()) == b["batches"]
+    elif key.startswith("avg"):
+        field = {"avgQueueWaitMs": "queue_wait_s",
+                 "avgFlushMs": "flush_s"}[key]
+        assert isinstance(served["raw"][key], (int, float))
+        assert b0[field] <= b1[field] <= b2[field]
+        assert all(d[field] >= 0 for d in served["diffs"])
+        if key == "avgFlushMs":     # a flush takes time on any clock
+            assert all(d[field] > 0 for d in served["diffs"])
+    else:
+        assert isinstance(served["raw"][key], int)
+        assert b0[key] <= b1[key] <= b2[key]
+        d1, d2 = served["diffs"]
+        if key == "queries":
+            assert d1[key] + d2[key] == asked
+        elif key == "batches":      # two rounds, two flushes or more
+            assert 1 <= d1[key] <= N_USERS and 1 <= d2[key] <= N_USERS
+        else:
+            assert key == "rejected" and d1[key] == d2[key] == 0
+
+
+def test_readyz_says_ready(served):
+    assert served["ready"].get("status") == "ready"
+
+
+def test_compile_counter_counts_a_compile_on_the_serving_path(served):
+    """`compile.in_window.*` is a difference of `compiles_total()`: an
+    int, and a query that has to compile (a k nothing was prebuilt for)
+    raises it."""
+    before, after = served["compiles"]
+    assert isinstance(before, int) and isinstance(after, int)
+    assert served["lazy"] is not None and len(served["lazy"]) == 7
+    assert after > before
+
+
+def test_loadgen_reads_full_replies(served):
+    """loadgen.py posts {"user": "u<ix>", "num": k} and parses
+    `itemScores` of (item, score): every reply comes back full, with
+    item names the reply check can turn back into indices."""
+    for records in served["records"]:
+        assert len(records) == N_USERS
+        for _user, _t0, _t1, items in records:
+            assert items is not None and len(items) == K
+            assert all(name[0] == "i" and int(name[1:]) < 300
+                       and isinstance(score, float)
+                       for name, score in items)
+
+
+# ---------------------------------------------------------------------------
+# (c) a program a metric names is a jitted entry point's module
+# ---------------------------------------------------------------------------
+
+def _s(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _lowered_topk_for_users(_mp):
+    from predictionio_tpu.ops import topk
+    return topk.topk_for_users.lower(
+        _s((N_USERS, 8), np.float32), _s((300, 8), np.float32),
+        _s((4,), np.int32), k=K)
+
+
+def _lowered_topk_for_users_sharded(_mp):
+    from predictionio_tpu.parallel import serve_dist
+    rng = np.random.default_rng(5)
+    sharded = serve_dist.shard_factors(
+        rng.standard_normal((N_USERS, 8), dtype=np.float32),
+        rng.standard_normal((300, 8), dtype=np.float32), n_shards=4)
+    return serve_dist.sharded_program_specs(sharded, (4,), (K,))[0].lower()
+
+
+def _lowered_trainer(entry, kernel):
+    """The trainer as `pio train` reaches it: als.train_explicit on a
+    tiny layout, with the jitted entry point lowered on the arguments
+    the driver hands it."""
+    def lowered(mp):
+        from predictionio_tpu.ops import als
+        real, seen = getattr(als, entry), []
+
+        def spy(*args, **kwargs):
+            seen.append(real.lower(*args, **kwargs))
+            return real(*args, **kwargs)
+
+        mp.setattr(als, entry, spy)
+        mp.setenv("PIO_ALS_HOT_K", "8")     # a hot set 40 items can split
+        rng = np.random.default_rng(3)
+        u = rng.integers(0, 30, 600).astype(np.int32)
+        i = rng.integers(0, 40, 600).astype(np.int32)
+        r = rng.uniform(1, 5, 600).astype(np.float32)
+        data = als.prepare_ratings(u, i, r, n_users=30, n_items=40)
+        als.train_explicit(data, rank=4, iterations=1, kernel=kernel)
+        assert seen, f"kernel={kernel!r} never reached als.{entry}"
+        return seen[0]
+    return lowered
+
+
+#: program name in a metric file -> (the module jax names that jitted
+#: entry point's program, its Lowered)
+_ENTRY_POINTS = {
+    "topk_for_users": ("jit_topk_for_users", _lowered_topk_for_users),
+    "topk_for_users_sharded": ("jit_topk_for_users_sharded",
+                               _lowered_topk_for_users_sharded),
+    "train_hybrid": ("jit__train_hybrid_jit",
+                     _lowered_trainer("_train_hybrid_jit", "hybrid")),
+    "train_csrb": ("jit__train_csrb_jit",
+                   _lowered_trainer("_train_csrb_jit", "csrb")),
+    "train_explicit": ("jit__train_explicit_jit",
+                       _lowered_trainer("_train_explicit_jit", "scan")),
+}
+
+
+@pytest.mark.parametrize("name", _program_names())
+def test_metric_program_name_is_a_jitted_entry_points_module(
+        name, monkeypatch):
+    """The trace reader finds a program by searching the module names of
+    the `XLA Modules` line for the metric's pattern (reduce.py `_term`);
+    jax names a module `jit_<function>`. The entry point lowers to
+    exactly the module placed here, and the metric's pattern finds it."""
+    assert name in _ENTRY_POINTS, (
+        f"benchmark/ names a program {name!r} this test cannot place: "
+        "add the entry point that lowers to it")
+    expected, lowered = _ENTRY_POINTS[name]
+    module = re.search(r"module @(\w+)", lowered(monkeypatch).as_text()
+                       ).group(1)
+    assert module == expected and re.search(name, module), module
+
+
+# ---------------------------------------------------------------------------
+# the parked training cell's phases
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def trained_instance():
+    """child_train.py's job at a tiny size: `pio train --telemetry` on
+    the parked cell's engine, fed through bench_engine.FEED; -> the one
+    COMPLETED instance it leaves."""
+    from predictionio_tpu.tools import cli
+
+    engine_dir = _first_engine_dir("train")
+    mp = pytest.MonkeyPatch()
+    mp.setenv("PIO_TELEMETRY", "1")     # --telemetry sets it: undone below
+    mp.setenv("PIO_TRAIN_STREAM", "off")
+    storage = use_memory_storage()
+    try:
+        with _benchmark_on_path():
+            import bench_engine
+            rng = np.random.default_rng(3)
+            sets = [(rng.integers(0, 30, 600).astype(np.int32),
+                     rng.integers(0, 40, 600).astype(np.int32),
+                     rng.uniform(1, 5, 600).astype(np.float32))
+                    for _ in range(2)]
+            bench_engine.FEED = bench_engine.Feed(sets, 30, 40)
+            rc = cli.main(["train", "--engine-dir", engine_dir,
+                           *PARKED_TRAIN_ARGV])
+            assert rc == 0 and bench_engine.FEED.taken == 1
+        rows = [i for i in
+                storage.get_meta_data_engine_instances().get_all()
+                if i.status == "COMPLETED"]
+        assert len(rows) == 1
+        yield rows[0]
+    finally:
+        reset_storage()
+        telemetry.set_enabled(None)
+        mp.undo()
+
+
+@pytest.mark.parametrize("phase", _phase_facts())
+def test_train_instance_carries_the_phase_the_metric_reads(
+        trained_instance, phase):
+    """child_train.py turns the instance's `phase_<name>_s` fields into
+    the facts `phase.<name>_s` / `traced.phase.<name>_s`."""
+    value = trained_instance.runtime_conf.get(f"phase_{phase}_s")
+    assert value is not None, sorted(trained_instance.runtime_conf)
+    assert float(value) >= 0

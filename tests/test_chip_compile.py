@@ -76,7 +76,7 @@ def _fp32_factors(sh):
 
 def _int8_layout(sh):
     """QuantizedServing's device layout: item matrix transposed and
-    padded to the fused kernel's tile."""
+    padded to ``quant.ITEM_TILE``."""
     n_pad = -(-N_ITEMS // TILE) * TILE
     return (_s((N_USERS, RANK), jnp.int8, sh),
             _s((N_USERS,), jnp.float32, sh),
@@ -142,32 +142,6 @@ def test_topk_for_users_compiles_at_the_benchmark_cells_shape(
                 if " sort(" in line and f",{n_items}]" in line]
     scores_bytes = 4 * max(bucket, 8) * n_items     # 8 sublanes a tile
     assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * scores_bytes
-
-
-@pytest.mark.xfail(strict=True, reason=(
-    "the TPU compiler refuses the fused kernel's output block shape "
-    "(b, 10) — 'the last two dimensions of your block shape are "
-    "divisible by 8 and 128' (ops/topk_pallas.py out_specs); the PR "
-    "that repairs the block shapes flips this and may then put the "
-    "kernel back on the default path"))
-def test_fused_topk_is_refused_by_the_tpu_compiler(one_chip,
-                                                   no_compile_cache):
-    from predictionio_tpu.ops import topk_pallas
-    topk_pallas.topk_for_users_quant_fused.lower(
-        *_int8_layout(one_chip), _s((64,), jnp.int32, one_chip),
-        k=K, n_items=N_ITEMS, tile=TILE, interpret=False).compile()
-
-
-def test_fused_auto_resolves_to_xla_even_on_tpu(monkeypatch):
-    """PIO_SERVE_FUSED unset must not select the refused kernel on any
-    backend; "on" on a TPU backend compiles it (no interpret mode), so
-    the compiler's error reaches the deploy."""
-    from predictionio_tpu.ops import topk_pallas
-    monkeypatch.delenv("PIO_SERVE_FUSED", raising=False)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert topk_pallas.fused_choice() == (False, False)
-    monkeypatch.setenv("PIO_SERVE_FUSED", "on")
-    assert topk_pallas.fused_choice() == (True, False)
 
 
 # ---------------------------------------------------------------------------

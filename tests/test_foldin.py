@@ -884,29 +884,33 @@ def _rate_new_item(storage, iid, parity=0, month=7):
     {},
     {"shard_serving": "on", "serve_quant": "on"},
 ], ids=["replicated", "sharded+quant"])
-def test_unseen_item_servable_within_2s(trained, extra):
+def test_unseen_item_servable_within_two_ticks(trained, extra):
     """An item the trainer never saw is rated by live events and must
-    rank in an even user's top-k within 2 s — no retrain, no /reload,
-    vocab grown in place — on the replicated AND sharded+quantized
-    layouts."""
+    rank in an even user's top-k after at most two worker ticks — no
+    retrain, no /reload, vocab grown in place — on the replicated AND
+    sharded+quantized layouts. Freshness is bounded in events here; in
+    seconds it is a deployed server's figure (PERF.md 7.4)."""
     storage, engine = trained
     iid = f"inew_{'sq' if extra else 'rep'}"
     api = _api(storage, engine, **extra)
     try:
         worker = api._foldin_worker
         assert worker is not None and worker.supported
+        worker.stop()   # drive the ticks deterministically
         generation_before = api.generation
-        t0 = time.perf_counter()
         _rate_new_item(storage, iid, parity=0)
         items = []
-        while time.perf_counter() - t0 < 2.0:
-            status, body = _post(api, "u0", num=10)
+        for _tick in range(2):
+            worker.tick()
+            # u2: an even user no other test of this module's shared
+            # store re-rates (test_foldin_updates_existing_user leaves
+            # u0 preferring odd items, and every fresh worker re-folds
+            # that)
+            status, body = _post(api, "u2", num=10)
             assert status == 200
             items = [s["item"] for s in body["itemScores"]]
             if iid in items:
                 break
-            time.sleep(0.01)
-        assert iid in items, items
         # rankable AND ranked like the even cluster it was rated into
         assert iid in items[:4], items
         assert api.generation == generation_before   # no /reload

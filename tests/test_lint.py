@@ -4,8 +4,8 @@ Three layers, all tier-1:
 
 1. **The repo is clean**: one entry point runs every pass over the real
    tree exactly like `pio lint` and requires exit 0 — THE static-analysis
-   gate. Any new violation anywhere in `predictionio_tpu/`, `bench.py`
-   or `diagnostics/` fails this test with file:line + rule + fix hint.
+   gate. Any new violation anywhere in `predictionio_tpu/`,
+   `chip_smoke.py` or `diagnostics/` fails this test with file:line + rule + fix hint.
 2. **The passes are live**: each rule is proven to fire on a seeded
    defect (a `block_until_ready` clock boundary, an unclipped padded
    gather, an implicit device->host sync, a `time.time()` inside a
@@ -507,12 +507,11 @@ def test_aot_pass_fires_on_unregistered_quant_kernel():
 
 
 def test_aot_scope_covers_quant_modules_automatically():
-    """ops/quant.py and ops/topk_pallas.py enter the AOT lint scope via
-    register_jit reachability — no hand-maintained list was touched."""
+    """ops/quant.py enters the AOT lint scope via register_jit
+    reachability — no hand-maintained list was touched."""
     modules = walker.discover(ROOT)
     scope = {m.rel for m in aot_registration.serving_scope(modules)}
     assert "predictionio_tpu/ops/quant.py" in scope
-    assert "predictionio_tpu/ops/topk_pallas.py" in scope
 
 
 def test_debug_surface_pass_fires_on_private_path():
@@ -543,7 +542,7 @@ _OLD_TIMED_MODULES = (
     "workflow/context.py", "workflow/core_workflow.py",
     "workflow/create_server.py", "data/store.py", "ops/staging.py",
     "models/recommendation/als_algorithm.py",
-    "tools/benchtrend.py", "tools/doctor.py", "tools/profile.py",
+    "tools/doctor.py", "tools/profile.py",
 )
 _OLD_AOT_MODULES = ("ops/topk.py", "parallel/serve_dist.py")  # + serving/*
 _OLD_DAEMON_MODULES = (
@@ -562,8 +561,8 @@ def test_timing_coverage_superset_of_old_list():
     discovered = {m.rel for m in walker.discover(ROOT)}
     old = {f"predictionio_tpu/{rel}" for rel in _OLD_TIMED_MODULES}
     assert old <= discovered, sorted(old - discovered)
-    # and strictly more: bench.py + diagnostics/ joined the walk
-    assert "bench.py" in discovered
+    # and strictly more: chip_smoke.py + diagnostics/ joined the walk
+    assert "chip_smoke.py" in discovered
     assert any(r.startswith("diagnostics/") for r in discovered)
 
 

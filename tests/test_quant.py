@@ -1,14 +1,13 @@
-"""Quantized serving (ops/quant.py + ops/topk_pallas.py).
+"""Quantized serving (ops/quant.py).
 
 The acceptance surface of ISSUE 11: int8 per-row-scale quantization of
 both factor matrices with dequantize-free int8 x int8 scoring; the
-fused Pallas score->mask->per-tile-top-k kernel bit-identical (in
-interpret mode, on CPU) to the XLA fallback AND to the sharded int8
-kernel, ties included; the ranking-parity contract (recall@k >= 0.99,
-exact-match@1 >= 0.999 vs fp32 on a trained model — KNOWN_ISSUES #12);
-PIO_SERVE_QUANT=off wire-byte identical to the pre-quant server
-(replicated and sharded); AOT-prebuilt quant programs keeping
-post_warmup_recompiles at 0 with quant+fused on; and the doctor /
+replicated kernel bit-identical to an integer NumPy reference AND to
+the sharded int8 kernel, ties included; the ranking-parity contract
+(recall@k >= 0.99, exact-match@1 >= 0.999 vs fp32 on a trained model —
+KNOWN_ISSUES #12); PIO_SERVE_QUANT=off wire-byte identical to the
+pre-quant server (replicated and sharded); AOT-prebuilt quant programs
+keeping post_warmup_recompiles at 0 with quant on; and the doctor /
 deploy-state surfaces, including the requested-but-fell-back WARN.
 """
 
@@ -28,7 +27,7 @@ from predictionio_tpu.data.storage import App, Storage
 from predictionio_tpu.models.recommendation import (
     ALSAlgorithmParams, DataSourceParams, RecommendationEngine,
 )
-from predictionio_tpu.ops import quant, topk, topk_pallas
+from predictionio_tpu.ops import quant
 from predictionio_tpu.parallel import serve_dist
 from predictionio_tpu.workflow import WorkflowContext, model_io, run_train
 from predictionio_tpu.workflow.create_server import QueryAPI, ServerConfig
@@ -37,8 +36,6 @@ from predictionio_tpu.workflow.create_server import QueryAPI, ServerConfig
 @pytest.fixture(autouse=True)
 def _clean(monkeypatch):
     monkeypatch.delenv("PIO_SERVE_QUANT", raising=False)
-    monkeypatch.delenv("PIO_SERVE_FUSED", raising=False)
-    monkeypatch.delenv("PIO_SERVE_FUSED_TILE", raising=False)
     yield
     quant.record_state(None)
     serve_dist.record_state(None)
@@ -83,45 +80,45 @@ def test_quantized_factors_bytes():
 
 
 # ---------------------------------------------------------------------------
-# kernel parity: fused Pallas (interpret) == XLA fallback == sharded int8
+# kernel parity: replicated int8 == integer NumPy reference == sharded int8
 # ---------------------------------------------------------------------------
 
-def _build_serving(qf, fused: str, tile: str, monkeypatch):
-    monkeypatch.setenv("PIO_SERVE_FUSED", fused)
-    monkeypatch.setenv("PIO_SERVE_FUSED_TILE", tile)
-    return quant.QuantizedServing.build(qf)
-
-
-def test_fused_interpret_matches_fallback_bit_identical(monkeypatch):
-    """Constructed ties (duplicated item rows quantize identically), k
-    below/at/above the tile, bucket sizes down to 1."""
+def test_quant_kernel_matches_integer_reference_bit_identical():
+    """The replicated kernel against the same arithmetic in NumPy (exact
+    int32 dot products, one fp32 rescale, descending score with ties to
+    the lowest index): constructed ties (duplicated item rows quantize
+    identically), k from 1 to the whole catalog, bucket sizes down to
+    1, and never a layout-padding column in an answer."""
     U, V = _factors()
     V[707] = V[3]
     V[13] = V[3]
     qf = quant.QuantizedFactors.from_factors(U, V)
-    fb = _build_serving(qf, "0", "256", monkeypatch)
-    fu = _build_serving(qf, "1", "256", monkeypatch)
-    assert fu.fused and fu.interpret and not fb.fused
+    qs = quant.QuantizedServing.build(qf)
+    assert np.shape(qs.vt_q) == (10, 1536)      # 1100 -> 3 x ITEM_TILE
+    s32 = qf.u_q.astype(np.int32) @ qf.v_q.astype(np.int32).T
+    want = s32.astype(np.float32) * (qf.u_scale[:, None]
+                                     * qf.v_scale[None, :])
     for ixs in (np.arange(16, dtype=np.int32),
                 np.asarray([7], dtype=np.int32)):
-        for k in (1, 5, 10, 300):   # 300 > the 256 tile
-            fv, fi = jax.device_get(fu.topk(ixs, k))
-            bv, bi = jax.device_get(fb.topk(ixs, k))
+        for k in (1, 5, 10, 300, 1100):
+            gv, gi = jax.device_get(qs.topk(ixs, k))
+            order = np.argsort(-want[ixs], axis=1, kind="stable")[:, :k]
+            np.testing.assert_array_equal(gi, order, err_msg=f"k={k}")
             np.testing.assert_array_equal(
-                fv.view(np.int32), bv.view(np.int32),
+                gv.view(np.int32),
+                np.take_along_axis(want[ixs], order, 1).view(np.int32),
                 err_msg=f"k={k} b={len(ixs)}")
-            np.testing.assert_array_equal(fi, bi, err_msg=f"k={k}")
     # the tie rule itself: clones of item 3 rank lowest-index first
-    _fv, fi = jax.device_get(fu.topk(np.arange(8, dtype=np.int32), 1100))
-    for row in fi:
+    _gv, gi = jax.device_get(qs.topk(np.arange(8, dtype=np.int32), 1100))
+    for row in gi:
         pos = [int(np.flatnonzero(row == c)[0]) for c in (3, 13, 707)]
         assert pos == sorted(pos), pos
 
 
-def test_inline_quant_matches_batched_row(monkeypatch):
+def test_inline_quant_matches_batched_row():
     U, V = _factors(seed=1)
     qf = quant.QuantizedFactors.from_factors(U, V)
-    qs = _build_serving(qf, "0", "512", monkeypatch)
+    qs = quant.QuantizedServing.build(qf)
     iv, ii = jax.device_get(qs.topk_one(np.int32(7), 10))
     bv, bi = jax.device_get(qs.topk(np.asarray([7], np.int32), 10))
     np.testing.assert_array_equal(iv.view(np.int32),
@@ -129,13 +126,13 @@ def test_inline_quant_matches_batched_row(monkeypatch):
     np.testing.assert_array_equal(ii, bi[0])
 
 
-def test_sharded_quant_matches_replicated_quant_bit_identical(monkeypatch):
+def test_sharded_quant_matches_replicated_quant_bit_identical():
     """8 int8 shards vs the replicated quant kernel: exact integer
     scores + elementwise rescale leave no room for drift."""
     U, V = _factors(seed=2)
     V[1099] = V[5]     # cross-shard tie with the clone in shard 0
     qf = quant.QuantizedFactors.from_factors(U, V)
-    qs = _build_serving(qf, "0", "512", monkeypatch)
+    qs = quant.QuantizedServing.build(qf)
     sharded = serve_dist.shard_factors(U, V, quant=qf)
     assert sharded.dtype == "int8" and sharded.n_shards == 8
     ixs = np.array([0, 5, 12, 0, 31], dtype=np.int32)
@@ -159,7 +156,7 @@ def test_sharded_quant_per_shard_bytes_quartered():
 
 
 # ---------------------------------------------------------------------------
-# mode / fused resolution
+# mode resolution
 # ---------------------------------------------------------------------------
 
 def test_mode_resolution(monkeypatch):
@@ -185,16 +182,6 @@ def test_mode_resolution(monkeypatch):
     with pytest.raises(ValueError):
         with quant.deploy_scope("sideways"):
             pass
-
-
-def test_fused_choice(monkeypatch):
-    # CPU backend: auto -> XLA fallback; on -> interpret; off -> fallback
-    monkeypatch.delenv("PIO_SERVE_FUSED", raising=False)
-    assert topk_pallas.fused_choice() == (False, False)
-    monkeypatch.setenv("PIO_SERVE_FUSED", "1")
-    assert topk_pallas.fused_choice() == (True, True)
-    monkeypatch.setenv("PIO_SERVE_FUSED", "0")
-    assert topk_pallas.fused_choice() == (False, False)
 
 
 def test_accept_parity(monkeypatch):
@@ -429,13 +416,12 @@ def test_quant_gauges_recorded(trained, monkeypatch):
         api2.close()
 
 
-def test_quant_fused_programs_prebuilt_no_post_warmup_recompiles(
+def test_quant_programs_prebuilt_no_post_warmup_recompiles(
         trained, monkeypatch):
-    """With quant + the fused kernel on (interpret mode on CPU), every
-    (bucket x k) program is primed before ready: a post-AOT serving
-    burst must compile NOTHING."""
+    """With quant on, every (bucket x k) program quant_program_specs
+    enumerates is primed before ready: a post-AOT serving burst must
+    compile NOTHING."""
     monkeypatch.setenv("PIO_SERVE_DEVICE_MS", "1e9")
-    monkeypatch.setenv("PIO_SERVE_FUSED", "1")
     storage, engine = trained
     telemetry.set_enabled(True)
     devicewatch.install()
@@ -444,7 +430,6 @@ def test_quant_fused_programs_prebuilt_no_post_warmup_recompiles(
                    config=ServerConfig(batching="on", serve_quant="on"))
     try:
         assert api.models[0].quant is not None
-        assert api.models[0].quant.fused
         assert devicewatch.serving_warmup_done()    # AOT marked it
         before = devicewatch.post_warmup_recompiles()
         for q in range(6):
@@ -503,7 +488,7 @@ def test_doctor_quant_line_states():
     from predictionio_tpu.tools import doctor
 
     dev = {"telemetry": True,
-           "quant": {"enabled": True, "dtype": "int8", "fused": True,
+           "quant": {"enabled": True, "dtype": "int8",
                      "int8Bytes": 14 * 2**20, "fp32Bytes": 40 * 2**20,
                      "recall": 0.9975}}
     metrics = "pio_serve_quant_mode 1\n"
@@ -513,7 +498,6 @@ def test_doctor_quant_line_states():
     assert state == doctor.OK
     assert "int8" in detail and "0.35x" in detail
     assert "recall gate 0.9975" in detail
-    assert "fused Pallas" in detail
     # requested but fell back -> WARN naming the cost
     dev_fb = {"telemetry": True, "quant": {"enabled": False,
                                            "fellBack": True}}
@@ -568,21 +552,39 @@ def test_factor_bytes_by_dtype_accounting():
 
 
 # ---------------------------------------------------------------------------
-# the quantized HBM-ceiling demonstration (bench leg, on the 8-device
-# tier-1 mesh)
+# the quantized HBM ceiling (on the 8-device tier-1 mesh)
 # ---------------------------------------------------------------------------
 
-def test_quant_hbm_ceiling_serves_past_fp32_sharded_budget(monkeypatch):
-    import bench
+def _factors_past_sharded_budget(budget: int, n_dev: int, rank: int = 64):
+    """A catalog at ~3.5x what ``n_dev`` fp32 shards of ``budget`` bytes
+    hold (the ideal int8 gain is 4x; the fp32 per-row scale vectors trim
+    it to (4r)/(r+4) = 3.76x at rank 64): each fp32 shard lands ~3.5x
+    past the budget while the int8 shards fit at ~0.93x of it."""
+    n_items = int(budget * 3.5) * n_dev // (rank * 4)
+    rng = np.random.default_rng(0)
+    U = rng.standard_normal((1024, rank), dtype=np.float32)
+    V = rng.standard_normal((n_items, rank), dtype=np.float32)
+    return U, V
 
-    monkeypatch.setenv("BENCH_SHARD_BUDGET_MB", "1")
-    out = bench._quant_hbm_ceiling_demo()
-    assert "skipped" not in out
-    assert out["n_devices"] == 8
-    assert not out["fp32_sharded_fits_budget"]
-    assert out["int8_sharded_fits_budget"]
-    assert out["catalog_vs_fp32_ceiling"] >= 3.0
-    assert out["quant_sharded_served_ok"]
+
+def test_quant_hbm_ceiling_serves_past_fp32_sharded_budget():
+    """Even the SHARDED fp32 layout busts the per-device (demonstration)
+    budget; the int8 shards fit, and the quantized sharded top-k
+    actually answers."""
+    budget, rank = 2**20, 64
+    n_dev = len(jax.devices())
+    assert n_dev == 8
+    U, V = _factors_past_sharded_budget(budget, n_dev, rank)
+    n_items = V.shape[0]
+    assert -(-n_items // n_dev) * rank * 4 > budget   # fp32 shard: no
+    assert n_items / (budget * n_dev // (rank * 4)) >= 3.0
+    qf = quant.QuantizedFactors.from_factors(U, V)
+    sharded = serve_dist.shard_factors(U, V, quant=qf)
+    assert sharded.per_shard_bytes() <= budget        # int8 shard: yes
+    vals, idx = jax.device_get(
+        sharded.topk(np.arange(8, dtype=np.int32), 10))
+    assert np.isfinite(vals).all()
+    assert (idx >= 0).all() and (idx < n_items).all()
 
 
 # ---------------------------------------------------------------------------

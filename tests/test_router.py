@@ -504,13 +504,19 @@ def test_latency_on_one_replica_opens_breaker_and_shifts_traffic(
 
 @pytest.mark.chaos
 def test_reload_barrier_zero_drops_and_monotone_generations(
-        memory_storage):
+        memory_storage, monkeypatch):
     """THE barrier e2e: two replicas serve model A under a live burst;
     a second instance (different seed => byte-distinguishable answers)
     trains; POST /reload on the ROUTER swaps the fleet. Zero queries
     drop, and no client ever observes new-then-old — per-client
     responses are generation-monotonic, so one client never sees two
     model generations interleaved."""
+    # On the CPU backend every (re)load times a query and keeps host or
+    # device arrays, whichever was faster; the two layouts differ in a
+    # score's last bit, so two replicas of one model could answer
+    # different bytes. Pin the layout: the test tells generations apart
+    # by bytes.
+    monkeypatch.setenv("PIO_SERVE_DEVICE_MS", "1e9")
     engine = _train_seeded(memory_storage, seed=3)
     api0, server0, port0 = _replica(memory_storage, engine)
     api1, server1, port1 = _replica(memory_storage, engine)

@@ -16,7 +16,7 @@ The contracts under test:
   generation's answer for the other;
 - cache off (the default) is advertisement-free: GET / has no
   `cache` key (wire parity is asserted in test_router_partition.py);
-- `data/synthetic.query_keys` (the bench's zipfian sampler, built on
+- `data/synthetic.query_keys` (the zipfian sampler, built on
   the same `_zipf_cdf` the synthetic ratings use): deterministic per
   seed, properly skewed, bounded to the pool.
 """

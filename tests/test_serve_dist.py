@@ -220,7 +220,6 @@ def test_shard_selection_is_the_whole_shard_sorts(shape, dtype):
             v_q=L.T.astype(np.int8), v_scale=v_scale)
         sharded = serve_dist.shard_factors(None, None, n_shards=4, quant=qf)
         replicated = quant.QuantizedServing.build(qf)
-        assert not replicated.fused
         unpadded = (4 * rows_dev == n_items
                     and int(replicated.vt_q.shape[1]) == n_items)
     else:
@@ -471,22 +470,34 @@ def test_sharded_program_specs_cover_inline_bucket():
     specs[0].build()
 
 
-def test_hbm_ceiling_demo_shards_past_one_device_budget(monkeypatch):
-    """The bench's HBM-ceiling leg on the 8-device mesh: a factor matrix
-    sized past one device's (demonstration) budget serves only sharded —
-    replicated placement exceeds the budget, each shard fits, and the
-    sharded top-k actually answers."""
-    import bench
+def _factors_past_budget(budget: int, rank: int = 64):
+    """An item matrix ~1.2x one device's ``budget`` bytes (replicated
+    placement needs all of it on every device), a small user matrix."""
+    n_items = int(budget * 1.2) // (rank * 4)
+    rng = np.random.default_rng(0)
+    U = rng.standard_normal((1024, rank), dtype=np.float32)
+    V = rng.standard_normal((n_items, rank), dtype=np.float32)
+    return U, V
 
-    monkeypatch.setenv("BENCH_SHARD_BUDGET_MB", "1")
-    out = bench._shard_hbm_ceiling_demo()
-    assert "skipped" not in out
-    assert out["n_devices"] == 8
-    assert out["factor_bytes"] > out["budget_bytes"]
-    assert not out["replicated_fits_budget"]
-    assert out["sharded_fits_budget"]
-    assert out["per_shard_bytes"] < out["factor_bytes"] // 4
-    assert out["sharded_served_ok"]
+
+def test_hbm_ceiling_demo_shards_past_one_device_budget():
+    """The HBM ceiling on the 8-device mesh: a factor matrix sized past
+    one device's (demonstration) budget serves only sharded — replicated
+    placement exceeds the budget, each shard fits, and the sharded top-k
+    actually answers."""
+    budget = 2**20
+    assert len(jax.devices()) == 8
+    U, V = _factors_past_budget(budget)
+    factor_bytes = (U.shape[0] + V.shape[0]) * U.shape[1] * 4
+    assert factor_bytes > budget            # replicated does not fit
+    sharded = serve_dist.shard_factors(U, V)
+    per_shard = sharded.per_shard_bytes()
+    assert per_shard <= budget
+    assert per_shard < factor_bytes // 4
+    vals, idx = jax.device_get(
+        sharded.topk(np.arange(8, dtype=np.int32), 10))
+    assert np.isfinite(vals).all()
+    assert (idx >= 0).all() and (idx < V.shape[0]).all()
 
 
 # ---------------------------------------------------------------------------

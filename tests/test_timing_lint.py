@@ -31,7 +31,7 @@ def test_no_wall_clock_time_in_package():
     """No time.time() anywhere in the repo-of-record: durations come
     from time.perf_counter() (monotonic — a wall-clock delta can go
     NEGATIVE mid-measurement under NTP steps), wall-clock timestamps
-    from timezone-aware datetime. Now covers bench.py and diagnostics/
+    from timezone-aware datetime. Now covers chip_smoke.py and diagnostics/
     too, not just the package."""
     findings = [f for f in timing.run(discover())
                 if f.rule == "timing-wall-clock"]
