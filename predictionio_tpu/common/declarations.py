@@ -474,6 +474,16 @@ METRICS: Dict[str, str] = {
     "pio_degraded_queries_upper_bound":
         "responses flagged degraded (upper bound; batch-granular)",
     "pio_time_to_ready_seconds": "deploy start to /readyz ready",
+    "pio_codec_plans_total":
+        "dataclass codec plans made, one a class (workflow/"
+        "json_extractor.py); still once every class has been seen",
+    "pio_codec_requests_total":
+        "request bodies extracted by path: planned / reflected (a class "
+        "whose hints would not resolve: per-request reflection)",
+    "pio_reply_checks_total":
+        "replies checked for NaN/Inf by kind: folded (in the pass that "
+        "builds the JSON value) / walked (again, after feedback or an "
+        "output blocker)",
     # ----------------------------------------------------------------- AOT
     "pio_aot_programs_total": "AOT program builds by status",
     "pio_aot_prebuild_seconds": "AOT prebuild wall time",

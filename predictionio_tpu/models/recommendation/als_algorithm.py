@@ -270,12 +270,14 @@ def _ensure_layout(ctx, td, use_mesh: bool):
     return data
 
 
-def _device_rows(topk_fn, ixs, valid, k: int):
+def _device_rows(topk_fn, ixs, k: int):
     """The device half of ``predict_batch``, the same for the replicated,
     quantized and sharded layouts: pad the batch's user indices up to a
     serving bucket (index 0 is in-bounds — KNOWN_ISSUES #5), make the ONE
-    dispatch ``topk_fn(padded_ixs, k)``, fetch, and cut each query's own
-    ``num`` out of its row. Waterfall stages, drill-downs inside
+    dispatch ``topk_fn(padded_ixs, k)``, fetch, and hand back the real
+    rows of the ``(bucket, k)`` values and indices, still arrays: the
+    `unpack` stage turns them into Python numbers, once a flush.
+    Waterfall stages, drill-downs inside
     `dispatch` (and, on the batcher's worker, host spans in a profiler
     capture): `pad`; `execute` round `enqueue` (the call that returns the
     device arrays: argument transfer and launch) and `device_get`
@@ -288,16 +290,15 @@ def _device_rows(topk_fn, ixs, valid, k: int):
     from predictionio_tpu.serving.protocol import bucket_for
 
     with waterfall.stage("pad"):
-        bucket = bucket_for(len(valid))
+        bucket = bucket_for(len(ixs))
         pix = np.zeros(bucket, dtype=np.int32)
-        pix[:len(valid)] = ixs
+        pix[:len(ixs)] = ixs
     with waterfall.stage("execute"):
         with waterfall.stage("enqueue"):
             on_device = topk_fn(pix, k)
         with waterfall.stage("device_get"):
             vals, idx = jax.device_get(on_device)
-    return [(vals[r, :min(q.num, k)], idx[r, :min(q.num, k)])
-            for r, (_qx, q, _ix) in enumerate(valid)]
+    return vals[:len(ixs)], idx[:len(ixs)]
 
 
 class ALSAlgorithm(Algorithm):
@@ -652,42 +653,51 @@ class ALSAlgorithm(Algorithm):
         from predictionio_tpu.common import waterfall
         sharding = getattr(model, "sharding", None)
         quant = getattr(model, "quant", None)
+        fetched = None   # a device layout's (values, indices) arrays
         if sharding is not None:
             # sharded device path (parallel/serve_dist.py): ONE fused
             # shard_map dispatch — per-device local top-k over each item
             # shard + the all-gather merge. The shards note turns
             # "execute is slow" into "it's the n-way sharded program",
             # one hop from /debug/slow.json.
-            rows = _device_rows(sharding.topk, ixs, valid, k)
+            fetched = _device_rows(sharding.topk, ixs, k)
             waterfall.note("shards", sharding.n_shards)
         elif quant is not None:
             # quantized device path (ops/quant.py): ONE dequantize-free
             # dispatch — int8 x int8 scores + fused rescale + top-k. The
             # quant note turns "execute is slow" into "it's the int8
             # path", one hop from /debug/slow.json.
-            rows = _device_rows(quant.topk, ixs, valid, k)
+            fetched = _device_rows(quant.topk, ixs, k)
             waterfall.note("quant", "int8")
         elif isinstance(model.user_factors, np.ndarray):
             # host: one BLAS gemm for the batch, per-row argpartition with
             # each query's own k (identical selection to predict())
             with waterfall.stage("execute"):
                 scores = model.user_factors[ixs] @ model.item_factors.T
-                rows = [topk.host_topk(scores[r], min(q.num, k))
+                rows = [tuple(a.tolist() for a in
+                              topk.host_topk(scores[r], min(q.num, k)))
                         for r, (_qx, q, _ix) in enumerate(valid)]
         else:
-            rows = _device_rows(
+            fetched = _device_rows(
                 lambda pix, k: topk.topk_for_users(
                     model.user_factors, model.item_factors, pix, k=k),
-                ixs, valid, k)
+                ixs, k)
         # same fold-in headroom guard as predict(): pad rows past the
         # item vocab never surface in a result
         with waterfall.stage("unpack"):
+            if fetched is not None:
+                # the device's (rows, k) arrays: two conversions a
+                # flush, not two a score (float32 -> float is exact
+                # either way); each query's own num is cut below
+                vals, idx = fetched
+                rows = zip(vals.tolist(), idx.tolist())
             n_real = len(model.item_vocab)
             inv = model.item_vocab.inverse()
-            for (qx, _q, _ix), (rvals, ridx) in zip(valid, rows):
+            for (qx, q, _ix), (rvals, ridx) in zip(valid, rows):
+                n = min(q.num, k)
                 out[qx] = PredictedResult(tuple(
-                    ItemScore(item=inv(int(i)), score=float(s))
-                    for s, i in zip(rvals, ridx) if int(i) < n_real))
+                    ItemScore(item=inv(i), score=s)
+                    for s, i in zip(rvals[:n], ridx[:n]) if i < n_real))
         return out
 
     def batch_predict(self, model: ALSModel,

@@ -56,6 +56,26 @@ logger = logging.getLogger("predictionio_tpu.server")
 #: headers (Retry-After on 503 saturation).
 Response = Tuple[int, Any]
 
+#: how each 200-or-500 reply was checked for NaN / infinity: in the walk
+#: that built it, or again after feedback or an output blocker touched it
+_M_REPLY_CHECKS = telemetry.registry().counter(
+    "pio_reply_checks_total",
+    "Replies checked for non-finite numbers, by whether the pass that "
+    "built the JSON value did it (folded) or a second walk (walked)",
+    labelnames=("kind",))
+_M_REPLY_FOLDED = _M_REPLY_CHECKS.labels(kind="folded")
+_M_REPLY_WALKED = _M_REPLY_CHECKS.labels(kind="walked")
+
+
+def _codec_status() -> Dict[str, Any]:
+    """`GET /`'s codec block, beside `batching`: the process-wide
+    counters /metrics has, which stand still once every query and
+    result class has been seen."""
+    return {**json_extractor.stats(), "replyChecks": {
+        "folded": int(_M_REPLY_FOLDED.value),
+        "walked": int(_M_REPLY_WALKED.value)}}
+
+
 #: distinguishes concurrently-live QueryAPI instances in the process
 #: metrics registry (tests, blue/green deploys in one process)
 _query_api_seq = itertools.count()
@@ -1103,6 +1123,7 @@ class QueryAPI:
         batcher = self._batcher
         out["batching"] = ({"enabled": True, **batcher.stats()}
                            if batcher is not None else {"enabled": False})
+        out["codec"] = _codec_status()
         if batcher is not None:
             # read-only, a fact of the compiled programs: whether the
             # flushes above sort whole score rows or k chunks of them
@@ -1169,6 +1190,7 @@ class QueryAPI:
             "modelBytesTotal": self.registry.total_model_bytes(),
             "hbmHardCapMb": self.registry.hard_cap_mb,
             "oversubscribed": self.registry.oversubscribed(),
+            "codec": _codec_status(),
         }
 
     def _readyz(self) -> Response:
@@ -1384,7 +1406,10 @@ class QueryAPI:
             devicewatch.note_serving_flush()
         with waterfall.activate((rec,)):
             with waterfall.stage("serialize"):
-                result = json_extractor.to_json_obj(prediction)
+                # the pass that builds the reply also says whether it is
+                # finite: no second walk of what nobody changes below
+                result, non_finite = json_extractor.to_json_checked(
+                    prediction)
         if degraded:
             # per-RESPONSE count: with batching on this over-counts (the
             # whole flush is tainted), hence "upper bound" in the metric
@@ -1403,17 +1428,24 @@ class QueryAPI:
             result = self._feedback(instance, query, prediction, result,
                                     query_time)
 
-        for blocker in self.plugin_context.output_blockers.values():
+        blockers = self.plugin_context.output_blockers
+        for blocker in blockers.values():
             result = blocker.process(
                 instance, json_extractor.to_json_obj(query), result,
                 self.plugin_context)
 
-        if tree_has_non_finite(result):
+        if self.config.feedback or blockers:
+            # feedback or a blocker may have changed the payload since
+            # the fold: what is validated is what is sent, so walk it
+            non_finite = tree_has_non_finite(result)
+            _M_REPLY_WALKED.inc()
+        else:
+            _M_REPLY_FOLDED.inc()
+        if non_finite:
             # the reference contract is real scores (quickstart_test.py:
             # 95-100); json.dumps would otherwise emit bare NaN tokens —
             # invalid JSON — straight to clients. Checked AFTER feedback/
-            # blockers so the final payload is what's validated; a cheap
-            # float walk, not a second serialization, on the latency path.
+            # blockers so the final payload is what's validated.
             logger.error("prediction for instance %s contains non-finite "
                          "scores; refusing to serve it", instance.id)
             if tenant is not None:

@@ -545,3 +545,174 @@ def test_concurrent_load_throughput(trained):
     finally:
         server.shutdown()
         api.close()
+
+
+# ---------------------------------------------------------------------------
+# the codec on the request path (workflow/json_extractor.py plans, the
+# finite check folded into the pass that builds the reply)
+# ---------------------------------------------------------------------------
+
+class _FixedTopK:
+    """Stands where a sharded layout's device program does: the fetched
+    (bucket, k) arrays are fixed float32 / int32, whoever asks. Row r is
+    a rotation of one row; index 6 is fold-in headroom past the six-item
+    vocabulary and never surfaces."""
+    n_shards = 1
+    VALS = [3.1415927, 2.7182817, 1.0e-7, 0.1, -0.0, -1.5, -123456.79]
+    IDX = [4, 2, 0, 6, 5, 1, 3]
+
+    def __init__(self, poison=None):
+        import numpy as np
+        self.vals = np.asarray([self.VALS[r % 7:] + self.VALS[:r % 7]
+                                for r in range(64)], np.float32)
+        self.idx = np.asarray([self.IDX[r % 7:] + self.IDX[:r % 7]
+                               for r in range(64)], np.int32)
+        if poison is not None:
+            self.vals[:, 1] = poison
+
+    def topk(self, pix, k):
+        return self.vals[:len(pix), :k], self.idx[:len(pix), :k]
+
+
+def _fixed_api(storage, poison=None, **kw):
+    api = QueryAPI(storage=storage, **kw)
+    api.models[0] = dataclasses.replace(
+        api.models[0], sharding=_FixedTopK(poison))
+    return api
+
+
+def _wire(api, q):
+    from predictionio_tpu.data.api.http import dispatch_request
+    out = dispatch_request(api, "POST", "/queries.json",
+                           json.dumps(q).encode(), {})
+    return out.status, out.data
+
+
+#: what the server answered for `_FixedTopK` before the codec was planned
+#: and the unpack went through `tolist()`: status and bytes, to the byte
+_FIVE = (b'{"itemScores": [{"item": "i4", "score": 3.1415927410125732}, '
+         b'{"item": "i2", "score": 2.7182817459106445}, '
+         b'{"item": "i0", "score": 1.0000000116860974e-07}, '
+         b'{"item": "i5", "score": -0.0}, {"item": "i1", "score": -1.5}]}')
+GOLDEN_FLUSH = [
+    ({"user": "u0", "num": 7}, 200, _FIVE),      # k = the 6 items; one is pad
+    ({"user": "u1", "num": 3}, 200,
+     b'{"itemScores": [{"item": "i4", "score": 3.1415927410125732}, '
+     b'{"item": "i2", "score": 2.7182817459106445}, '
+     b'{"item": "i0", "score": 1.0000000116860974e-07}]}'),
+    ({"user": "u2", "num": 1}, 200,
+     b'{"itemScores": [{"item": "i4", "score": 3.1415927410125732}]}'),
+    ({"user": "nobody", "num": 4}, 200, b'{"itemScores": []}'),
+    ({"user": "u3", "num": 0}, 200, b'{"itemScores": []}'),
+    ({"user": "u4"}, 400, b'{"message": "field num is required for Query"}'),
+    ({"user": "u5", "num": 2, "extra": 1}, 400,
+     b'{"message": "unknown field(s) [\'extra\'] for Query '
+     b'(accepts [\'num\', \'user\'])"}'),
+]
+
+
+@pytest.mark.parametrize("batching", ["on", "off"])
+def test_reply_bytes_are_the_parents(trained, monkeypatch, batching):
+    monkeypatch.setenv("PIO_SERVE_DEVICE_MS", "1e9")  # pin the device path
+    storage, _app_id, _iid = trained
+    api = _fixed_api(storage, config=ServerConfig(batching=batching))
+    try:
+        assert (api._batcher is not None) == (batching == "on")
+        assert [(q, *_wire(api, q))
+                for q, _st, _data in GOLDEN_FLUSH] == GOLDEN_FLUSH
+    finally:
+        api.close()
+
+
+NON_FINITE_MESSAGE = (
+    "prediction contains non-finite scores (the deployed model is "
+    "numerically invalid); retrain or /reload a healthy instance")
+
+
+@pytest.mark.parametrize("poison", [float("nan"), float("inf"),
+                                    float("-inf")], ids=str)
+@pytest.mark.parametrize("batching", ["on", "off"])
+def test_non_finite_score_is_still_the_same_500(trained, monkeypatch, caplog,
+                                               batching, poison):
+    monkeypatch.setenv("PIO_SERVE_DEVICE_MS", "1e9")
+    storage, _app_id, iid = trained
+    api = _fixed_api(storage, poison=poison,
+                     config=ServerConfig(batching=batching))
+    try:
+        with caplog.at_level("ERROR", logger="predictionio_tpu.server"):
+            status, data = _wire(api, {"user": "u1", "num": 4})
+        assert status == 500
+        assert json.loads(data) == {"message": NON_FINITE_MESSAGE}
+        assert (f"prediction for instance {iid} contains non-finite "
+                "scores; refusing to serve it") in caplog.text
+        # a finite cut of the same row is served
+        status, data = _wire(api, {"user": "u1", "num": 1})
+        assert status == 200 and len(json.loads(data)["itemScores"]) == 1
+    finally:
+        api.close()
+
+
+@pytest.mark.parametrize("poison", [float("nan"), float("inf")], ids=str)
+@pytest.mark.parametrize("batching", ["on", "off"])
+def test_non_finite_put_in_after_the_fold_is_caught(trained, batching,
+                                                    poison):
+    """An output blocker runs after the pass that builds (and checks) the
+    reply: what it puts in is found by the walk kept for that case."""
+    storage, _app_id, _iid = trained
+
+    class Spoil(EngineServerPlugin):
+        plugin_name = "spoil"
+        plugin_description = "overwrites the first score"
+        plugin_type = OUTPUT_BLOCKER
+
+        def process(self, engine_instance, query_obj, prediction_obj, context):
+            prediction_obj["itemScores"][0]["score"] = poison   # in place
+            return prediction_obj
+
+    api = QueryAPI(storage=storage, config=ServerConfig(batching=batching),
+                   plugin_context=EngineServerPluginContext([Spoil()]))
+    try:
+        walked0 = api.handle("GET", "/")[1]["codec"]["replyChecks"]["walked"]
+        status, data = _wire(api, {"user": "u1", "num": 4})
+        assert status == 500
+        assert json.loads(data) == {"message": NON_FINITE_MESSAGE}
+        checks = api.handle("GET", "/")[1]["codec"]["replyChecks"]
+        assert checks["walked"] == walked0 + 1
+    finally:
+        api.close()
+
+
+def test_codec_counters_on_status_and_metrics(trained):
+    storage, _app_id, _iid = trained
+    api = QueryAPI(storage=storage)
+    try:
+        for k in range(3):      # warm-up: the classes are seen here
+            assert _post(api, {"user": f"u{k}", "num": 4})[0] == 200
+        before = api.handle("GET", "/")[1]["codec"]
+        assert set(before) == {"plans", "requests", "replyChecks"}
+        assert before["plans"] >= 3   # Query, PredictedResult, ItemScore
+        for k in range(100):
+            assert _post(api, {"user": f"u{k % 8}", "num": 1 + k % 5})[0] == 200
+        assert _post(api, {"user": "u1"})[0] == 400     # planned, and refused
+        after = api.handle("GET", "/")[1]["codec"]
+        assert after["plans"] == before["plans"]
+        assert (after["requests"]["planned"]
+                - before["requests"]["planned"]) == 101
+        assert after["requests"]["reflected"] == before["requests"]["reflected"]
+        assert (after["replyChecks"]["folded"]
+                - before["replyChecks"]["folded"]) == 100
+        assert after["replyChecks"]["walked"] == before["replyChecks"]["walked"]
+        status, text, _headers = api.handle("GET", "/metrics")
+        assert status == 200
+        for line in (f'pio_codec_plans_total {after["plans"]}',
+                     'pio_codec_requests_total{path="planned"} '
+                     f'{after["requests"]["planned"]}',
+                     'pio_codec_requests_total{path="reflected"} '
+                     f'{after["requests"]["reflected"]}',
+                     'pio_reply_checks_total{kind="folded"} '
+                     f'{after["replyChecks"]["folded"]}',
+                     'pio_reply_checks_total{kind="walked"} '
+                     f'{after["replyChecks"]["walked"]}'):
+            assert line in text.splitlines(), line
+    finally:
+        api.close()

@@ -541,7 +541,7 @@ def test_legacy_wire_shape_without_engines_conf(mt_trained):
             "status", "engineInstance", "algorithms", "requestCount",
             "avgServingSec", "lastServingSec", "degradedCount",
             "draining", "serverStartTime", "generation", "batching",
-            "aot"}
+            "aot", "codec"}
         status, ready = api.handle("GET", "/readyz")
         assert status == 200
         assert "generations" not in ready and "queueDepths" not in ready

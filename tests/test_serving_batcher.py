@@ -260,3 +260,81 @@ def test_batch_capable_detects_real_overrides():
     # the base fallback maps predict, preserving order
     assert Plain().predict_batch(None, [1, 2]) == [("p", 1), ("p", 2)]
     assert Batched().predict_batch(None, [1, 2]) == [("b", 1), ("b", 2)]
+
+
+# ------------------------------------------- predict_batch's unpack stage
+class _FetchedRows:
+    """In a sharded layout's place: fixed float32 / int32 (bucket, k)
+    arrays for any flush. Items past ``n_real`` are fold-in headroom."""
+    n_shards = 1
+
+    def __init__(self, n_items, seed=11):
+        import numpy as np
+        rng = np.random.default_rng(seed)
+        self.vals = -np.sort(-rng.standard_normal((64, 12)).astype(
+            np.float32), axis=1)
+        self.idx = rng.integers(0, n_items + 3, (64, 12)).astype(np.int32)
+        self.asked = []
+
+    def topk(self, pix, k):
+        self.asked.append((len(pix), k))
+        return self.vals[:len(pix), :k], self.idx[:len(pix), :k]
+
+
+def _unpack_as_it_was(model, stub, queries, k):
+    """The scalar-by-scalar loop `predict_batch` ran before `tolist()`."""
+    from predictionio_tpu.models.recommendation.engine import (
+        ItemScore, PredictedResult,
+    )
+    n_real, inv = len(model.item_vocab), model.item_vocab.inverse()
+    out, row = [], 0
+    for q in queries:
+        if model.user_vocab.get(q.user) is None or min(q.num, n_real) <= 0:
+            out.append(PredictedResult(()))
+            continue
+        n = min(q.num, k)
+        out.append(PredictedResult(tuple(
+            ItemScore(item=inv(int(i)), score=float(s))
+            for s, i in zip(stub.vals[row, :n], stub.idx[row, :n])
+            if int(i) < n_real)))
+        row += 1
+    return out
+
+
+@pytest.mark.parametrize("nums,bucket", [
+    ([10], 1), ([10] * 4, 4), ([10] * 3, 4), ([10] * 50, 64),
+    ([10] * 64, 64), ([3, 10, 1, 0, 12, 40, -2, 7], 16),
+], ids=["bucket1", "bucket4", "bucket4_padded", "bucket64_of_50",
+        "bucket64_full", "mixed_num"])
+def test_predict_batch_unpack_gives_the_same_results(nums, bucket):
+    from predictionio_tpu.data.bimap import BiMap
+    from predictionio_tpu.models.recommendation.als_algorithm import (
+        ALSAlgorithm, ALSAlgorithmParams, ALSModel,
+    )
+    from predictionio_tpu.models.recommendation.engine import Query
+
+    n_items = 9    # under the stub's k of 12, so the headroom guard bites
+    stub = _FetchedRows(n_items)
+    model = ALSModel(
+        rank=4, user_factors=None, item_factors=None,
+        user_vocab=BiMap.string_int(f"u{j}" for j in range(80)),
+        item_vocab=BiMap.string_int(f"i{j}" for j in range(n_items)),
+        sharding=stub)
+    queries = [Query(user=f"u{j}", num=n) for j, n in enumerate(nums)]
+    queries.insert(len(queries) // 2, Query(user="nobody", num=5))
+    got = ALSAlgorithm(ALSAlgorithmParams()).predict_batch(model, queries)
+    valid = [n for n in nums if min(n, n_items) > 0]
+    k = min(max(valid), n_items)
+    assert stub.asked == [(bucket, k)]
+    want = _unpack_as_it_was(model, stub, queries, k)
+    assert got == want
+    dropped = 0
+    for g, w in zip(got, want):
+        for a, b in zip(g.itemScores, w.itemScores):
+            assert type(a.item) is str and type(a.score) is float
+            assert (a.item, repr(a.score)) == (b.item, repr(b.score))
+    for row, n in enumerate(valid):
+        dropped += int((stub.idx[row, :min(n, k)] >= n_items).sum())
+    assert dropped > 0   # pad rows were asked for, and none surfaced
+    assert sum(len(g.itemScores) for g in got) == sum(
+        min(n, k) for n in valid) - dropped

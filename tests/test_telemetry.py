@@ -743,7 +743,8 @@ def test_responses_byte_identical_with_telemetry_on_and_off(memory_storage):
         assert set(info) == {
             "status", "engineInstance", "algorithms", "requestCount",
             "avgServingSec", "lastServingSec", "degradedCount", "draining",
-            "serverStartTime", "generation", "batching", "aot"}
+            "serverStartTime", "generation", "batching", "aot",
+            "codec"}
     finally:
         telemetry.set_enabled(None)
         api.close()
