@@ -1,7 +1,9 @@
 """A serving cell, from the parent's side: a child runs `pio deploy` on
 the chip, this process offers the load over HTTP, and once the window
-has closed and the child has gone it checks a sample of the replies
-against the plain reference. The parent stays off jax.
+has closed and the child has gone the configuration's adapter checks a
+sample of the replies against its plain reference. What a query and a
+reply are is the adapter's (adapters/<name>.py). The parent stays off
+jax.
 """
 
 import glob
@@ -16,16 +18,10 @@ import time
 import urllib.error
 import urllib.request
 
-import numpy as np
-
 import compare
-import gen_factors
 import harness
 import loadgen
 import reduce
-
-sys.path.insert(0, os.path.join(harness.HERE, "reference"))
-import topk_reference  # noqa: E402
 
 READY_TIMEOUT_S = 1100
 
@@ -92,8 +88,12 @@ class Deploy:
         return harness.load_json(path)
 
     def batching(self):
-        b = get_json(self.port, "/")["batching"]
-        return {"batches": b["batches"], "queries": b["queries"],
+        """The batcher's counters, and under `page` the whole `GET /`
+        object they came with, for the metrics' `counter` terms."""
+        page = get_json(self.port, "/")
+        b = page["batching"]
+        return {"page": page,
+                "batches": b["batches"], "queries": b["queries"],
                 "rejected": b["rejected"],
                 "queue_wait_s": b["avgQueueWaitMs"] * b["queries"] / 1e3,
                 "flush_s": b["avgFlushMs"] * b["batches"] / 1e3,
@@ -115,71 +115,50 @@ def diff(a, b):
                                      "queue_wait_s", "flush_s")}
 
 
-def check_replies(spec, model, seed, records, control=False):
-    """A sample of the window's requests, drawn from the seed, against
-    the reference: every one has to have come, with k distinct items
-    whose reference scores are the best to within the limits."""
-    traffic, config = spec["traffic"], spec["config"]
-    k = config["query"]["num"]
-    rng = np.random.default_rng([int(seed), 0xC4])
-    n = min(int(traffic["checked_replies"]), len(records))
-    picks = rng.choice(len(records), n, replace=False)
-    nu, ni, r, decay = (model["n_users"], model["n_items"], model["rank"],
-                        model["decay"])
-    V = gen_factors.matrix(seed, "item", ni, r, decay)
-    user_ixs = np.asarray([records[p][0] for p in picks], np.int64)
-    rows = gen_factors.rows(seed, "user", user_ixs, nu, r, decay)
-    replies = []
-    for p in picks:
-        items = records[p][-1]
-        if items is not None:
-            try:
-                items = [(int(name[1:]), s) for name, s in items]
-            except ValueError:
-                items = None
-        replies.append(items)
-    precisions = {"program": "float32"}
-    if control:
-        precisions["control"] = config["serving"]["control_precision"]
-    step = 32
-    prepared = {prec: topk_reference.prepare(V, prec)
-                for prec in set(precisions.values())}
-    state = {name: {"rank_gap": 0.0, "score_gap": 0.0, "bad_replies": 0.0}
-             for name in precisions}
-    for s in range(0, n, step):
-        ref = topk_reference.scores(rows[s:s + step], prepared["float32"])
-        for name, prec in precisions.items():
-            if name == "program":
-                got = [(int(user_ixs[s + j]), replies[s + j])
-                       for j in range(ref.shape[0])]
-            else:
-                # the control in the program's place: what the lower
-                # precision would have served for the same queries
-                low = topk_reference.scores(rows[s:s + step], prepared[prec], prec)
-                got = []
-                for j in range(ref.shape[0]):
-                    top = topk_reference.topk(low[j], k)
-                    got.append((int(user_ixs[s + j]),
-                                [(int(i), float(low[j][i])) for i in top]))
-            lookup = {int(user_ixs[s + j]): ref[j]
-                      for j in range(ref.shape[0])}
-            # one user may be asked twice in a block; same row either way
-            nums = compare.topk_numbers(got, lookup.__getitem__, k)
-            for key in state[name]:
-                state[name][key] = (state[name][key] + nums[key]
-                                    if key == "bad_replies"
-                                    else max(state[name][key], nums[key]))
-    return {**state, "checked": int(n)}
+def read_trace(work, trace_dir):
+    """reduce.summarize_trace's summary of the newest capture under
+    `trace_dir`, read by a child: the parent stays off jax."""
+    paths = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    if not paths:
+        harness.fail("the profiler left no .xplane.pb")
+    summary_path = os.path.join(work, "trace_summary.json")
+    rc = harness.run_child(
+        [sys.executable, os.path.join(harness.HERE, "reduce_child.py"),
+         max(paths, key=os.path.getmtime), summary_path],
+        harness.child_env(rehearse=True), os.path.join(work, "reduce.log"))
+    if rc != 0:
+        harness.tail(os.path.join(work, "reduce.log"))
+        harness.fail("the trace could not be reduced")
+    return harness.load_json(summary_path)["summary"]
+
+
+def offered(spec, adapter, model, seed, seconds):
+    """What the cell sends, all of it from the seed: -> (due, asked,
+    warm): an open loop's arrival offsets (None for a closed loop), the
+    window's queries in the order they leave, and the warm-up's, which
+    the window leaves alone."""
+    traffic = spec["traffic"]
+    if traffic["kind"] == "closed_loop":
+        # more than any window can ask; the loop stops at its end
+        due, n_window = None, int(traffic["max_queries"])
+    else:
+        due = loadgen.arrival_times(seed, traffic, seconds)
+        n_window = len(due)
+    queries = adapter.queries(spec, model, seed,
+                              n_window + int(traffic["warmup_queries"]))
+    return due, queries[:n_window], queries[n_window:]
 
 
 def run(spec, args):
     workload = spec["cell"]["name"]
     config, traffic = spec["config"], spec["traffic"]
     work = harness.work_dir(workload, fresh=True)
+    adapter = harness.adapter_of(config)
     model = config["model"]
     if args.rehearse:
-        model = gen_factors.scaled_model(model, traffic["rehearse_cut"])
-    k = config["query"]["num"]
+        model = adapter.rehearsal_model(model, traffic["rehearse_cut"])
+    wire = adapter.wire(spec)
     dep = Deploy(spec, args, work)
     try:
         t_ready = dep.wait_ready()
@@ -187,24 +166,15 @@ def run(spec, args):
         device = started["device"]
         ready_s = t_ready - started["t_deploy_start"]
         harness.log("serve:ready", ready_s=ready_s,
-                    factors_s=started["factors_s"],
+                    models_s=started["models_s"],
                     instance_s=started["instance_s"], **device)
         conns = int(traffic["connections"])
         closed = traffic["kind"] == "closed_loop"
-        if closed:
-            # more than any window can ask; the loop stops at its end
-            n_window = int(traffic["max_queries"])
-            due = None
-        else:
-            due = loadgen.arrival_times(args.seed, traffic, args.seconds)
-            n_window = len(due)
-        n_warm = int(traffic["warmup_queries"])
-        users = loadgen.query_users(args.seed, n_window + n_warm,
-                                    model["n_users"],
-                                    config["query"]["zipf_a"])
-        # warm-up: the window's own pattern, on users the window leaves
+        due, asked, warm_up = offered(spec, adapter, model, args.seed,
+                                      args.seconds)
+        # warm-up: the window's own pattern, on queries the window leaves
         warm, _, _ = loadgen.closed_loop(
-            dep.port, users[n_window:], k, conns, traffic["warmup_seconds"])
+            dep.port, warm_up, wire, conns, traffic["warmup_seconds"])
         if not warm or any(r[-1] is None for r in warm):
             harness.fail("a warm-up query failed")
         s0, b0 = dep.ask("stats"), dep.batching()
@@ -228,10 +198,10 @@ def run(spec, args):
                 t.start()
         if closed:
             records, t_start, t_end = loadgen.closed_loop(
-                dep.port, users[:n_window], k, conns, args.seconds)
+                dep.port, asked, wire, conns, args.seconds)
         else:
             records, t_start = loadgen.open_loop(
-                dep.port, users[:n_window], k, due, conns)
+                dep.port, asked, wire, due, conns)
             t_end = max(r[3] for r in records)
         for t in timers:
             t.join()
@@ -239,8 +209,9 @@ def run(spec, args):
     finally:
         dep.stop()
     window = diff(b0, b1)
+
     def full(r):
-        return r[-1] is not None and len(r[-1]) == k
+        return wire.whole(asked[r[0]], r[-1])
 
     failed = sum(1 for r in records if not full(r))
     good = len(records) - failed
@@ -248,7 +219,7 @@ def run(spec, args):
                 window_s=t_end - t_start, batching=window,
                 sizes=b1["sizes"], buckets=b1["buckets"],
                 compiles=s1["compiles"] - s0["compiles"])
-    checked = check_replies(spec, model, args.seed, records,
+    checked = adapter.check(spec, model, args.seed, asked, records,
                             control=args.control)
     harness.log("serve:checked", **checked)
     numbers = dict(checked["program"])
@@ -264,41 +235,39 @@ def run(spec, args):
              "rejected": window["rejected"],
              "queue_wait_ms_total": window["queue_wait_s"] * 1e3,
              "flush_ms_total": window["flush_s"] * 1e3,
+             "counters": {"window": [b0["page"], b1["page"]]},
              "trace": None}
     if not closed:
         late = loadgen.lateness_ms(records)
         facts["late_ms_p95"] = reduce.percentile_all(late, 0, 95)
         facts["offered_qps"] = len(records) / max(due[-1], 1e-9)
-    if args.rehearse:
-        return {"ok": ok, "numbers": numbers, "requests": len(records),
-                "failed": failed, "facts_keys": sorted(facts),
-                "reference": checked}
-    device["memory_peak_bytes"] = s1["memory_peak_bytes"]
-    facts["peaks"] = harness.peaks_for(device["kind"])
     if args.trace:
-        paths = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
-                                       "*.xplane.pb"))
-        if not paths:
-            harness.fail("the profiler left no .xplane.pb")
-        # read by a child: the parent stays off jax
-        summary_path = os.path.join(work, "trace_summary.json")
-        rc = harness.run_child(
-            [sys.executable, os.path.join(harness.HERE, "reduce_child.py"),
-             max(paths, key=os.path.getmtime), summary_path],
-            harness.child_env(rehearse=True), os.path.join(work, "reduce.log"))
-        if rc != 0:
-            harness.tail(os.path.join(work, "reduce.log"))
-            harness.fail("the trace could not be reduced")
-        summary = harness.load_json(summary_path)["summary"]
-        if summary is None or summary["busy_s"] <= 0:
-            harness.fail("the traced window holds no device operation")
+        summary = read_trace(work, trace_dir)
         traced = diff(marks["b_start"], marks["b_stop"])
+        facts["counters"]["traced"] = [marks["b_start"]["page"],
+                                       marks["b_stop"]["page"]]
         facts.update({"trace": summary,
                       "traced_flushes": traced["batches"] or None,
                       "traced_queries": traced["queries"] or None,
                       "mean_flush_rows": (traced["queries"] / traced["batches"]
                                           if traced["batches"] else None),
-                      "trace.window_s": summary["window_s"]})
+                      "trace.window_s": summary and summary["window_s"]})
+    if args.rehearse:
+        # which per-layer readers found something to read; on the CPU
+        # their values mean nothing and are not kept, and there is no
+        # chip whose peaks a share could be of
+        facts["peaks"] = None
+        return {"ok": ok, "numbers": numbers, "compared": compared,
+                "requests": len(records),
+                "failed": failed, "facts_keys": sorted(facts),
+                "layer_metrics": sorted(reduce.layer_metrics(
+                    spec["per_layer"], facts)),
+                "reference": checked}
+    device["memory_peak_bytes"] = s1["memory_peak_bytes"]
+    facts["peaks"] = harness.peaks_for(device["kind"])
+    if args.trace:
+        if summary is None or summary["busy_s"] <= 0:
+            harness.fail("the traced window holds no device operation")
         device["busy_s"] = summary["busy_s"]
         device["window_s"] = summary["window_s"]
         metrics = reduce.layer_metrics(spec["per_layer"], facts)

@@ -1,8 +1,10 @@
-"""The child that holds the chip for a serving cell: makes the factors
-from the seed, writes them as the completed engine instance a `pio
-train` would have left (the program's own model_io and storage calls,
-into the in-memory store of this process), then runs `pio deploy`'s
-entry point, which serves until it is told to stop.
+"""The child that holds the chip for a serving cell: has the
+configuration's adapter make the model from the seed (and, where the
+deployment reads the event store while it serves, write its events),
+writes it as the completed engine instance a `pio train` would have
+left (the program's own model_io and storage calls, into the in-memory
+store of this process), then runs `pio deploy`'s entry point, which
+serves until it is told to stop.
 
 A side thread answers the parent's few questions over stdin, one JSON
 object a line, each reply a file in --ctl-dir: the device's peak memory,
@@ -18,7 +20,6 @@ import sys
 import threading
 import time
 
-import gen_factors
 import harness
 
 
@@ -68,30 +69,25 @@ def main():
     spec = harness.load_cell(args.workload)
     device = harness.device_gate(spec["cell"]["chips"], rehearse)
     config = spec["config"]
+    adapter = harness.adapter_of(config)
     model = config["model"]
     if rehearse:
-        model = gen_factors.scaled_model(model, spec["traffic"]["rehearse_cut"])
+        model = adapter.rehearsal_model(model,
+                                        spec["traffic"]["rehearse_cut"])
     t0 = time.time()
-    nu, ni, r = model["n_users"], model["n_items"], model["rank"]
-    U = gen_factors.matrix(args.seed, "user", nu, r, model["decay"])
-    V = gen_factors.matrix(args.seed, "item", ni, r, model["decay"])
-    t_factors = time.time()
 
-    from predictionio_tpu.data.bimap import BiMap
     from predictionio_tpu.data.storage import (EngineInstance, Model,
                                                get_storage)
-    from predictionio_tpu.models.recommendation.als_algorithm import ALSModel
     from predictionio_tpu.tools import cli
     from predictionio_tpu.workflow import model_io
 
     engine_dir = os.path.join(harness.ROOT, config["engine_dir"])
     variant = harness.load_json(engine_dir, "engine.json")
-    als = ALSModel(rank=r, user_factors=U, item_factors=V,
-                   user_vocab=BiMap({f"u{k}": k for k in range(nu)}),
-                   item_vocab=BiMap({f"i{k}": k for k in range(ni)}))
-    blob = model_io.serialize_models([als], check_finite=True)
-    del als, U, V
     storage = get_storage()
+    models = adapter.models(config, model, args.seed, storage, variant)
+    t_models = time.time()
+    blob = model_io.serialize_models(models, check_finite=True)
+    del models
     now = datetime.datetime.now(datetime.timezone.utc)
     instance_id = storage.get_meta_data_engine_instances().insert(
         EngineInstance(
@@ -106,33 +102,19 @@ def main():
     storage.get_model_data_models().insert(Model(id=instance_id, models=blob))
     n_blob = len(blob)
     del blob
-    if args.fault == "altered_answer":
-        # tests only: every answer leaves with its best item replaced
-        from predictionio_tpu.models.recommendation import als_algorithm
-        from predictionio_tpu.models.recommendation.engine import (
-            ItemScore, PredictedResult)
-
-        honest = als_algorithm.ALSAlgorithm.predict_batch
-
-        def altered(self, model, queries):
-            out = []
-            for res in honest(self, model, queries):
-                items = list(res.itemScores)
-                if items:
-                    items[0] = ItemScore(item="i0", score=items[0].score)
-                out.append(PredictedResult(tuple(items)))
-            return out
-
-        als_algorithm.ALSAlgorithm.predict_batch = altered
-    elif args.fault:
-        harness.fail(f"a serving cell has no fault {args.fault!r}")
+    if args.fault:
+        # tests only: the timed path broken underneath
+        if args.fault not in adapter.FAULTS:
+            harness.fail(f"adapter {config['adapter']!r} has no fault "
+                         f"{args.fault!r}")
+        adapter.FAULTS[args.fault]()
     threading.Thread(target=control_loop, args=(args.ctl_dir,),
                      daemon=True).start()
     t_deploy = time.time()
     with open(os.path.join(args.ctl_dir, "deploy_start.json"), "w") as f:
         json.dump({"device": device, "t_child_start": t0,
-                   "factors_s": t_factors - t0,
-                   "instance_s": t_deploy - t_factors,
+                   "models_s": t_models - t0,
+                   "instance_s": t_deploy - t_models,
                    "t_deploy_start": t_deploy, "model_blob_bytes": n_blob}, f)
     return cli.main(["deploy", "--engine-dir", engine_dir, "--ip",
                      "127.0.0.1", "--port", str(args.port), "--telemetry",

@@ -5,6 +5,7 @@ The parent (run.py) never imports jax or anything under
 predictionio_tpu; its children hold the chip one after another.
 """
 
+import importlib.util
 import json
 import os
 import shutil
@@ -50,6 +51,37 @@ def load_cell(workload):
     return {"cell": cell, "config": config, "traffic": traffic,
             "end_to_end": e2e, "per_layer": layer,
             "run_seconds": bj["run_seconds"]}
+
+
+def load_module(module, path):
+    """The Python file at `path` as the module `module`, loaded once: a
+    file the benchmark finds by a name in its data."""
+    if module not in sys.modules:
+        spec = importlib.util.spec_from_file_location(module, path)
+        sys.modules[module] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(sys.modules[module])
+    return sys.modules[module]
+
+
+def load_adapter(name):
+    """adapters/<name>.py, by the name a configuration's file gives
+    under `adapter`: what knows one engine (adapters/rec_als.py says
+    what an adapter holds). Its reference is under reference/."""
+    path = os.path.join(HERE, "adapters", name + ".py")
+    if not os.path.exists(path):
+        raise SystemExit(f"run.py: no adapter {name!r} under "
+                         "benchmark/adapters/")
+    ref = os.path.join(HERE, "reference")
+    if ref not in sys.path:
+        sys.path.insert(0, ref)
+    return load_module("adapter_" + name, path)
+
+
+def adapter_of(config):
+    if "adapter" not in config:
+        raise SystemExit(f"run.py: configuration {config.get('name')!r} "
+                         "names no `adapter` (there is no default)")
+    return load_adapter(config["adapter"])
 
 
 def peaks_for(device_kind):

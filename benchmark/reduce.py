@@ -8,7 +8,9 @@ left out of the line; a share of a roofline or of a peak is never 0.
 """
 
 import bisect
+import inspect
 import math
+import os
 import re
 
 import numpy as np
@@ -91,11 +93,16 @@ def summarize_trace(trace, unnamed_gap="outside_any_span"):
     full length); ops: {op: seconds} self-contained leaf ops only (an op
     that contains other ops, a while or a call, is left out of the
     ranking so nothing is counted twice); gaps: idle stretches of the
-    first device, longest first, each with the benchmark span it lies in.
+    first device, longest first, each with the benchmark span it lies in;
+    spans: {name: [seconds, count]} of the bench:<name> annotations that
+    START inside the window, their full length, as the programs'.
+
+    A capture with spans and no device plane (a rehearsal on the CPU)
+    gives the spans and a busy_s of 0, which no cell accepts from a chip.
     """
     planes = trace["planes"]
     spans = trace["spans"]
-    if not planes:
+    if not planes and not spans:
         return None
     win = [s for s in spans if s[0] == "window"]
     if win:
@@ -134,9 +141,15 @@ def summarize_trace(trace, unnamed_gap="outside_any_span"):
             owner = mods[m][2] if m >= 0 and s < mods[m][1] else "?"
             key = f"{owner}/{op_name(n)}"
             ops[key] = ops.get(key, 0.0) + (e - s)
-    n_dev = len(planes)
+    n_dev = max(len(planes), 1)
     for rec in programs.values():
         rec[0] /= n_dev
+    span_totals = {}
+    for n, s, d in spans:
+        if n != "window" and lo <= s < hi:
+            rec = span_totals.setdefault(n, [0.0, 0])
+            rec[0] += d
+            rec[1] += 1
     gaps = []
     edges = [lo] + [t for se in (first_busy or []) for t in se] + [hi]
     for a, b in zip(edges[0::2], edges[1::2]):
@@ -151,8 +164,9 @@ def summarize_trace(trace, unnamed_gap="outside_any_span"):
     return {
         "window_s": hi - lo,
         "busy_s": sum(busy) / n_dev,
-        "devices": n_dev,
+        "devices": len(planes),
         "programs": programs,
+        "spans": span_totals,
         "ops": {n: t / n_dev for n, t in ops.items()},
         "gap_seconds_by_span": by_name,
         "longest_gaps": sorted(gaps, key=lambda g: -g[1])[:10],
@@ -191,9 +205,34 @@ def percentile_all(latencies_ms, failed, q):
 # reducers (named by benchmark/metrics/*.json)
 # ---------------------------------------------------------------------------
 
+def _counter(pages, path):
+    """By how much the number at a dotted path into the program's status
+    page (`GET /`) rose between two readings of the page."""
+    if not pages:
+        return None
+    ends = []
+    for node in pages:
+        for key in path.split("."):
+            node = node.get(key) if isinstance(node, dict) else None
+        if isinstance(node, bool) or not isinstance(node, (int, float)):
+            return None
+        ends.append(node)
+    return float(ends[1] - ends[0])
+
+
+#: a term's key -> (the table of the trace's summary it searches by
+#: pattern, 0 for seconds or 1 for calls)
+_TRACED = {"program_s": ("programs", 0), "program_n": ("programs", 1),
+           "span_s": ("spans", 0), "span_n": ("spans", 1)}
+
+
 def _term(term, facts):
-    """One term of a sum: a named fact (number or list of numbers), or
-    seconds/calls of the traced programs whose name matches a pattern."""
+    """One term of a sum: a named fact (number or list of numbers); a
+    counter of the status page, differenced over the window (`counter`)
+    or between the trace's marks (`traced_counter`); or seconds/calls of
+    the traced programs (`program_s`/`program_n`) or of the program's
+    bench:<span> annotations (`span_s`/`span_n`) whose name matches a
+    pattern."""
     if "times_fact" in term:
         rest = {k: v for k, v in term.items() if k != "times_fact"}
         a, b = _term(rest, facts), facts.get(term["times_fact"])
@@ -203,16 +242,22 @@ def _term(term, facts):
         if v is None:
             return None
         return float(np.sum(v))
+    for key, marks in (("counter", "window"), ("traced_counter", "traced")):
+        if key in term:
+            return _counter(facts.get("counters", {}).get(marks), term[key])
+    key = next((k for k in _TRACED if k in term), None)
+    if key is None:
+        raise SystemExit(f"run.py: a metric's term {term!r} is none of "
+                         f"fact, counter, traced_counter, {', '.join(_TRACED)}")
     trace = facts.get("trace")
     if trace is None:
         return None
-    key = "program_s" if "program_s" in term else "program_n"
+    table, field = _TRACED[key]
     pat = re.compile(term[key])
-    hits = [rec for name, rec in trace["programs"].items()
-            if pat.search(name)]
+    hits = [rec for name, rec in trace[table].items() if pat.search(name)]
     if not hits:
         return None
-    return float(sum(rec[0 if key == "program_s" else 1] for rec in hits))
+    return float(sum(rec[field] for rec in hits))
 
 
 def reduce_sum(args, facts):
@@ -240,21 +285,39 @@ def reduce_busy_union(args, facts):
     return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
 
 
+def cost_function(name):
+    """A metric's `cost` by name: a function defined in kernel_costs.py
+    (not a module it imports), else the function `name` of
+    costs/<name>.py, the file a PR that brings a kernel brings with it.
+    Same signature, (config, facts)."""
+    import harness
+    import kernel_costs
+
+    fn = getattr(kernel_costs, name, None)
+    if inspect.isfunction(fn) and fn.__module__ == kernel_costs.__name__:
+        return fn
+    path = os.path.join(harness.HERE, "costs", name + ".py")
+    if not os.path.exists(path):
+        raise SystemExit(f"run.py: no cost function {name!r} in "
+                         "benchmark/kernel_costs.py or benchmark/costs/")
+    return getattr(harness.load_module("cost_" + name, path), name)
+
+
 def reduce_roofline_share(args, facts):
     """Least time the chip could take for the calls of a kernel (the
     larger of operations/peak and bytes/bandwidth, from kernel_costs)
     over the device time the trace gives those calls, in percent. The
     bound that decides is kept in facts['bounds'] for the log."""
-    import kernel_costs
-
     secs = _term({"program_s": args["program"]}, facts)
     calls = _term({"program_n": args["program"]}, facts)
     if not secs or not calls:
         return None
-    cost = getattr(kernel_costs, args["cost"])(facts["config"], facts)
+    peaks = facts["peaks"]
+    if peaks is None:               # a rehearsal: no chip, no share
+        return None
+    cost = cost_function(args["cost"])(facts["config"], facts)
     if cost is None:
         return None
-    peaks = facts["peaks"]
     t_ops = cost["ops_per_call"] / peaks[args.get("peak", "flops_fp32")]
     t_bytes = cost["bytes_per_call"] / peaks["hbm_bytes_per_s"]
     facts.setdefault("bounds", {})[args["program"]] = (
@@ -264,12 +327,12 @@ def reduce_roofline_share(args, facts):
 
 def reduce_mfu(args, facts):
     """Operations the algorithm needs over (seconds * chips * peak)."""
-    import kernel_costs
-
     secs = _term(args["seconds"], facts)
     if not secs:
         return None
-    ops = getattr(kernel_costs, args["cost"])(facts["config"], facts)
+    if facts["peaks"] is None:      # a rehearsal: no chip, no share
+        return None
+    ops = cost_function(args["cost"])(facts["config"], facts)
     if ops is None:
         return None
     peak = facts["peaks"][args.get("peak", "flops_bf16")]

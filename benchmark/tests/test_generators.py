@@ -10,7 +10,10 @@ import pytest
 
 import gen_factors
 import gen_ratings
+import harness
 import loadgen
+
+rec_als = harness.load_adapter("rec_als")
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 SHAPE = gen_ratings.scaled_shape(json.load(open(os.path.join(
@@ -71,12 +74,12 @@ def test_factor_blocks_are_reproducible_alone():
 
 
 def test_query_users_are_seeded_skewed_and_in_range():
-    a = loadgen.query_users(2**31 + 1, 20_000, 1000, 1.1)
-    assert (a == loadgen.query_users(2**31 + 1, 20_000, 1000, 1.1)).all()
+    a = rec_als.query_users(2**31 + 1, 20_000, 1000, 1.1)
+    assert (a == rec_als.query_users(2**31 + 1, 20_000, 1000, 1.1)).all()
     assert a.min() >= 0 and a.max() < 1000
     counts = np.sort(np.bincount(a, minlength=1000))[::-1]
     assert counts[0] > 20 * np.median(counts)        # a head
-    assert (loadgen.query_users(3, 2000, 1000, 1.1) != a[:2000]).any()
+    assert (rec_als.query_users(3, 2000, 1000, 1.1) != a[:2000]).any()
 
 
 def test_poisson_schedule_and_lateness():
@@ -87,3 +90,15 @@ def test_poisson_schedule_and_lateness():
     assert (due == loadgen.arrival_times(9, tr, 10.0)).all()
     recs = [(1, 10.0, 10.004, 10.1, []), (2, 11.0, 10.9, 11.2, [])]
     assert loadgen.lateness_ms(recs) == [pytest.approx(4.0), 0.0]
+
+
+def test_the_wire_is_the_adapters_and_tier1s_old_names_still_reach_it():
+    wire = rec_als.Wire(10)
+    assert wire.body(np.int64(7)) == '{"user": "u7", "num": 10}'
+    reply = wire.parse(200, b'{"itemScores": [{"item": "i3", "score": 1}]}')
+    assert reply == [("i3", 1.0)] and not wire.whole(7, reply)
+    assert wire.whole(7, reply * 10) and not wire.whole(7, None)
+    assert wire.parse(503, b"") is None and wire.parse(200, b"{}") is None
+    # tests/test_benchmark_contract.py calls these (loadgen.py says why)
+    assert loadgen._body(7, 10) == wire.body(7)
+    assert loadgen.parse_reply(200, b'{"itemScores": []}') == []

@@ -76,6 +76,112 @@ def test_reducers_leave_out_what_they_cannot_read():
         {"a": {"value": pytest.approx(0.2), "unit": "s"}}
 
 
+def test_span_terms_read_the_programs_annotations():
+    """seconds and count of the bench:<span> annotations that start in
+    the window, whole, as a program's calls are; `window` is no span."""
+    t = hand_trace()
+    t["spans"] += [("flush", 1.0, 0.5), ("flush", 4.0, 1.5),
+                   ("enqueue", 4.1, 0.2), ("flush", 9.9, 0.4),
+                   ("flush", 10.0, 1.0), ("flush", -0.2, 0.5)]
+    s = reduce.summarize_trace(t)
+    assert s["spans"]["flush"] == [pytest.approx(2.4), 3]
+    assert s["spans"]["enqueue"] == [pytest.approx(0.2), 1]
+    assert "window" not in s["spans"] and s["spans"]["job0"][1] == 1
+    facts = {"trace": s}
+    per_flush = {"terms": [{"span_s": "^flush$"}], "scale": 1000.0,
+                 "over": {"span_n": "^flush$"}}
+    assert reduce.reduce_sum(per_flush, facts) == pytest.approx(800.0)
+    outside = {"terms": [{"span_s": "^flush$"}, {"span_s": "enqueue",
+                                                 "sign": -1}]}
+    assert reduce.reduce_sum(outside, facts) == pytest.approx(2.2)
+    assert reduce.reduce_sum({"terms": [{"span_s": "absent"}]}, facts) is None
+    assert reduce.reduce_sum(per_flush, {"trace": None}) is None
+    with pytest.raises(SystemExit):
+        reduce.reduce_sum({"terms": [{"spam_s": "flush"}]}, facts)
+
+
+def test_a_capture_with_no_device_plane_keeps_its_spans():
+    """A rehearsal's capture on the CPU: the spans are read, and the
+    busy time is 0, which a cell refuses from a chip."""
+    t = {"planes": {}, "spans": [("window", 2.0, 4.0), ("flush", 3.0, 0.5)]}
+    s = reduce.summarize_trace(t, unnamed_gap="between_flushes")
+    assert (s["window_s"], s["busy_s"], s["devices"]) == (4.0, 0.0, 0)
+    assert s["spans"] == {"flush": [0.5, 1]} and s["programs"] == {}
+    assert reduce.summarize_trace({"planes": {}, "spans": []}) is None
+    assert reduce.summarize_trace(
+        {"planes": {}, "spans": [("flush", 3.0, 0.5)]}) is None
+
+
+def test_counter_terms_difference_the_status_page():
+    """`counter`: a dotted path into `GET /`, last reading of the window
+    less the first; `traced_counter`: between the trace's marks."""
+    def page(planned, queries, hist):
+        return {"codec": {"plans": 3, "requests": {"planned": planned,
+                                                   "reflected": 0}},
+                "batching": {"enabled": True, "queries": queries,
+                             "bucketHist": hist, "layout": "replicated"}}
+
+    facts = {"counters": {
+        "window": [page(100, 90, {"64": 2}), page(1100, 1090, {"64": 22})],
+        "traced": [page(300, 290, {"64": 6}), page(500, 490, {"64": 10})]}}
+    term = reduce._term
+    assert term({"counter": "codec.requests.planned"}, facts) == 1000.0
+    assert term({"traced_counter": "codec.requests.planned"}, facts) == 200.0
+    assert term({"counter": "batching.bucketHist.64"}, facts) == 20.0
+    assert term({"counter": "codec.requests.reflected"}, facts) == 0.0
+    share = {"terms": [{"counter": "codec.requests.planned"}],
+             "over": {"counter": "batching.queries"}}
+    assert reduce.reduce_sum(share, facts) == pytest.approx(1.0)
+    # nothing to read: no such path, not a number, a flag, no marks
+    for path in ("codec.requests.absent", "batching.layout",
+                 "batching.enabled", "batching", "codec.plans.deeper"):
+        assert term({"counter": path}, facts) is None, path
+    assert term({"traced_counter": "codec.plans"},
+                {"counters": {"window": facts["counters"]["window"]}}) is None
+    assert term({"counter": "codec.plans"}, {}) is None
+
+
+def test_a_cost_function_is_found_in_kernel_costs_first_then_by_file():
+    import kernel_costs
+
+    assert reduce.cost_function("topk_flush") is kernel_costs.topk_flush
+    # tests/test_cells.py has one found under costs/ in a copy that
+    # brings the file; here there is none of that name
+    with pytest.raises(SystemExit):
+        reduce.cost_function("no_such_kernel")
+    facts = {"trace": reduce.summarize_trace(hand_trace()),
+             "peaks": {"flops_bf16": 1.0}, "chips": 1, "config": {}}
+    with pytest.raises(SystemExit):
+        reduce.reduce_mfu({"seconds": {"program_s": "train"},
+                           "cost": "no_such_kernel"}, facts)
+    # only a function kernel_costs.py defines is found there: a private
+    # helper is one, a name it merely holds (a module, a constant a
+    # later edit imports) would shadow costs/<name>.py and is passed by
+    kernel_costs.shadow = math
+    kernel_costs.borrowed = math.sqrt
+    try:
+        for name in ("shadow", "borrowed", "__doc__"):
+            with pytest.raises(SystemExit):
+                reduce.cost_function(name)
+    finally:
+        del kernel_costs.shadow, kernel_costs.borrowed
+
+
+def test_a_share_of_a_peak_wants_the_peaks_or_a_rehearsals_word():
+    """A run on a chip that forgot the table of peaks is an error, as it
+    was; a rehearsal says `peaks` None and the share is left out."""
+    facts = {"trace": reduce.summarize_trace(hand_trace()), "chips": 1,
+             "config": {"data": {"n_users": 100, "n_items": 50, "nnz": 1000},
+                        "engine_params": {"rank": 2, "numIterations": 10}}}
+    roof = {"program": "train", "cost": "als_program", "peak": "flops_fp32"}
+    mfu = {"seconds": {"program_s": "train"}, "cost": "als_program"}
+    for reducer, args in ((reduce.reduce_roofline_share, roof),
+                          (reduce.reduce_mfu, mfu)):
+        with pytest.raises(KeyError):
+            reducer(args, facts)
+        assert reducer(args, {**facts, "peaks": None}) is None
+
+
 def test_roofline_share_and_mfu_from_costs():
     s = reduce.summarize_trace(hand_trace())
     config = {"data": {"n_users": 100, "n_items": 50, "nnz": 1000},

@@ -172,14 +172,37 @@ def test_flush_host_ms_facts_are_what_a_rehearsal_offers():
     assert window["batches"] >= 1 and window["flush_s"] > 0
 
 
+#: `per_layer` of BENCHMARK.json as accepted up to PR 33, in its order
+ACCEPTED = [
+    "deploy.ready_s",
+    "batcher.flush_size.rate",
+    "batcher.queue_wait_ms.rate",
+    "batcher.flush_size.p95",
+    "batcher.queue_wait_ms.p95",
+    "topk.flush_device_ms.rate",
+    "topk_flush_roofline",
+    "topk.flush_device_ms.p95",
+    "serve.flush_mfu.rate",
+    "device.idle.rate",
+    "device.idle.p95",
+    "compile.in_window.rate",
+    "compile.in_window.p95",
+    "loadgen.late_ms.p95",
+    "loadgen.offered_rate.p95",
+    "batcher.flush_host_ms.rate",
+    "batcher.flush_host_ms.p95",
+    "sharded.flush_device_ms.rate"]
+
+
 def test_benchmark_json_only_gained_entries():
-    """Each accepted per-layer entry is where it was; the new ones are at
-    the end, one per serving cell."""
+    """Each accepted per-layer entry is where it was; what a later PR
+    brings comes after them (the two of this file's PR, 27, came after
+    `loadgen.offered_rate.p95`; PR 29's one after those)."""
     bj = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     names = [m["name"] for m in bj["per_layer"]]
-    assert names[-2:] == ["batcher.flush_host_ms.rate",
-                          "batcher.flush_host_ms.p95"]
-    assert names.index("loadgen.offered_rate.p95") == len(names) - 3
-    for n in NEW:
+    assert names[:len(ACCEPTED)] == ACCEPTED
+    at = ACCEPTED.index("loadgen.offered_rate.p95")
+    assert ACCEPTED[at + 1:at + 3] == sorted(NEW, reverse=True)
+    for n in names:
         assert os.path.exists(os.path.join(ROOT, "benchmark", "metrics",
-                                           n + ".json"))
+                                           n + ".json")), n
