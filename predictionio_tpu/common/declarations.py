@@ -484,6 +484,28 @@ METRICS: Dict[str, str] = {
         "replies checked for NaN/Inf by kind: folded (in the pass that "
         "builds the JSON value) / walked (again, after feedback or an "
         "output blocker)",
+    "pio_ecomm_queries_total":
+        "e-commerce queries answered, by any layout "
+        "(models/ecommerce/als_algorithm.py)",
+    "pio_ecomm_excluded_items_total":
+        "item indices (black list + seen items) handed to the device "
+        "program as exclusions",
+    "pio_ecomm_seen_reads_total":
+        "live seen-items reads of the event store (one a query with "
+        "unseenOnly)",
+    "pio_ecomm_constraint_reads_total":
+        "live reads of constraint/unavailableItems: one a flush on the "
+        "device layout, one a query on the host layout",
+    "pio_ecomm_constraint_uploads_total":
+        "eligibility arrays placed on the device: one a deploy, one each "
+        "time the constraint's last $set changed",
+    "pio_ecomm_host_fallbacks_total":
+        "queries answered by the host kernels while a device layout is "
+        "deployed (whiteList, unknown user, exclusion list past the "
+        "largest declared width)",
+    "pio_ecomm_exclude_width_flushes_total":
+        "device flushes by the declared exclusion width they were "
+        "padded to",
     # ----------------------------------------------------------------- AOT
     "pio_aot_programs_total": "AOT program builds by status",
     "pio_aot_prebuild_seconds": "AOT prebuild wall time",

@@ -31,18 +31,26 @@ class MemoryEvents(base.Events):
         #: _store but never rewrite the log, so integer cursors stay
         #: stable (the in-memory analogue of eventlog's (seq, row)).
         self._log: Dict[_ChannelKey, List[Event]] = {}
+        #: (entity_type, entity_id) -> the ids of its events in _store,
+        #: in _store's order (a dict as an ordered set): a read by
+        #: entity — the serving-time lookups of the e-commerce template,
+        #: two a query — touches that entity's events, not the app's
+        self._by_entity: Dict[_ChannelKey,
+                              Dict[Tuple[str, str], Dict[str, None]]] = {}
         self._lock = threading.RLock()
 
     def init(self, app_id: int, channel_id: Optional[int] = None) -> bool:
         with self._lock:
             self._store.setdefault((app_id, channel_id), {})
             self._log.setdefault((app_id, channel_id), [])
+            self._by_entity.setdefault((app_id, channel_id), {})
         return True
 
     def remove(self, app_id: int, channel_id: Optional[int] = None) -> bool:
         with self._lock:
             self._store.pop((app_id, channel_id), None)
             self._log.pop((app_id, channel_id), None)
+            self._by_entity.pop((app_id, channel_id), None)
         return True
 
     def close(self) -> None:
@@ -53,10 +61,43 @@ class MemoryEvents(base.Events):
         event_id = event.event_id or uuid.uuid4().hex
         with self._lock:
             table = self._store.setdefault((app_id, channel_id), {})
+            index = self._by_entity.setdefault((app_id, channel_id), {})
             stamped = event.with_event_id(event_id)
+            old = table.get(event_id)
             table[event_id] = stamped
+            key = (stamped.entity_type, stamped.entity_id)
+            if old is None or (old.entity_type, old.entity_id) == key:
+                index.setdefault(key, {})[event_id] = None
+            else:
+                # an id written again under another entity keeps its
+                # place in _store: both entities' ids are read off it
+                self._unindex(index, old)
+                index[key] = {i: None for i, e in table.items()
+                              if (e.entity_type, e.entity_id) == key}
             self._log.setdefault((app_id, channel_id), []).append(stamped)
         return event_id
+
+    @staticmethod
+    def _unindex(index, event: Event) -> None:
+        key = (event.entity_type, event.entity_id)
+        ids = index.get(key)
+        if ids is not None:
+            ids.pop(event.event_id, None)
+            if not ids:
+                del index[key]
+
+    def _candidates(self, app_id: int, channel_id: Optional[int],
+                    entity_type: Optional[str],
+                    entity_id: Optional[str]) -> List[Event]:
+        """The events a filter can match, in _store's order: one
+        entity's where both halves of its key are given, else all."""
+        with self._lock:
+            table = self._store.get((app_id, channel_id), {})
+            if entity_type is None or entity_id is None:
+                return list(table.values())
+            ids = self._by_entity.get((app_id, channel_id), {}).get(
+                (entity_type, entity_id), ())
+            return [table[i] for i in ids]
 
     # -- incremental cursor read (realtime fold-in tail; the in-memory
     # twin of eventlog.read_columns_since, object-shaped because this
@@ -96,7 +137,11 @@ class MemoryEvents(base.Events):
                channel_id: Optional[int] = None) -> bool:
         with self._lock:
             table = self._store.get((app_id, channel_id), {})
-            return table.pop(event_id, None) is not None
+            event = table.pop(event_id, None)
+            if event is not None:
+                self._unindex(self._by_entity.get((app_id, channel_id), {}),
+                              event)
+            return event is not None
 
     def find(
         self,
@@ -112,10 +157,9 @@ class MemoryEvents(base.Events):
         limit: Optional[int] = None,
         reversed_: bool = False,
     ) -> Iterator[Event]:
-        with self._lock:
-            events = list(self._store.get((app_id, channel_id), {}).values())
         events = [
-            e for e in events
+            e for e in self._candidates(app_id, channel_id, entity_type,
+                                        entity_id)
             if event_matches(
                 e, start_time, until_time, entity_type, entity_id,
                 event_names, target_entity_type, target_entity_id)
@@ -124,6 +168,24 @@ class MemoryEvents(base.Events):
         if limit is not None and limit >= 0:
             events = events[:limit]
         return iter(events)
+
+    def find_target_ids(self, app_id: int,
+                        channel_id: Optional[int] = None,
+                        entity_type: Optional[str] = None,
+                        entity_id: Optional[str] = None,
+                        event_names: Optional[Sequence[str]] = None,
+                        target_entity_type: Optional[str] = None,
+                        ) -> List[str]:
+        """Target ids of the matching events, as eventlog's fast path
+        gives them (data/store.py find_target_ids): unsorted, no copy of
+        an event made."""
+        return [e.target_entity_id
+                for e in self._candidates(app_id, channel_id, entity_type,
+                                          entity_id)
+                if e.target_entity_id is not None and event_matches(
+                    e, entity_type=entity_type, entity_id=entity_id,
+                    event_names=event_names,
+                    target_entity_type=target_entity_type)]
 
 
 class MemoryApps(base.Apps):

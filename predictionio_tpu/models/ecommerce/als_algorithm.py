@@ -5,20 +5,35 @@ main/scala/ALSAlgorithm.scala — train :49-131 (rate events, latest value
 per (user, item) wins, ALS.train); predict :133-260 (seen-events and
 unavailable-items constraints read LIVE from the event store per query,
 known users score by U[u] . V, unknown users by similarity to their
-recent views). The device-side scoring is one masked matvec + top-k;
-only the business-rule lookups touch the host event store.
+recent views).
+
+Serving layouts (``prepare_serving``): on an accelerator the factors,
+one word array of category bits an item and one eligibility array
+(trained AND NOT on the constraint's unavailableItems) live on the
+device, and a flush is ONE dispatch of ops/topk.py masked_topk_rows:
+what a query's rules add to it is a row of category bits and a short
+list of excluded item indices (black list + seen items), never a mask
+as long as the catalog. The rule reads stay LIVE: one seen-items read a
+query, one constraint read a flush. A query the device program has no
+argument for (whiteList, a user the model does not know, an exclusion
+list past ops/topk.py EXCLUDE_WIDTHS) is answered by the host layout's
+code and counted (``hostFallbacks``). On the CPU backend a tiny model
+keeps the host layout: one BLAS product + argpartition.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import logging
+import math
+import threading
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Any, Dict, List, Optional, Set, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
-from predictionio_tpu.common import resilience
+from predictionio_tpu.common import resilience, telemetry, waterfall
 from predictionio_tpu.controller import Algorithm, Params
 from predictionio_tpu.data import store
 from predictionio_tpu.data.bimap import BiMap
@@ -27,8 +42,86 @@ from predictionio_tpu.models.ecommerce.engine import (
     Item, ItemScore, PredictedResult, Query,
 )
 from predictionio_tpu.ops import als, topk
+from predictionio_tpu.serving.protocol import (
+    bucket_for, device_rows, host_serves_faster,
+)
 
 logger = logging.getLogger("predictionio_tpu.ecommerce")
+
+_REG = telemetry.registry()
+_M_QUERIES = _REG.counter(
+    "pio_ecomm_queries_total",
+    "E-commerce queries answered, by any layout").child()
+_M_EXCLUDED = _REG.counter(
+    "pio_ecomm_excluded_items_total",
+    "Item indices (black list + seen items) handed to the device "
+    "program as exclusions").child()
+_M_SEEN_READS = _REG.counter(
+    "pio_ecomm_seen_reads_total",
+    "Live seen-items reads of the event store (one a query with "
+    "unseenOnly)").child()
+_M_CONSTRAINT_READS = _REG.counter(
+    "pio_ecomm_constraint_reads_total",
+    "Live reads of constraint/unavailableItems (one a flush on the "
+    "device layout, one a query on the host layout)").child()
+_M_CONSTRAINT_UPLOADS = _REG.counter(
+    "pio_ecomm_constraint_uploads_total",
+    "Eligibility arrays placed on the device: one a deploy and one "
+    "each time the constraint's last $set changed").child()
+_M_HOST_FALLBACKS = _REG.counter(
+    "pio_ecomm_host_fallbacks_total",
+    "Queries answered by the host kernels while a device layout is "
+    "deployed (whiteList, unknown user, exclusion list past the "
+    "largest declared width)").child()
+_M_WIDTH_FLUSHES = _REG.counter(
+    "pio_ecomm_exclude_width_flushes_total",
+    "Device flushes by the declared exclusion width they were padded "
+    "to", labelnames=("width",))
+
+
+def stats() -> Dict[str, Any]:
+    """`GET /`'s ``ecomm`` block: the process-wide counters /metrics
+    has as ``pio_ecomm_*``, and the widths the device program is
+    compiled for."""
+    return {
+        "queries": int(_M_QUERIES.value),
+        "excludedItems": int(_M_EXCLUDED.value),
+        "seenReads": int(_M_SEEN_READS.value),
+        "constraintReads": int(_M_CONSTRAINT_READS.value),
+        "constraintUploads": int(_M_CONSTRAINT_UPLOADS.value),
+        "hostFallbacks": int(_M_HOST_FALLBACKS.value),
+        "excludeWidths": {
+            str(w): int(_M_WIDTH_FLUSHES.labels(width=str(w)).value)
+            for w in topk.EXCLUDE_WIDTHS},
+    }
+
+
+def category_words(items: Dict[int, Item], n_items: int,
+                   category_masks: Optional[Dict[str, np.ndarray]] = None
+                   ) -> Tuple[Dict[str, Tuple[int, "np.uint32"]],
+                              np.ndarray]:
+    """Every item's categories as bits, for ops/topk.py
+    masked_topk_rows: -> (category -> (word, bit mask), (w, n_items)
+    uint32). Bit 0 of word 0 is set on every item (topk.RULE_ANY_BIT:
+    what a query with no categories asks for, so that an item with no
+    category still answers it); category j, in name order, is bit
+    j + 1. ``category_masks`` (similarproduct build_category_masks of
+    the same items) is used where the model carries it."""
+    from predictionio_tpu.models.similarproduct.als_algorithm import (
+        build_category_masks,
+    )
+    if category_masks is None:
+        category_masks = build_category_masks(items, n_items)
+    names = sorted(category_masks)
+    words = np.zeros((-(-(len(names) + 1) // 32), n_items), np.uint32)
+    words[0] = topk.RULE_ANY_BIT
+    bits: Dict[str, Tuple[int, np.uint32]] = {}
+    for j, name in enumerate(names):
+        word, bit = divmod(j + 1, 32)
+        bits[name] = (word, np.uint32(1 << bit))
+        words[word] |= np.where(np.asarray(category_masks[name]),
+                                bits[name][1], np.uint32(0))
+    return bits, words
 
 
 @dataclass(frozen=True)
@@ -73,6 +166,93 @@ class ECommModel:
     item_trained: "np.ndarray"      # (n_items,) bool
     category_masks: Dict[str, "np.ndarray"] = None
     product_features_hat: "np.ndarray" = None   # L2-normalized rows
+    #: serve-time-only state (RuleDevice) when prepare_serving chose the
+    #: device layout; never persisted (train's output has None, and a
+    #: pickle from before the field lacks it: read with getattr)
+    device: Optional["RuleDevice"] = None
+
+    def serving_layout(self) -> Dict[str, Any]:
+        """`GET /` ``batching``: where the arrays that answer the
+        flushes live."""
+        dev = getattr(self, "device", None)
+        if dev is None:
+            return {"layout": "host", "shards": 0, "perShardBytes": 0}
+        return {"layout": "replicated+rules", "shards": 1,
+                "perShardBytes": dev.nbytes(),
+                "excludeWidths": list(topk.EXCLUDE_WIDTHS)}
+
+    def topk_rows(self) -> Optional[int]:
+        """The score row the deployed device programs select from."""
+        dev = getattr(self, "device", None)
+        return None if dev is None else int(dev.item_factors.shape[0])
+
+    def hbm_bytes(self) -> int:
+        """serving/registry.py model_hbm_bytes: what the device holds."""
+        dev = getattr(self, "device", None)
+        return 0 if dev is None else dev.nbytes()
+
+    def status_block(self) -> Tuple[str, Dict[str, Any]]:
+        return "ecomm", stats()
+
+
+@dataclass
+class RuleDevice:
+    """What the device holds of a deployed ECommModel: both factor
+    matrices, the items' category bits, and the eligibility array of the
+    constraint as last read (``constraint_key`` says which $set)."""
+    user_factors: Any            # (n_users, r) float32, device
+    item_factors: Any            # (n_items, r) float32, device
+    rule_words: Any              # (w, n_items) uint32, device
+    category_bits: Dict[str, Tuple[int, "np.uint32"]]
+    eligible: Any                # (n_items,) bool, device
+    constraint_key: Any = None
+    lock: Any = dataclasses.field(default_factory=threading.Lock)
+
+    def nbytes(self) -> int:
+        return int(sum(a.nbytes for a in (
+            self.user_factors, self.item_factors, self.rule_words,
+            self.eligible)))
+
+    def topk(self, eligible, want, exclude):
+        """device_rows' ``topk_fn`` for one flush's rule arguments."""
+        return lambda pix, k: topk.masked_topk_rows(
+            self.user_factors, self.item_factors, self.rule_words,
+            eligible, pix, want, exclude, k=k)
+
+
+def _place_eligible(model: ECommModel, unavailable):
+    """(n_items,) bool on the device: trained AND NOT unavailable."""
+    import jax
+
+    eligible = np.array(model.item_trained, dtype=bool)
+    gone = [model.item_vocab.get(x) for x in unavailable]
+    eligible[[ix for ix in gone if ix is not None]] = False
+    _M_CONSTRAINT_UPLOADS.inc()
+    return jax.device_put(eligible)
+
+
+def _place(model: ECommModel) -> RuleDevice:
+    """The device layout of ``model``. The eligibility array starts
+    from ``item_trained`` alone; the first flush reads the constraint."""
+    import jax
+
+    n_items = len(model.item_vocab)
+    bits, words = category_words(model.items, n_items,
+                                 model.category_masks)
+    return RuleDevice(
+        user_factors=jax.device_put(
+            np.asarray(model.user_features, np.float32)),
+        item_factors=jax.device_put(
+            np.asarray(model.product_features, np.float32)),
+        rule_words=jax.device_put(words), category_bits=bits,
+        eligible=_place_eligible(model, ()))
+
+
+def _rule_arguments(dev: RuleDevice, bucket: int, longest: int):
+    """topk.blank_rule_arguments at this layout's shapes."""
+    return topk.blank_rule_arguments(
+        bucket, int(dev.rule_words.shape[0]), longest,
+        int(dev.item_factors.shape[0]))
 
 
 class ECommAlgorithm(Algorithm):
@@ -155,6 +335,7 @@ class ECommAlgorithm(Algorithm):
         columnar target-id fast path (no Event materialization)."""
         if not self.ap.unseenOnly:
             return set()
+        _M_SEEN_READS.inc()
         try:
             return set(store.find_target_ids(
                 app_name=self.ap.appName, entity_type="user",
@@ -167,21 +348,50 @@ class ECommAlgorithm(Algorithm):
             resilience.note_degraded(f"seen-events lookup failed: {e}")
             return set()
 
+    def _constraint_event(self):
+        """Latest $set on constraint/unavailableItems, or None
+        (:178-200): a point read. Raises what the store raises."""
+        _M_CONSTRAINT_READS.inc()
+        events = store.find_by_entity(
+            app_name=self.ap.appName, entity_type="constraint",
+            entity_id="unavailableItems", event_names=["$set"],
+            limit=1, latest=True, storage=self._storage)
+        return events[0] if events else None
+
     def _unavailable_items(self) -> Set[str]:
-        """Latest $set on constraint/unavailableItems (:178-200)."""
+        """The host layout's read, one a query."""
         try:
-            events = store.find_by_entity(
-                app_name=self.ap.appName, entity_type="constraint",
-                entity_id="unavailableItems", event_names=["$set"],
-                limit=1, latest=True, storage=self._storage)
+            event = self._constraint_event()
         except Exception as e:
             logger.error("Error when read set unavailableItems event: %s", e)
             resilience.note_degraded(
                 f"unavailableItems lookup failed: {e}")
             return set()
-        if not events:
+        if event is None:
             return set()
-        return set(events[0].properties.get_opt("items") or ())
+        return set(event.properties.get_opt("items") or ())
+
+    def _eligible(self, model: "ECommModel", dev: "RuleDevice"):
+        """The device layout's read, one a flush: the eligibility array
+        of the constraint as it stands now. Placed on the device again
+        only when its last $set is another event than the one the
+        resident array was made from; a read that fails keeps the
+        resident array and flags the flush degraded."""
+        try:
+            event = self._constraint_event()
+        except Exception as e:
+            logger.error("Error when read set unavailableItems event: %s", e)
+            resilience.note_degraded(
+                f"unavailableItems lookup failed: {e}")
+            return dev.eligible
+        key = None if event is None else (event.event_id, event.event_time)
+        with dev.lock:
+            if key != dev.constraint_key:
+                names = () if event is None else (
+                    event.properties.get_opt("items") or ())
+                dev.eligible = _place_eligible(model, names)
+                dev.constraint_key = key
+            return dev.eligible
 
     def _item_weights(self, model: "ECommModel") -> Optional[np.ndarray]:
         """Latest $set on constraint/weightedItems → per-item score
@@ -220,7 +430,134 @@ class ECommAlgorithm(Algorithm):
                 logger.error("Malformed WeightsGroup %r ignored: %s", g, e)
         return w
 
+    # ------------------------------------------------------ serving layout
+    def prepare_serving(self, model: ECommModel) -> ECommModel:
+        """On an accelerator: the device layout, always (module
+        docstring), and a layout that fails RAISES, as the
+        recommendation engine's does: a deploy that quietly served some
+        other way would pass every check without its layout ever having
+        reached the chip. On the CPU backend, where a tiny model serves
+        faster from host BLAS than through a dispatch, a real bucket-1
+        query is timed and the host layout kept when it is slow
+        (serving/protocol.py host_serves_faster: the recommendation
+        engine's rule). With ``weightedItems`` the host layout stays: the device
+        program takes no weights."""
+        import jax
+
+        if self.ap.weightedItems:
+            logger.info("weightedItems is on: serving from host arrays")
+            return dataclasses.replace(model, device=None)
+        on_chip = jax.default_backend() != "cpu"
+        try:
+            dev = _place(model)
+            if not on_chip:
+                run = dev.topk(dev.eligible, *_rule_arguments(dev, 1, 0))
+                ix, k = np.zeros(1, np.int32), min(10, len(model.item_vocab))
+                if host_serves_faster(lambda: run(ix, k), logger):
+                    dev = None
+        except Exception:
+            if on_chip:
+                raise
+            logger.exception("device serving layout failed; serving from "
+                             "host arrays")
+            dev = None
+        return dataclasses.replace(model, device=dev)
+
+    def aot_serving_programs(self, model: ECommModel, buckets,
+                             declared: bool = False):
+        """This model's device programs from declared shapes
+        (serving/aot.py): masked_topk_rows per (bucket, exclusion
+        width, k), bucket 1 always among them for ``predict``. Nothing
+        on the host layout; ``declared=True`` (the `pio train`
+        cache-artifact export) enumerates regardless."""
+        from predictionio_tpu.serving import aot
+
+        dev = getattr(model, "device", None)
+        if dev is None and not declared:
+            return ()
+        n_users, rank = (int(d) for d in np.shape(model.user_features))
+        n_items = int(np.shape(model.product_features)[0])
+        n_words = -(-(len(model.category_masks or {}) + 1) // 32) \
+            if dev is None else int(dev.rule_words.shape[0])
+        return aot.specs_masked_topk_rows(
+            n_users, n_items, rank, n_words,
+            sorted({1, *buckets}), aot.serving_ks(n_items), device=dev)
+
     # ------------------------------------------------------------- serving
+    def _predict_batch_device(self, model: ECommModel, dev: "RuleDevice",
+                              queries) -> List[PredictedResult]:
+        """One flush on the device layout. `rules` (host): one
+        seen-items read a query, ONE constraint read (every query of
+        the flush sees a snapshot taken after it arrived), and the rule
+        arguments: a row of wanted-category bits and a row of excluded
+        item indices a query, padded to the flush's bucket and to a
+        declared width. Then the shared device half (`pad`, `execute` >
+        `enqueue`, `device_get`) and `unpack`."""
+        out: List[Optional[PredictedResult]] = [None] * len(queries)
+        n_items = len(model.item_vocab)
+        item_ix = model.item_vocab.get
+        host: List[int] = []
+        rows: List[Tuple[int, Query, int, set]] = []
+        with waterfall.stage("rules"):
+            with waterfall.stage("rules.seen"):
+                for qx, query in enumerate(queries):
+                    if min(query.num, n_items) <= 0:
+                        out[qx] = PredictedResult(())
+                        continue
+                    user_ix = model.user_vocab.get(query.user)
+                    if query.whiteList is not None or user_ix is None \
+                            or not model.user_trained[user_ix]:
+                        host.append(qx)
+                        continue
+                    gone = {item_ix(x) for x in query.blackList or ()}
+                    gone.update(item_ix(x)
+                                for x in self._seen_items(query.user))
+                    gone.discard(None)
+                    if len(gone) > topk.EXCLUDE_WIDTHS[-1]:
+                        host.append(qx)
+                    else:
+                        rows.append((qx, query, user_ix, gone))
+            if rows:
+                with waterfall.stage("rules.constraint"):
+                    eligible = self._eligible(model, dev)
+                want, exclude = _rule_arguments(
+                    dev, bucket_for(len(rows)),
+                    max(len(gone) for *_, gone in rows))
+                for r, (_qx, query, _ix, gone) in enumerate(rows):
+                    if query.categories is not None:
+                        want[r] = 0
+                        for name in query.categories:
+                            word, bit = dev.category_bits.get(name, (0, 0))
+                            want[r, word] |= bit
+                    exclude[r, :len(gone)] = list(gone)
+                _M_EXCLUDED.inc(sum(len(gone) for *_, gone in rows))
+                _M_WIDTH_FLUSHES.labels(
+                    width=str(exclude.shape[1])).inc()
+        if rows:
+            k = min(max(q.num for _qx, q, _ix, _g in rows), n_items)
+            vals, idx = device_rows(
+                dev.topk(eligible, want, exclude),
+                np.asarray([ix for _qx, _q, ix, _g in rows], np.int32), k)
+            waterfall.note("rules", int(exclude.shape[1]))
+            with waterfall.stage("unpack"):
+                inv = model.item_vocab.inverse()
+                for (qx, query, _ix, _g), rvals, ridx in zip(
+                        rows, vals.tolist(), idx.tolist()):
+                    n = min(query.num, k)
+                    # _rows_to_result's rule: scores <= 0 (NEG_INF: no
+                    # candidate left) and non-finite ones are dropped
+                    out[qx] = PredictedResult(tuple(
+                        ItemScore(item=inv(i), score=s)
+                        for s, i in zip(rvals[:n], ridx[:n])
+                        if 0 < s < math.inf))
+        if host:
+            _M_HOST_FALLBACKS.inc(len(host))
+            answers = self._predict_batch_host(
+                model, [queries[qx] for qx in host])
+            for qx, res in zip(host, answers):
+                out[qx] = res
+        return out
+
     def _query_plan(self, model: ECommModel, query: Query):
         """Per-query business-rule prep shared by predict and
         predict_batch — the LIVE event-store lookups (seen events,
@@ -270,7 +607,11 @@ class ECommAlgorithm(Algorithm):
     def predict(self, model: ECommModel, query: Query) -> PredictedResult:
         """Known users score U[u] . V; unknown users fall back to
         similarity with their recent views — both as one masked device
-        top-K (:202-260)."""
+        top-K (:202-260). On the device layout: a flush of one, on the
+        bucket-1 program."""
+        if getattr(model, "device", None) is not None:
+            return self.predict_batch(model, [query])[0]
+        _M_QUERIES.inc()
         plan = self._query_plan(model, query)
         if plan is None:
             return PredictedResult(())
@@ -291,7 +632,17 @@ class ECommAlgorithm(Algorithm):
 
     def predict_batch(self, model: ECommModel,
                       queries) -> List[PredictedResult]:
-        """Serving micro-batch: per-query business rules stay live (one
+        """Serving micro-batch, by the layout prepare_serving chose."""
+        queries = list(queries)
+        _M_QUERIES.inc(len(queries))
+        dev = getattr(model, "device", None)
+        if dev is not None:
+            return self._predict_batch_device(model, dev, queries)
+        return self._predict_batch_host(model, queries)
+
+    def _predict_batch_host(self, model: ECommModel,
+                            queries) -> List[PredictedResult]:
+        """The host layout: per-query business rules stay live (one
         event-store lookup chain per query, as in predict), but the
         scoring matvecs coalesce into one (B, rank) @ (rank, n_items)
         matmul per factor side (known users score against raw factors,
@@ -299,7 +650,6 @@ class ECommAlgorithm(Algorithm):
         ONE constraint snapshot per batch rather than per query — within
         a flush every query sees the same weights, which is also the
         stronger consistency story."""
-        queries = list(queries)
         out: List[Optional[PredictedResult]] = [None] * len(queries)
         weights = self._item_weights(model) if self.ap.weightedItems \
             else None

@@ -21,6 +21,9 @@ from predictionio_tpu.models.recommendation.engine import (
 )
 from predictionio_tpu.models.recommendation.preparator import PreparedData
 from predictionio_tpu.ops import als, topk
+from predictionio_tpu.serving.protocol import (
+    device_rows, host_serves_faster,
+)
 
 
 @dataclass(frozen=True)
@@ -270,37 +273,6 @@ def _ensure_layout(ctx, td, use_mesh: bool):
     return data
 
 
-def _device_rows(topk_fn, ixs, k: int):
-    """The device half of ``predict_batch``, the same for the replicated,
-    quantized and sharded layouts: pad the batch's user indices up to a
-    serving bucket (index 0 is in-bounds — KNOWN_ISSUES #5), make the ONE
-    dispatch ``topk_fn(padded_ixs, k)``, fetch, and hand back the real
-    rows of the ``(bucket, k)`` values and indices, still arrays: the
-    `unpack` stage turns them into Python numbers, once a flush.
-    Waterfall stages, drill-downs inside
-    `dispatch` (and, on the batcher's worker, host spans in a profiler
-    capture): `pad`; `execute` round `enqueue` (the call that returns the
-    device arrays: argument transfer and launch) and `device_get`
-    (blocked until the device is done, plus the copy back — the host
-    transfer IS the clock stop, KNOWN_ISSUES #3, so the stage is honest
-    on every backend)."""
-    import jax
-
-    from predictionio_tpu.common import waterfall
-    from predictionio_tpu.serving.protocol import bucket_for
-
-    with waterfall.stage("pad"):
-        bucket = bucket_for(len(ixs))
-        pix = np.zeros(bucket, dtype=np.int32)
-        pix[:len(ixs)] = ixs
-    with waterfall.stage("execute"):
-        with waterfall.stage("enqueue"):
-            on_device = topk_fn(pix, k)
-        with waterfall.stage("device_get"):
-            vals, idx = jax.device_get(on_device)
-    return vals[:len(ixs)], idx[:len(ixs)]
-
-
 class ALSAlgorithm(Algorithm):
     params_class = ALSAlgorithmParams
     query_class = Query
@@ -409,8 +381,6 @@ class ALSAlgorithm(Algorithm):
         3 ms). No reference analogue — MLlib serving is always
         JVM-host-side."""
         import logging
-        import os
-        import time
 
         import jax
 
@@ -500,21 +470,9 @@ class ALSAlgorithm(Algorithm):
             # tiny model there, so time a real query and move serving
             # to host numpy when a dispatch is slow. On an attached
             # accelerator the factors stay on the device, always.
-            try:
-                k = min(10, len(model.item_vocab))
-                ix = np.int32(0)
-                # warm the compile, then time the steady state
-                jax.device_get(topk.topk_for_user(U, V, ix, k=k))
-                t0 = time.perf_counter()
-                for _ in range(3):
-                    jax.device_get(topk.topk_for_user(U, V, ix, k=k))
-                per_query_ms = (time.perf_counter() - t0) / 3 * 1e3
-            except Exception:
-                per_query_ms = float("inf")
-            threshold = float(os.environ.get("PIO_SERVE_DEVICE_MS", "3.0"))
-            if per_query_ms > threshold:
-                log.info("device round-trip %.2fms > %.1fms; serving "
-                         "from host arrays", per_query_ms, threshold)
+            k = min(10, len(model.item_vocab))
+            if host_serves_faster(
+                    lambda: topk.topk_for_user(U, V, np.int32(0), k=k), log):
                 return ALSModel(
                     rank=model.rank,
                     user_factors=np.asarray(model.user_factors),
@@ -660,14 +618,14 @@ class ALSAlgorithm(Algorithm):
             # shard + the all-gather merge. The shards note turns
             # "execute is slow" into "it's the n-way sharded program",
             # one hop from /debug/slow.json.
-            fetched = _device_rows(sharding.topk, ixs, k)
+            fetched = device_rows(sharding.topk, ixs, k)
             waterfall.note("shards", sharding.n_shards)
         elif quant is not None:
             # quantized device path (ops/quant.py): ONE dequantize-free
             # dispatch — int8 x int8 scores + fused rescale + top-k. The
             # quant note turns "execute is slow" into "it's the int8
             # path", one hop from /debug/slow.json.
-            fetched = _device_rows(quant.topk, ixs, k)
+            fetched = device_rows(quant.topk, ixs, k)
             waterfall.note("quant", "int8")
         elif isinstance(model.user_factors, np.ndarray):
             # host: one BLAS gemm for the batch, per-row argpartition with
@@ -678,7 +636,7 @@ class ALSAlgorithm(Algorithm):
                               topk.host_topk(scores[r], min(q.num, k)))
                         for r, (_qx, q, _ix) in enumerate(valid)]
         else:
-            fetched = _device_rows(
+            fetched = device_rows(
                 lambda pix, k: topk.topk_for_users(
                     model.user_factors, model.item_factors, pix, k=k),
                 ixs, k)

@@ -353,6 +353,123 @@ def topk_for_users(
         return stable_topk(scores, k)
 
 
+#: widths E of the per-row exclusion lists :func:`masked_topk_rows` is
+#: compiled for: a flush pads its longest list (black list + seen items)
+#: up to the smallest of these, so the programs of a deploy are (padding
+#: bucket x width x k) and none compiles under load. A list longer than
+#: the largest width is not cut: that query is answered on the host
+#: (models/ecommerce counts it as a host fallback; KNOWN_ISSUES.md).
+EXCLUDE_WIDTHS: Tuple[int, ...] = (128, 4352)
+
+#: bit 0 of word 0 of every item's rule words: "an item", what a query
+#: with no categories asks for; category j of the model is bit j + 1
+RULE_ANY_BIT = np.uint32(1)
+
+
+def exclude_width(n: int) -> Optional[int]:
+    """The declared width a flush whose longest exclusion list holds
+    ``n`` indices is padded to; None past the largest."""
+    for w in EXCLUDE_WIDTHS:
+        if n <= w:
+            return w
+    return None
+
+
+def blank_rule_arguments(bucket: int, n_words: int, longest: int,
+                         n_items: int):
+    """A flush's rule arguments for :func:`masked_topk_rows` with no
+    rule in them yet (host arrays, the caller's to fill): (bucket, w)
+    wanted bits, every one set, and (bucket, E) exclusions, every one
+    padding, E the declared width that holds ``longest``."""
+    return (np.full((bucket, n_words), 0xFFFFFFFF, np.uint32),
+            np.full((bucket, exclude_width(longest)), n_items, np.int32))
+
+
+def _exclude(scores: jnp.ndarray, exclude_ixs: jnp.ndarray) -> jnp.ndarray:
+    """``scores`` (b, n) with ``NEG_INF`` at ``[r, exclude_ixs[r, e]]``
+    for every e up to row r's first index that is not under n (padding
+    closes a row's list). One element a step, written where the matrix
+    lies: a loop over the indices that are THERE, a handful a query,
+    and not over the padded width. The TPU compiler lowers one scatter
+    of the same (b, E) indices to two relayout copies of the whole
+    score matrix (to a flat array and back: 2 x 625 MB moved each way
+    at 64 x 2,441,053, and a second copy held) round a sort of all b*E
+    indices, padding included (described v5e, PR 35)."""
+    b, n = scores.shape
+    width = exclude_ixs.shape[1]
+    if not width:
+        return scores
+    gone = jnp.full((1, 1), NEG_INF, scores.dtype)
+
+    def row(r, scores):
+        # a step reads ONE index, the next one, and carries it: on the
+        # v5e every scalar read of the list is an op of its own, 0.8 us
+        # beside the 0.8 us of the write itself (my chip runs, PR 35)
+        def more(state):
+            e, ix, _ = state
+            return (e < width) & (ix < n)
+
+        def one(state):
+            e, ix, scores = state
+            scores = lax.dynamic_update_slice(scores, gone, (r, ix))
+            return (e + 1, exclude_ixs[r, jnp.minimum(e + 1, width - 1)],
+                    scores)
+
+        return lax.while_loop(
+            more, one, (jnp.int32(0), exclude_ixs[r, 0], scores))[2]
+
+    return lax.fori_loop(0, b, row, scores)
+
+
+@partial(jax.jit, static_argnames=("k",))
+def masked_topk_rows(
+    user_factors: jnp.ndarray,   # (n_users, r) device-resident
+    item_factors: jnp.ndarray,   # (n_items, r) device-resident
+    rule_words: jnp.ndarray,     # (w, n_items) uint32 device-resident
+    eligible: jnp.ndarray,       # (n_items,) bool device-resident
+    user_ixs: jnp.ndarray,       # (b,) int32, padded to a serving bucket
+    want_words: jnp.ndarray,     # (b, w) uint32
+    exclude_ixs: jnp.ndarray,    # (b, E) int32, padded with n_items
+    k: int = 10,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`topk_for_users` with business rules between `score` and
+    `select` (the e-commerce template's isCandidateItem, on the device):
+    the same gather, the same one fp32 matmul, the same
+    :func:`stable_topk` (score descending, ties by lowest index), and
+    between them
+
+    - `mask`: a score becomes ``NEG_INF`` where the item is not
+      ``eligible`` (untrained, or on the constraint's unavailable list:
+      one array for every row, replaced on the device when the
+      constraint changes) or shares no bit with the row's
+      ``want_words``. An item's rule words hold :data:`RULE_ANY_BIT`
+      and one bit a category; a query with categories asks for their
+      bits, one with none for every bit;
+    - `exclude`: ``NEG_INF`` written at each row's own ``exclude_ixs``
+      (its black list and what its user has seen), into the score matrix
+      where it lies (:func:`_exclude`). A row's list ends at its first
+      index of ``n_items`` or more: padding. Negative indices are the
+      caller's to keep out. E is one of :data:`EXCLUDE_WIDTHS`.
+
+    What a flush sends is (b,) + (b, w) + (b, E) small integers, never a
+    mask as long as the catalog. Rows whose every candidate is ruled out
+    return ``NEG_INF`` scores, which the caller drops with the scores
+    <= 0. Padding rows: any in-bounds user, any bits, all-padding
+    exclusions."""
+    with jax.named_scope("gather"):
+        Q = jnp.take(user_factors, user_ixs, axis=0)
+    with jax.named_scope("score"):
+        scores = fp32_matmul(Q, item_factors.T)
+    with jax.named_scope("mask"):
+        shared = rule_words[None, :, :] & want_words[:, :, None]
+        ok = eligible[None, :] & jnp.any(shared != 0, axis=1)
+        scores = jnp.where(ok, scores, NEG_INF)
+    with jax.named_scope("exclude"):
+        scores = _exclude(scores, exclude_ixs)
+    with jax.named_scope("select"):
+        return stable_topk(scores, k)
+
+
 def host_masked_topk_batch(factors, query_vecs, masks, ks, weights=None):
     """Batched host serving kernel: ONE (b, r) x (r, n_items) BLAS matmul
     for the whole micro-batch, then the per-row mask/weight/argpartition

@@ -280,6 +280,61 @@ def specs_topk_for_user(n_users: int, n_items: int, rank: int,
         for k in ks]
 
 
+def specs_masked_topk_rows(n_users: int, n_items: int, rank: int,
+                           n_words: int, buckets: Iterable[int],
+                           ks: Iterable[int], device: Any = None
+                           ) -> List[ProgramSpec]:
+    """The e-commerce engine's batched device programs: one per
+    (bucket, exclusion width of ops/topk.py EXCLUDE_WIDTHS, k).
+    ``device`` (models/ecommerce RuleDevice: the live resident arrays)
+    attaches prime closures."""
+    from predictionio_tpu.ops import topk
+    out = []
+    for b in buckets:
+        for width in topk.EXCLUDE_WIDTHS:
+            for k in ks:
+                shape = (n_users, n_items, rank, n_words,
+                         int(b), int(width), int(k))
+                out.append(ProgramSpec(
+                    name="masked_topk_rows",
+                    key=("masked_topk_rows", *shape),
+                    lower=_masked_rows_lowerer(topk, *shape),
+                    prime=(_masked_rows_primer(topk, device, *shape[3:])
+                           if device is not None else None)))
+    return out
+
+
+def _masked_rows_lowerer(topk, n_users, n_items, rank, n_words, bucket,
+                         width, k):
+    def lower():
+        import jax
+        import numpy as np
+        return topk.masked_topk_rows.lower(
+            jax.ShapeDtypeStruct((n_users, rank), np.float32),
+            jax.ShapeDtypeStruct((n_items, rank), np.float32),
+            jax.ShapeDtypeStruct((n_words, n_items), np.uint32),
+            jax.ShapeDtypeStruct((n_items,), np.bool_),
+            jax.ShapeDtypeStruct((bucket,), np.int32),
+            jax.ShapeDtypeStruct((bucket, n_words), np.uint32),
+            jax.ShapeDtypeStruct((bucket, width), np.int32), k=k)
+    return lower
+
+
+def _masked_rows_primer(topk, device, n_words, bucket, width, k):
+    def prime():
+        import jax
+        import numpy as np
+        # a flush with no rule in it: user 0 (in bounds), every bit
+        # wanted, every exclusion padding
+        jax.device_get(topk.masked_topk_rows(
+            device.user_factors, device.item_factors, device.rule_words,
+            device.eligible, np.zeros((bucket,), np.int32),
+            *topk.blank_rule_arguments(
+                bucket, n_words, width, int(device.item_factors.shape[0])),
+            k=k))
+    return prime
+
+
 def _topk_users_lowerer(topk, n_users, n_items, rank, bucket, k):
     def lower():
         import jax
@@ -617,6 +672,10 @@ def _register_builtin() -> None:
     register_jit("topk_for_user", topk.topk_for_user, kind="serving",
                  note="enumerated per k by specs_topk_for_user "
                       "(inline / batching-off path)")
+    register_jit("masked_topk_rows", topk.masked_topk_rows, kind="serving",
+                 note="enumerated per (bucket, exclusion width, k) by "
+                      "specs_masked_topk_rows (the e-commerce engine's "
+                      "device layout)")
     register_jit("topk_scores", topk.topk_scores, kind="serving",
                  note="host-prep templates score via host_masked_topk; "
                       "device dispatch of this kernel is eval/batch-"

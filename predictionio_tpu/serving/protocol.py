@@ -99,3 +99,63 @@ def predict_batch(algo: Any, model: Any, queries: Sequence[Any]) -> List[Any]:
     if impl is None:
         return [algo.predict(model, q) for q in queries]
     return list(impl(model, queries))
+
+
+def host_serves_faster(dispatch, log) -> bool:
+    """The CPU backend's layout rule, one for every engine: "device"
+    arrays buy nothing for a tiny model there, so a real query
+    (``dispatch()``, one call of the engine's single-query program) is
+    compiled, then timed over three calls, and serving moves to host
+    NumPy when a round trip is slower than PIO_SERVE_DEVICE_MS (default
+    3 ms) or fails. Never asked on an accelerator, where the arrays stay
+    on the device."""
+    import time
+
+    import jax
+
+    try:
+        jax.device_get(dispatch())      # warm the compile, then time
+        t0 = time.perf_counter()
+        for _ in range(3):
+            jax.device_get(dispatch())
+        per_query_ms = (time.perf_counter() - t0) / 3 * 1e3
+    except Exception:
+        per_query_ms = float("inf")
+    threshold = float(os.environ.get("PIO_SERVE_DEVICE_MS", "3.0"))
+    if per_query_ms > threshold:
+        log.info("device round-trip %.2fms > %.1fms; serving from host "
+                 "arrays", per_query_ms, threshold)
+        return True
+    return False
+
+
+def device_rows(topk_fn, ixs, k: int):
+    """The device half of a ``predict_batch``, the same for every device
+    layout of every engine (recommendation: replicated, quantized,
+    sharded; e-commerce: replicated with rules): pad the batch's user indices up to a
+    serving bucket (index 0 is in-bounds — KNOWN_ISSUES #5), make the ONE
+    dispatch ``topk_fn(padded_ixs, k)``, fetch, and hand back the real
+    rows of the ``(bucket, k)`` values and indices, still arrays: the
+    `unpack` stage turns them into Python numbers, once a flush.
+    Waterfall stages, drill-downs inside
+    `dispatch` (and, on the batcher's worker, host spans in a profiler
+    capture): `pad`; `execute` round `enqueue` (the call that returns the
+    device arrays: argument transfer and launch) and `device_get`
+    (blocked until the device is done, plus the copy back — the host
+    transfer IS the clock stop, KNOWN_ISSUES #3, so the stage is honest
+    on every backend)."""
+    import jax
+    import numpy as np
+
+    from predictionio_tpu.common import waterfall
+
+    with waterfall.stage("pad"):
+        bucket = bucket_for(len(ixs))
+        pix = np.zeros(bucket, dtype=np.int32)
+        pix[:len(ixs)] = ixs
+    with waterfall.stage("execute"):
+        with waterfall.stage("enqueue"):
+            on_device = topk_fn(pix, k)
+        with waterfall.stage("device_get"):
+            vals, idx = jax.device_get(on_device)
+    return vals[:len(ixs)], idx[:len(ixs)]

@@ -184,7 +184,9 @@ def model_hbm_bytes(models: Iterable[Any]) -> int:
     growth (KNOWN_ISSUES #16). A budget is one device's HBM, so a
     device array counts for what its fullest device holds of it: all
     of a replicated array, one block of a row-sharded one
-    (parallel/serve_dist.py)."""
+    (parallel/serve_dist.py). A model that keeps host copies beside
+    its device arrays says what the device holds itself
+    (``hbm_bytes()``: models/ecommerce ECommModel)."""
     total = 0
     seen: set = set()
 
@@ -203,6 +205,10 @@ def model_hbm_bytes(models: Iterable[Any]) -> int:
 
     for model in models:
         if model is None:
+            continue
+        own = getattr(model, "hbm_bytes", None)
+        if callable(own):
+            total += int(own())
             continue
         add(model)
         attrs = getattr(model, "__dict__", None)

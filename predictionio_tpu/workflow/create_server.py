@@ -333,6 +333,15 @@ def _topk_selection(models: List[Any]) -> Optional[Dict[str, str]]:
     from predictionio_tpu.ops import topk
     from predictionio_tpu.serving import aot
     for m in models:
+        rows = getattr(m, "topk_rows", None)
+        if rows is not None:
+            # a model that says itself which row its programs select
+            # from (models/ecommerce: ops/topk.py masked_topk_rows)
+            n = rows()
+            if n is None:
+                continue
+            return {str(k): topk.selection_name(n, k)
+                    for k in aot.serving_ks(n)}
         fac = getattr(m, "item_factors", None)
         if fac is None:
             continue
@@ -360,10 +369,15 @@ def _serving_layout(models: List[Any]) -> Dict[str, Any]:
     (parallel/serve_dist.py ShardedFactors),
     "replicated" device arrays on one, or "host"; null where no model
     has that shape. ``perShardBytes`` is what one device holds of them:
-    a shard's factor bytes, or the registry's estimate of the model."""
+    a shard's factor bytes, or the registry's estimate of the model. A
+    model of another shape names its own layout (models/ecommerce:
+    "replicated+rules" with its declared ``excludeWidths``, or "host")."""
     import numpy as np
 
     for m in models:
+        own = getattr(m, "serving_layout", None)
+        if own is not None:
+            return own()
         fac = getattr(m, "item_factors", None)
         if fac is None:
             continue
@@ -1124,6 +1138,13 @@ class QueryAPI:
         out["batching"] = ({"enabled": True, **batcher.stats()}
                            if batcher is not None else {"enabled": False})
         out["codec"] = _codec_status()
+        for m in self.models:
+            # an engine's own block, only where that engine is deployed
+            # (models/ecommerce: "ecomm", its rule reads and fallbacks)
+            block = getattr(m, "status_block", None)
+            if block is not None:
+                name, value = block()
+                out[name] = value
         if batcher is not None:
             # read-only, a fact of the compiled programs: whether the
             # flushes above sort whole score rows or k chunks of them

@@ -172,8 +172,9 @@ def test_cell_flags_parse_and_engine_builds(command, flags, engine_dir):
         assert isinstance(engine, Engine)
         params = engine_params_from_json(engine, variant)
     (name, algo), = params.algorithm_params_list
-    assert name == "als" and algo.rank == variant[
-        "algorithms"][0]["params"]["rank"]
+    declared = variant["algorithms"][0]
+    assert name == declared["name"] and algo.rank == declared[
+        "params"]["rank"]
 
 
 # ---------------------------------------------------------------------------
@@ -351,6 +352,15 @@ def _lowered_topk_for_users_sharded(_mp):
     return serve_dist.sharded_program_specs(sharded, (4,), (K,))[0].lower()
 
 
+def _lowered_masked_topk_rows(_mp):
+    from predictionio_tpu.ops import topk
+    return topk.masked_topk_rows.lower(
+        _s((N_USERS, 8), np.float32), _s((300, 8), np.float32),
+        _s((1, 300), np.uint32), _s((300,), np.bool_),
+        _s((4,), np.int32), _s((4, 1), np.uint32),
+        _s((4, topk.EXCLUDE_WIDTHS[0]), np.int32), k=K)
+
+
 def _lowered_trainer(entry, kernel):
     """The trainer as `pio train` reaches it: als.train_explicit on a
     tiny layout, with the jitted entry point lowered on the arguments
@@ -382,6 +392,7 @@ _ENTRY_POINTS = {
     "topk_for_users": ("jit_topk_for_users", _lowered_topk_for_users),
     "topk_for_users_sharded": ("jit_topk_for_users_sharded",
                                _lowered_topk_for_users_sharded),
+    "masked_topk_rows": ("jit_masked_topk_rows", _lowered_masked_topk_rows),
     "train_hybrid": ("jit__train_hybrid_jit",
                      _lowered_trainer("_train_hybrid_jit", "hybrid")),
     "train_csrb": ("jit__train_csrb_jit",
@@ -405,6 +416,182 @@ def test_metric_program_name_is_a_jitted_entry_points_module(
     module = re.search(r"module @(\w+)", lowered(monkeypatch).as_text()
                        ).group(1)
     assert module == expected and re.search(name, module), module
+
+
+def _cell_program_patterns():
+    """{cell: the program patterns its trace metrics search with}."""
+    out = {}
+    for m in BENCHMARK["per_layer"]:
+        spec = json.dumps(_json(BENCH, "metrics", m["name"] + ".json"))
+        pats = set(re.findall(
+            r'"(?:program_s|program_n|program)": "([^"]+)"', spec))
+        for cell in m.get("workloads", ()):
+            out.setdefault(cell, set()).update(pats)
+    return out
+
+
+def test_the_ecomm_cells_patterns_and_the_other_cells_keep_apart():
+    """PERF.md 7.7: a pattern is searched over every module name of a
+    capture. The e-commerce cell's pattern finds its own program's
+    module and no other cell's; theirs do not find it."""
+    pats = _cell_program_patterns()
+    mine = pats.pop(ECOMM_CELL)
+    assert mine == {"masked_topk_rows"}
+    others = set().union(*pats.values())
+    assert others and not mine & others
+    modules = {name: module for name, (module, _l) in _ENTRY_POINTS.items()}
+    for pat in mine:
+        assert [n for n, mod in modules.items() if re.search(pat, mod)] \
+            == ["masked_topk_rows"]
+    for pat in others:
+        assert not re.search(pat, modules["masked_topk_rows"]), pat
+
+
+# ---------------------------------------------------------------------------
+# the e-commerce cell: every counter path and span name its metric
+# files name is one a live deploy has (ISSUE 34's two cases, PERF.md 7.8)
+# ---------------------------------------------------------------------------
+
+ECOMM_CELL = "serve.ecomm-amazon-r128.closed128"
+
+
+def _ecomm_metric_terms(kind):
+    """The `counter` / `traced_counter` paths (kind "counter") or the
+    `span_s` / `span_n` patterns (kind "span") of the cell's metric
+    files."""
+    keys = {"counter": ("counter", "traced_counter"),
+            "span": ("span_s", "span_n")}[kind]
+    found = set()
+
+    def walk(node):
+        if isinstance(node, dict):
+            for k, v in node.items():
+                if k in keys:
+                    found.add(v)
+                else:
+                    walk(v)
+        elif isinstance(node, list):
+            for v in node:
+                walk(v)
+
+    for m in BENCHMARK["per_layer"]:
+        if ECOMM_CELL in m.get("workloads", ()):
+            walk(_json(BENCH, "metrics", m["name"] + ".json"))
+    return sorted(found)
+
+
+@pytest.fixture(scope="module")
+def ecomm_served():
+    """The cell's adapter at rehearsal size (its own `models`: the
+    ECommModel, the events, the constraint), deployed by QueryAPI with
+    batching and telemetry on; a few queries of its own generator
+    answered on the batcher's worker with the spans recorded; -> the
+    `GET /` page before and after, the span names, the replies."""
+    from predictionio_tpu.common import profiling
+    from predictionio_tpu.data.storage import EngineInstance, Model
+    from predictionio_tpu.models.ecommerce import ECommerceEngine
+    from predictionio_tpu.workflow import model_io
+    from predictionio_tpu.workflow.create_server import QueryAPI, ServerConfig
+
+    cfg = _json(ROOT, _CONFIG_FILE["ecomm-als-amazon-r128"])
+    variant = _json(ROOT, cfg["engine_dir"], "engine.json")
+    mp = pytest.MonkeyPatch()
+    mp.setenv("PIO_SERVE_DEVICE_MS", "1e9")
+    telemetry.set_enabled(True)
+    devicewatch.install()
+    storage = use_memory_storage()
+    spans = []
+    real_annotate = profiling.annotate
+
+    def annotate(name):
+        spans.append(name)
+        return real_annotate(name)
+
+    mp.setattr(profiling, "annotate", annotate)
+    try:
+        with _benchmark_on_path():
+            harness = _load("harness")
+            sys.modules["harness"] = harness
+            adapter = harness.adapter_of(cfg)
+            spec = {"config": cfg, "traffic": _json(
+                BENCH, "traffic", "closed128.json")}
+            model = adapter.rehearsal_model(cfg["model"], 4000)
+            models = adapter.models(cfg, model, 5, storage, variant)
+            asked = adapter.queries(spec, model, 5, 48)
+            wire = adapter.wire(spec)
+            now = dt.datetime.now(dt.timezone.utc)
+            instance_id = storage.get_meta_data_engine_instances().insert(
+                EngineInstance(
+                    id="", status="COMPLETED", start_time=now, end_time=now,
+                    engine_id=variant["id"], engine_version="NOT_USED",
+                    engine_variant=variant["id"],
+                    engine_factory=variant["engineFactory"],
+                    data_source_params=json.dumps(variant["datasource"]),
+                    preparator_params="{}",
+                    algorithms_params=json.dumps(variant["algorithms"]),
+                    serving_params="{}"))
+            storage.get_model_data_models().insert(Model(
+                id=instance_id, models=model_io.serialize_models(
+                    models, check_finite=True)))
+            api = QueryAPI(storage=storage, engine=ECommerceEngine(),
+                           config=ServerConfig(batching="on"))
+            try:
+                _st, before = api.handle("GET", "/")
+                replies = []
+                for query in asked:
+                    status, body = api.handle(
+                        "POST", "/queries.json", body=wire.body(query).encode())
+                    replies.append(wire.parse(status, json.dumps(body)))
+                _st, after = api.handle("GET", "/")
+            finally:
+                api.close()
+            whole = [wire.whole(q, r) for q, r in zip(asked, replies)]
+        yield {"before": before, "after": after, "spans": set(spans),
+               "replies": replies, "whole": whole}
+    finally:
+        sys.modules.pop("harness", None)
+        reset_storage()
+        telemetry.set_enabled(None)
+        mp.undo()
+
+
+def test_ecomm_cell_deploys_on_the_device_layout_and_answers(ecomm_served):
+    b = ecomm_served["after"]["batching"]
+    assert b["layout"] == "replicated+rules" and b["excludeWidths"]
+    assert all(ecomm_served["whole"]) and any(ecomm_served["replies"])
+
+
+@pytest.mark.parametrize("path", _ecomm_metric_terms("counter"))
+def test_ecomm_counter_path_is_on_the_status_page(ecomm_served, path):
+    """benchmark/reduce.py `_counter` walks the dotted path into `GET /`
+    and wants a number that does not run backwards."""
+    ends = []
+    for page in (ecomm_served["before"], ecomm_served["after"]):
+        node = page
+        for key in path.split("."):
+            assert isinstance(node, dict) and key in node, (path, key)
+            node = node[key]
+        assert isinstance(node, (int, float)) and not isinstance(node, bool)
+        ends.append(node)
+    assert ends[0] <= ends[1]
+    if path == "ecomm.queries":
+        assert ends[1] - ends[0] == len(ecomm_served["replies"])
+    if path == "ecomm.hostFallbacks":
+        assert ends[1] == ends[0]
+
+
+@pytest.mark.parametrize("pattern", _ecomm_metric_terms("span"))
+def test_ecomm_span_is_one_annotate_emits(ecomm_served, pattern):
+    """A `span_s` term is searched over the names common/profiling.py
+    annotate gave the worker's spans."""
+    assert [n for n in ecomm_served["spans"] if re.search(pattern, n)], (
+        pattern, sorted(ecomm_served["spans"]))
+
+
+def test_ecomm_flush_emits_the_rule_spans_and_the_shared_stages(
+        ecomm_served):
+    assert {"rules", "rules.seen", "rules.constraint", "pad", "execute",
+            "enqueue", "device_get", "unpack"} <= ecomm_served["spans"]
 
 
 # ---------------------------------------------------------------------------

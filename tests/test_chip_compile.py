@@ -144,6 +144,47 @@ def test_topk_for_users_compiles_at_the_benchmark_cells_shape(
     assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * scores_bytes
 
 
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_masked_topk_rows_compiles_at_the_benchmark_cells_shape(
+        one_chip, no_compile_cache, bucket):
+    """The e-commerce cell's program (BENCHMARK.json,
+    ecomm-als-amazon-r128: the same factors, one uint32 rule word and
+    one eligibility flag an item, 28 categories) at its real size and
+    its largest declared exclusion width. As its unmasked twin: no sort
+    as long as the catalog. And what the rules must not bring: a second
+    copy of the scores. The temporaries are the score matrix (written
+    once, the rules applied in the matmul's own output fusion; the
+    exclusions overwritten in place, one element a step of a `while`)
+    and small change: under 1.1 of it, where one XLA scatter of the
+    same indices holds 2.0 (1,250,232,832 B at bucket 64: a relayout to
+    a flat copy and back round a sort of all 64 x 4,352 indices)."""
+    from predictionio_tpu.ops import topk
+    n_users, n_items, rank, words = 6_643_669, 2_441_053, 128, 1
+    width = topk.EXCLUDE_WIDTHS[-1]
+    compiled = topk.masked_topk_rows.lower(
+        _s((n_users, rank), jnp.float32, one_chip),
+        _s((n_items, rank), jnp.float32, one_chip),
+        _s((words, n_items), jnp.uint32, one_chip),
+        _s((n_items,), jnp.bool_, one_chip),
+        _s((bucket,), jnp.int32, one_chip),
+        _s((bucket, words), jnp.uint32, one_chip),
+        _s((bucket, width), jnp.int32, one_chip), k=K).compile()
+    text = compiled.as_text()
+    assert not [line for line in text.splitlines()
+                if " sort(" in line and f",{n_items}]" in line]
+    # no scatter op, and no flat copy of the score matrix for one
+    assert " scatter(" not in text
+    if bucket > 1:      # bucket 1's scores are a flat row as they are
+        assert f"f32[{bucket * n_items}]" not in text
+    scores_bytes = 4 * max(bucket, 8) * n_items     # 8 sublanes a tile
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.1 * scores_bytes
+    # resident: factors + rule words + eligibility, and a flush's
+    # arguments: under 0.3 % over the factors
+    factors = 4 * (n_users + n_items) * rank
+    assert factors < mem.argument_size_in_bytes < 1.003 * factors
+
+
 # ---------------------------------------------------------------------------
 # the trainer
 # ---------------------------------------------------------------------------
