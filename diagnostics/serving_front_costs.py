@@ -17,13 +17,20 @@ repository (a parent commit unpacked beside this one).
   `data/api/http.py make_server` in a process of its own, asked by
   `benchmark/loadgen.py closed_loop`. One connection: the server's CPU-us
   a request. 128 connections: queries/s, the generator's and the
-  server's cores. That rate is the generator's own ceiling: a cell that
-  reads near it is measuring `loadgen.py`, not the server.
+  server's cores. That rate is the PAIR's ceiling (one GIL each side).
+- `generator_alone`: the same 128 connections against a bare socket
+  answerer (one selector loop in a process that imports nothing of the
+  program, a canned reply as soon as a request is whole; `server_*` is
+  that answerer): what `loadgen.py` can ask when the other side costs
+  next to nothing. A cell that reads near it is measuring `loadgen.py`,
+  not the server.
 """
 
 import argparse
 import json
 import os
+import selectors
+import socket
 import subprocess
 import sys
 import time
@@ -118,7 +125,53 @@ def serve_stub():
     server.serve_forever()
 
 
-def transport(root, seconds):
+def serve_bare():
+    """The canned reply to whatever request is whole, on one selector
+    loop; `GET /cpu` as the stub has it."""
+    body = json.dumps(REPLY).encode()
+    head = ("HTTP/1.1 200 OK\r\nContent-Type: application/json\r\n"
+            "Content-Length: %d\r\n\r\n")
+    canned = (head % len(body)).encode() + body
+    listener = socket.create_server(("127.0.0.1", 0), backlog=128)
+    listener.setblocking(False)
+    print(listener.getsockname()[1], flush=True)
+    sel = selectors.DefaultSelector()
+    sel.register(listener, selectors.EVENT_READ, None)
+    while True:
+        for key, _events in sel.select():
+            if key.data is None:
+                conn, _addr = listener.accept()
+                conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+                sel.register(conn, selectors.EVENT_READ, bytearray())
+                continue
+            conn, buf = key.fileobj, key.data
+            got = conn.recv(65536)
+            if not got:
+                sel.unregister(conn)
+                conn.close()
+                continue
+            buf += got
+            while True:
+                end = buf.find(b"\r\n\r\n")
+                if end < 0:
+                    break
+                lower = bytes(buf[:end]).lower()
+                at = lower.find(b"content-length:")
+                length = (int(lower[at + 15:].split(b"\r\n", 1)[0])
+                          if at >= 0 else 0)
+                if len(buf) < end + 4 + length:
+                    break
+                reply = canned
+                if buf.startswith(b"GET /cpu "):
+                    cpu = json.dumps({"cpu_s": time.process_time()}).encode()
+                    reply = (head % len(cpu)).encode() + cpu
+                del buf[:end + 4 + length]
+                conn.sendall(reply)
+
+
+def _asked(root, seconds, flag, connections):
+    """`loadgen.closed_loop` against a child of this file started with
+    `flag`, at each count of connections."""
     import urllib.request
 
     import numpy as np
@@ -128,7 +181,7 @@ def transport(root, seconds):
 
     env = dict(os.environ, PYTHONPATH=root)
     child = subprocess.Popen(
-        [sys.executable, os.path.abspath(__file__), "--serve-stub"],
+        [sys.executable, os.path.abspath(__file__), flag],
         env=env, stdout=subprocess.PIPE, text=True)
     try:
         port = int(child.stdout.readline())
@@ -140,7 +193,7 @@ def transport(root, seconds):
 
         users = np.arange(2_000_000)
         out = {}
-        for conns in (1, 128):
+        for conns in connections:
             loadgen.closed_loop(port, users, 10, conns, 1.0)   # warm
             c0, g0 = server_cpu(), time.process_time()
             records, t0, t1 = loadgen.closed_loop(
@@ -159,22 +212,35 @@ def transport(root, seconds):
         child.wait(30)
 
 
+def transport(root, seconds):
+    return _asked(root, seconds, "--serve-stub", (1, 128))
+
+
+def generator_alone(root, seconds):
+    return _asked(root, seconds, "--serve-bare", (128,))["connections_128"]
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--root", default=os.path.dirname(HERE))
     ap.add_argument("--seconds", type=float, default=5.0)
     ap.add_argument("--calls", type=int, default=20000)
     ap.add_argument("--serve-stub", action="store_true")
+    ap.add_argument("--serve-bare", action="store_true")
     args = ap.parse_args()
     root = os.path.abspath(args.root)
     if args.serve_stub:
         return serve_stub()   # PYTHONPATH names the checkout
+    if args.serve_bare:
+        return serve_bare()
     sys.path.insert(0, root)
     print(json.dumps({"root": root, "cpus": os.cpu_count(),
                       "codec_us": codec(args.calls)}), flush=True)
     print(json.dumps({"root": root,
                       "transport": transport(root, args.seconds)}),
           flush=True)
+    print(json.dumps({"root": root, "generator_alone":
+                      generator_alone(root, args.seconds)}), flush=True)
 
 
 if __name__ == "__main__":

@@ -484,6 +484,16 @@ METRICS: Dict[str, str] = {
         "replies checked for NaN/Inf by kind: folded (in the pass that "
         "builds the JSON value) / walked (again, after feedback or an "
         "output blocker)",
+    "pio_transport_requests_total":
+        "requests the HTTP transport finished, answered or severed "
+        "(data/api/http.py), counted as the reply goes out",
+    "pio_transport_writes_total":
+        "socket writes the HTTP transport made for replies; equal to "
+        "pio_transport_requests_total: one write a reply (an injected "
+        "abort has none, an Expect: 100-continue two)",
+    "pio_transport_protocol_errors_total":
+        "requests refused by the transport itself, by status code: 400 "
+        "(request line, header line, Content-Length), 414, 431, 501, 505",
     "pio_ecomm_queries_total":
         "e-commerce queries answered, by any layout "
         "(models/ecommerce/als_algorithm.py)",

@@ -6,11 +6,15 @@ of extra response headers (e.g. Retry-After on a 503 from the query
 batcher's admission control). Two interchangeable transports sit under
 every daemon (event, storage, query), selected by ``PIO_TRANSPORT``:
 
-- ``threaded`` (default): the stdlib ``ThreadingHTTPServer`` stack —
-  one OS thread per connection, mirroring the reference's spray actors
-  over a dispatcher (EventServer.scala:602-663). Bit-compatible
-  fallback: its wire bytes are the contract the async transport is
-  asserted against.
+- ``threaded`` (default; what ``pio deploy`` starts): the stdlib
+  ``ThreadingHTTPServer`` accept loop — one OS thread per connection,
+  mirroring the reference's spray actors over a dispatcher
+  (EventServer.scala:602-663) — under this module's own handler, not
+  ``http.server``'s: one head parse into a plain dict, one ``sendall``
+  of head + body a reply. The bytes are ``BaseHTTPRequestHandler``'s,
+  reply for reply (tests/test_http_transport.py holds recordings of
+  it), save that HTTP/0.9 and a malformed header line or
+  ``Content-Length`` answer 400 and every error reply has its head.
 - ``async``: a single-threaded selector event loop (asyncio) that owns
   accept/parse/serialize, with proper HTTP/1.1 keep-alive and
   pipelining — pipelined requests on one connection dispatch
@@ -22,12 +26,14 @@ every daemon (event, storage, query), selected by ``PIO_TRANSPORT``:
   front door's scaling path (ROADMAP item 4): the thread-per-request
   stack stops scaling past ~8 connections, the loop does not.
 
-Both transports funnel every request through ONE dispatch function
-(:func:`dispatch_request`) — fault injection, trace adoption, compile
-attribution, request telemetry, JSON strictness and header assembly are
-decided once, so the two modes are wire-byte identical on every
-endpoint (asserted by tests/test_async_transport.py; only the Date
-header's clock value differs).
+Both transports share ONE head parser (:class:`RequestHead`), ONE
+dispatch function (:func:`dispatch_request`) and ONE head renderer
+(:func:`_render_head`) — protocol checks, fault injection, trace
+adoption, compile attribution, request telemetry, JSON strictness and
+header assembly are decided once, so the two modes are wire-byte
+identical on every endpoint (asserted by tests/test_async_transport.py;
+only the Date header's clock value differs) and differ only in who
+owns the socket.
 """
 
 from __future__ import annotations
@@ -35,12 +41,14 @@ from __future__ import annotations
 import asyncio
 import contextlib
 import email.utils
+import html
 import http.server
 import json
 import logging
 import os
 import signal
 import socket
+import socketserver
 import threading
 import time
 import urllib.parse
@@ -93,10 +101,11 @@ class RequestOutcome:
     truncation (PIO_FAULT_SPEC): the client must observe a genuinely
     torn response, so the transport sends the short body and drops the
     connection. ``abort`` means send NOTHING and sever (a mid-request
-    kill)."""
+    kill). ``reason`` is set on a transport-level error reply alone
+    (:func:`_error_outcome`)."""
 
     __slots__ = ("status", "data", "ctype", "extra_headers",
-                 "advertised_len", "close", "abort")
+                 "advertised_len", "close", "abort", "reason")
 
     def __init__(self):
         self.status = 500
@@ -106,6 +115,7 @@ class RequestOutcome:
         self.advertised_len = 0
         self.close = False
         self.abort = False
+        self.reason: Optional[str] = None
 
 
 def dispatch_request(api, method: str, target: str, body: bytes,
@@ -218,74 +228,48 @@ def dispatch_request(api, method: str, target: str, body: bytes,
 
 
 # ---------------------------------------------------------------------------
-# threaded transport (the bit-compatible fallback)
+# the wire format both transports share: one head parser, one renderer
 # ---------------------------------------------------------------------------
 
-class _Handler(BaseHTTPRequestHandler):
-    api = None  # set by make_server
-    protocol_version = "HTTP/1.1"
-    # serving-latency path: without this, Nagle + delayed-ACK adds ~40ms
-    # per small keep-alive response (CreateServer.scala p50 parity target)
-    disable_nagle_algorithm = True
-
-    def _dispatch(self, method: str) -> None:
-        length = int(self.headers.get("Content-Length") or 0)
-        body = self.rfile.read(length) if length else b""
-        out = dispatch_request(self.api, method, self.path, body,
-                               dict(self.headers.items()))
-        if out.abort:
-            self.close_connection = True
-            return   # no response bytes at all: a mid-request kill
-        try:
-            self.send_response(out.status)
-            self.send_header("Content-Type", out.ctype)
-            self.send_header("Content-Length", str(out.advertised_len))
-            for name, value in out.extra_headers.items():
-                self.send_header(name, str(value))
-            self.end_headers()
-            self.wfile.write(out.data)
-        except (BrokenPipeError, ConnectionResetError):
-            # the client gave up on this connection (timeout, retry on a
-            # fresh one, or a mid-request kill); the work is done — losing
-            # the response write is their failure mode, not ours
-            self.close_connection = True
-        if out.close:
-            self.close_connection = True
-
-    def do_GET(self):  # noqa: N802
-        self._dispatch("GET")
-
-    def do_POST(self):  # noqa: N802
-        self._dispatch("POST")
-
-    def do_DELETE(self):  # noqa: N802
-        self._dispatch("DELETE")
-
-    def do_PUT(self):  # noqa: N802
-        self._dispatch("PUT")
-
-    def log_message(self, fmt, *args):  # route logs through logging, quietly
-        logging.getLogger("predictionio_tpu.http").debug(fmt, *args)
-
-
-# ---------------------------------------------------------------------------
-# async transport (the event-loop rewrite, ROADMAP item 4)
-# ---------------------------------------------------------------------------
-
-#: methods the threaded handler implements (do_*); anything else answers
-#: 501 on both transports
+#: methods a handler is asked for; anything else answers 501
 _METHODS = frozenset({"GET", "POST", "PUT", "DELETE"})
 
-#: known-nonblocking GET routes served inline on the loop thread; every
-#: other request runs on the bounded executor because handlers may block
-#: (WAL group commit, device dispatch, storage RPC)
-_INLINE_PATHS = frozenset({"/healthz"})
+#: the Server header `BaseHTTPRequestHandler.version_string()` gave
+_SERVER_SOFTWARE = (BaseHTTPRequestHandler.server_version + " "
+                    + BaseHTTPRequestHandler.sys_version)
 
-#: exact Server header of the threaded stack — wire-byte parity
-_SERVER_SOFTWARE = (_Handler.server_version + " " + _Handler.sys_version)
-
+#: `http.client`'s limits, which held under `BaseHTTPRequestHandler`: a
+#: line of the head longer than this answers 414 (request line) or 431
+#: (header line), as does the 100th header line
 _MAX_LINE = 65536
-_MAX_HEADERS = 128
+_MAX_HEADERS = 100
+
+_BLANK = (b"\r\n", b"\n", b"")
+_CONTINUE = b"HTTP/1.1 100 Continue\r\n\r\n"
+
+_log = logging.getLogger("predictionio_tpu.http")
+
+_M_REQUESTS = telemetry.registry().counter(
+    "pio_transport_requests_total",
+    "Requests finished, answered or severed, as the reply goes out").child()
+_M_WRITES = telemetry.registry().counter(
+    "pio_transport_writes_total",
+    "Socket writes made for replies: one a reply").child()
+_M_PROTOCOL_ERRORS = telemetry.registry().counter(
+    "pio_transport_protocol_errors_total",
+    "Requests the transport itself refused, by status code",
+    labelnames=("code",))
+
+
+def transport_status() -> Dict[str, object]:
+    """`GET /`'s transport block: the process-wide counters /metrics
+    has. `writes == requests` says one write a reply (an injected abort
+    is a request with none, an `Expect: 100-continue` one with two)."""
+    return {"mode": transport_mode(),
+            "requests": int(_M_REQUESTS.value),
+            "writes": int(_M_WRITES.value),
+            "protocolErrors": int(sum(
+                v for _n, _l, v in _M_PROTOCOL_ERRORS.samples()))}
 
 
 def _status_phrase(code: int) -> str:
@@ -311,19 +295,216 @@ def _http_date() -> str:
 
 
 def _render_head(out: RequestOutcome) -> bytes:
-    """The exact byte sequence BaseHTTPRequestHandler emits for this
+    """The exact byte sequence BaseHTTPRequestHandler emitted for this
     outcome: status line, Server, Date, Content-Type, Content-Length,
-    extra headers, blank line."""
+    extra headers, blank line; for a transport-level error its
+    `send_error`'s: the message as the reason phrase and
+    `Connection: close` ahead of the entity headers."""
     lines = [
-        f"HTTP/1.1 {out.status} {_status_phrase(out.status)}\r\n",
+        f"HTTP/1.1 {out.status} "
+        f"{out.reason or _status_phrase(out.status)}\r\n",
         f"Server: {_SERVER_SOFTWARE}\r\n",
         f"Date: {_http_date()}\r\n",
-        f"Content-Type: {out.ctype}\r\n",
-        f"Content-Length: {out.advertised_len}\r\n",
     ]
+    if out.reason:
+        lines.append("Connection: close\r\n")
+    lines.append(f"Content-Type: {out.ctype}\r\n")
+    lines.append(f"Content-Length: {out.advertised_len}\r\n")
     lines.extend(f"{k}: {v}\r\n" for k, v in out.extra_headers.items())
     lines.append("\r\n")
     return "".join(lines).encode("latin-1", "strict")
+
+
+def _error_outcome(code: int, message: Optional[str] = None,
+                   explain: Optional[str] = None) -> RequestOutcome:
+    """A transport-level error reply (malformed request line, oversized
+    header, unsupported method) as the stdlib's send_error wrote it;
+    the connection closes after it."""
+    _M_PROTOCOL_ERRORS.labels(code=str(code)).inc()
+    out = RequestOutcome()
+    phrase, long_explain = (BaseHTTPRequestHandler.responses.get(code)
+                            or ("???", "???"))
+    out.reason = message or phrase
+    body = (http.server.DEFAULT_ERROR_MESSAGE % {
+        "code": code,
+        "message": html.escape(out.reason, quote=False),
+        "explain": html.escape(explain or long_explain, quote=False),
+    }).encode("utf-8", "replace")
+    out.status = code
+    out.data = body
+    out.advertised_len = len(body)
+    out.ctype = http.server.DEFAULT_ERROR_CONTENT_TYPE
+    out.close = True
+    return out
+
+
+class RequestHead:
+    """One request's head, taken a line at a time from whichever
+    transport owns the socket (`rfile`, or the loop's `StreamReader`):
+    no I/O here, so both share `BaseHTTPRequestHandler.parse_request`'s
+    checks, in its order. After the last line :meth:`feed` wants,
+    `error` holds a protocol error's canned reply, or `method` is None
+    (the peer closed, or sent a blank line for a request: hang up), or
+    the other fields describe the request; `headers` is a plain dict
+    under the names as sent, the last of a repeated name winning, as
+    `dict(Message.items())` gave."""
+
+    __slots__ = ("line", "method", "target", "headers", "length",
+                 "close_after", "expect_continue", "error", "_http11",
+                 "_lines")
+
+    def __init__(self):
+        self.line = b""
+        self.method: Optional[str] = None
+        self.target = ""
+        self.headers: Dict[str, str] = {}
+        self.length = 0
+        self.close_after = True
+        self.expect_continue = False
+        self.error: Optional[RequestOutcome] = None
+        self._http11 = False
+        self._lines = 0
+
+    def _refuse(self, code: int, message: Optional[str] = None,
+                explain: Optional[str] = None) -> bool:
+        self.error = _error_outcome(code, message, explain)
+        return False
+
+    def _request_line(self, line: bytes) -> bool:
+        self.line = line
+        if len(line) > _MAX_LINE:
+            return self._refuse(414)
+        text = line.decode("latin-1").rstrip("\r\n")
+        words = text.split()
+        if not words:
+            return False
+        if len(words) >= 3:
+            version = words[-1]
+            if version == "HTTP/1.1":
+                self._http11 = True
+            elif version != "HTTP/1.0":
+                major, dot, minor = version[5:].partition(".")
+                if not (version.startswith("HTTP/") and version.isascii()
+                        and major.isdigit() and minor.isdigit()
+                        and len(major) <= 10 and len(minor) <= 10):
+                    return self._refuse(
+                        400, f"Bad request version ({version!r})")
+                if int(major) >= 2:
+                    return self._refuse(
+                        505, f"Invalid HTTP version ({version[5:]})")
+                self._http11 = (int(major), int(minor)) >= (1, 1)
+        if len(words) != 3:
+            # two words were an HTTP/0.9 request, which is not served
+            return self._refuse(400, f"Bad request syntax ({text!r})")
+        self.method, target = words[0], words[1]
+        if target.startswith("//"):
+            # gh-87389: //host/path reads as a URI without its scheme
+            target = "/" + target.lstrip("/")
+        self.target = target
+        self.close_after = not self._http11
+        return True
+
+    def feed(self, line: bytes) -> bool:
+        """Take the next line of the head as the socket gave it (`b""`
+        at EOF); False once no more is wanted."""
+        if self.method is None:
+            return self._request_line(line)
+        if line in _BLANK:
+            if self.method not in _METHODS:
+                return self._refuse(
+                    501, f"Unsupported method ({self.method!r})")
+            return False
+        self._lines += 1
+        if len(line) > _MAX_LINE:
+            return self._refuse(
+                431, "Line too long",
+                f"got more than {_MAX_LINE} bytes when reading header line")
+        if self._lines >= _MAX_HEADERS:
+            return self._refuse(
+                431, "Too many headers",
+                f"got more than {_MAX_HEADERS} headers")
+        key, sep, value = line.decode("latin-1").partition(":")
+        if not sep or key[:1] in " \t":
+            return self._refuse(400, "Bad header line")
+        key, value = key.strip(), value.strip()
+        self.headers[key] = value
+        lk = key.lower()
+        if lk == "content-length":
+            if not (value.isascii() and value.isdigit()):
+                return self._refuse(400, "Bad Content-Length")
+            self.length = int(value)
+        elif lk == "connection":
+            v = value.lower()
+            if v == "close":
+                self.close_after = True
+            elif v == "keep-alive":
+                self.close_after = False
+        elif lk == "expect":
+            self.expect_continue = (value.lower() == "100-continue"
+                                    and self._http11)
+        return True
+
+
+# ---------------------------------------------------------------------------
+# threaded transport (what `pio deploy` starts)
+# ---------------------------------------------------------------------------
+
+class _Handler(socketserver.StreamRequestHandler):
+    """One connection's thread: requests off a kept-alive socket until
+    either side closes it."""
+
+    api = None  # set by make_server
+    # serving-latency path: without this, Nagle + delayed-ACK adds ~40ms
+    # per small keep-alive response (CreateServer.scala p50 parity target)
+    disable_nagle_algorithm = True
+
+    def handle(self):
+        try:
+            while self._one_request():
+                pass
+        except ConnectionError:
+            # the client gave up on this connection (timeout, retry on a
+            # fresh one, or a mid-request kill); the work is done — losing
+            # the response write is their failure mode, not ours
+            pass
+
+    def _one_request(self) -> bool:
+        """Read, dispatch and answer one request; False to hang up."""
+        readline, send = self.rfile.readline, self.connection.sendall
+        head = RequestHead()
+        while head.feed(readline(_MAX_LINE + 1)):
+            pass
+        out = head.error
+        if out is None:
+            if head.method is None:
+                return False
+            if head.expect_continue:
+                _M_WRITES.inc()
+                send(_CONTINUE)
+            # read() on the buffered file takes Content-Length bytes
+            # however they arrive (storage RPC bodies run to many MB)
+            body = self.rfile.read(head.length) if head.length else b""
+            out = dispatch_request(self.api, head.method, head.target,
+                                   body, head.headers)
+        if _log.isEnabledFor(logging.DEBUG):
+            _log.debug('"%s" %s -', head.line.decode("latin-1").rstrip(),
+                       "aborted" if out.abort else out.status)
+        _M_REQUESTS.inc()
+        if out.abort:
+            return False   # no response bytes at all: a mid-request kill
+        _M_WRITES.inc()
+        send(_render_head(out) + out.data)
+        return not (out.close or head.close_after)
+
+
+# ---------------------------------------------------------------------------
+# async transport (the event-loop rewrite, ROADMAP item 4)
+# ---------------------------------------------------------------------------
+
+#: known-nonblocking GET routes served inline on the loop thread; every
+#: other request runs on the bounded executor because handlers may block
+#: (WAL group commit, device dispatch, storage RPC)
+_INLINE_PATHS = frozenset({"/healthz"})
 
 
 def _dispatch_and_render(api, method, target, body, headers):
@@ -334,27 +515,6 @@ def _dispatch_and_render(api, method, target, body, headers):
     if out.abort:
         return out, None
     return out, _render_head(out) + out.data
-
-
-def _error_outcome(code: int, message: Optional[str] = None,
-                   ) -> RequestOutcome:
-    """A transport-level error reply (malformed request line, oversized
-    header, unsupported method) in the stdlib send_error shape."""
-    out = RequestOutcome()
-    phrase = _status_phrase(code)
-    explain = (BaseHTTPRequestHandler.responses.get(code) or ("", ""))[1]
-    import html as _html
-    body = (http.server.DEFAULT_ERROR_MESSAGE % {
-        "code": code,
-        "message": _html.escape(message or phrase, quote=False),
-        "explain": _html.escape(explain, quote=False),
-    }).encode("utf-8", "replace")
-    out.status = code
-    out.data = body
-    out.advertised_len = len(body)
-    out.ctype = http.server.DEFAULT_ERROR_CONTENT_TYPE
-    out.close = True
-    return out
 
 
 class _Conn:
@@ -398,8 +558,7 @@ class AsyncHTTPServer:
             )
             self._ssl = ssl_context_from_env()
             if self._ssl is not None:
-                logging.getLogger("predictionio_tpu.http").info(
-                    "TLS enabled (PIO_SSL_CERTFILE)")
+                _log.info("TLS enabled (PIO_SSL_CERTFILE)")
         # socketserver's default listen backlog of 5 resets bursts of
         # concurrent connects (measured: 32 parallel ingest clients) —
         # same 128 backlog as the threaded transport
@@ -494,7 +653,7 @@ class AsyncHTTPServer:
         queue: asyncio.Queue = asyncio.Queue()
         window = asyncio.Semaphore(self._pipeline)
         conn.reader_task = asyncio.create_task(
-            self._read_loop(reader, queue, window, conn))
+            self._read_loop(reader, writer, queue, window, conn))
         self._conns.add(conn)
         try:
             while True:
@@ -505,11 +664,12 @@ class AsyncHTTPServer:
                 try:
                     out, payload = await fut
                 except Exception:
-                    logging.getLogger("predictionio_tpu.http").exception(
-                        "async dispatch failed")
+                    _log.exception("async dispatch failed")
                     break
+                _M_REQUESTS.inc()
                 if out.abort:
                     break   # injected mid-request kill: sever, no bytes
+                _M_WRITES.inc()
                 writer.write(payload)
                 try:
                     await writer.drain()
@@ -533,35 +693,47 @@ class AsyncHTTPServer:
             with contextlib.suppress(BaseException):
                 writer.close()
 
-    async def _read_loop(self, reader, queue, window, conn):
+    async def _read_loop(self, reader, writer, queue, window, conn):
         loop = asyncio.get_running_loop()
         try:
             while True:
                 await window.acquire()
-                req = await self._read_request(reader)
-                if req is None:
-                    queue.put_nowait(None)
+                head = RequestHead()
+                try:
+                    while head.feed(await reader.readline()):
+                        pass
+                except (asyncio.LimitOverrunError, ValueError):
+                    # the StreamReader's own 64 KiB limit on a line
+                    head.feed(b" " * (_MAX_LINE + 1))
+                if head.error is None and head.method is None:
+                    queue.put_nowait(None)   # the peer closed
                     return
-                method, target, body, headers, close_after, err = req
                 conn.admitted += 1
+                err = head.error
                 if err is not None:
                     fut = loop.create_future()
                     fut.set_result((err, _render_head(err) + err.data))
                     queue.put_nowait((fut, True))
                     return
+                close_after = head.close_after
+                if head.expect_continue:
+                    _M_WRITES.inc()
+                    writer.write(_CONTINUE)
+                body = (await reader.readexactly(head.length)
+                        if head.length else b"")
                 if self._stop_event is not None \
                         and self._stop_event.is_set():
                     close_after = True   # draining: serve, then hang up
-                if method == "GET" and \
-                        target.partition("?")[0] in _INLINE_PATHS:
+                work = (self.api, head.method, head.target, body,
+                        head.headers)
+                if head.method == "GET" and \
+                        head.target.partition("?")[0] in _INLINE_PATHS:
                     # known-nonblocking probe: skip the executor hop
                     fut = loop.create_future()
-                    fut.set_result(_dispatch_and_render(
-                        self.api, method, target, body, headers))
+                    fut.set_result(_dispatch_and_render(*work))
                 else:
                     fut = loop.run_in_executor(
-                        self._executor, _dispatch_and_render, self.api,
-                        method, target, body, headers)
+                        self._executor, _dispatch_and_render, *work)
                 queue.put_nowait((fut, close_after))
                 if close_after:
                     return
@@ -571,57 +743,6 @@ class AsyncHTTPServer:
         except (ConnectionError, OSError, asyncio.IncompleteReadError,
                 ValueError):
             queue.put_nowait(None)
-
-    async def _read_request(self, reader):
-        """Parse one request: (method, target, body, headers, close_after,
-        err_outcome) — or None at EOF. ``err_outcome`` is a canned reply
-        for transport-level protocol errors."""
-        try:
-            line = await reader.readline()
-        except (asyncio.LimitOverrunError, ValueError):
-            return "GET", "/", b"", {}, True, _error_outcome(414)
-        if not line:
-            return None
-        if len(line) > _MAX_LINE:
-            return "GET", "/", b"", {}, True, _error_outcome(414)
-        words = line.decode("latin-1").rstrip("\r\n").split()
-        if len(words) != 3 or not words[2].startswith("HTTP/"):
-            return "GET", "/", b"", {}, True, _error_outcome(
-                400, f"Bad request syntax ({line.decode('latin-1', 'replace').rstrip()!r})")
-        method, target, version = words
-        close_after = version == "HTTP/1.0"
-        headers: Dict[str, str] = {}
-        length = 0
-        while True:
-            h = await reader.readline()
-            if h in (b"\r\n", b"\n", b""):
-                break
-            if len(h) > _MAX_LINE or len(headers) >= _MAX_HEADERS:
-                return method, target, b"", {}, True, _error_outcome(431)
-            text = h.decode("latin-1")
-            key, sep, value = text.partition(":")
-            if not sep:
-                return method, target, b"", {}, True, _error_outcome(
-                    400, "Bad header line")
-            key, value = key.strip(), value.strip()
-            headers[key] = value
-            lk = key.lower()
-            if lk == "content-length":
-                try:
-                    length = int(value)
-                except ValueError:
-                    return method, target, b"", {}, True, _error_outcome(
-                        400, "Bad Content-Length")
-            elif lk == "connection":
-                v = value.lower()
-                close_after = (v == "close" if version != "HTTP/1.0"
-                               else v != "keep-alive")
-        if method not in _METHODS:
-            # the threaded handler only implements do_GET/POST/PUT/DELETE
-            return method, target, b"", {}, True, _error_outcome(
-                501, f"Unsupported method ({method!r})")
-        body = await reader.readexactly(length) if length else b""
-        return method, target, body, headers, close_after, None
 
 
 # ---------------------------------------------------------------------------
@@ -652,8 +773,7 @@ def make_server(api, host: str = "localhost", port: int = 0,
         from predictionio_tpu.common.server_security import maybe_wrap_ssl
         scheme = maybe_wrap_ssl(server)
         if scheme == "https":
-            logging.getLogger("predictionio_tpu.http").info(
-                "TLS enabled (PIO_SSL_CERTFILE)")
+            _log.info("TLS enabled (PIO_SSL_CERTFILE)")
     return server
 
 

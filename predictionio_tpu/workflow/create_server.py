@@ -36,6 +36,7 @@ from predictionio_tpu.common import (
 )
 from predictionio_tpu.controller.engine import Engine, EngineParams
 from predictionio_tpu.controller.persistent_model import PersistentModelManifest
+from predictionio_tpu.data.api import http as http_transport
 from predictionio_tpu.data.event import (
     format_event_time, tree_has_non_finite, utcnow,
 )
@@ -1138,6 +1139,7 @@ class QueryAPI:
         out["batching"] = ({"enabled": True, **batcher.stats()}
                            if batcher is not None else {"enabled": False})
         out["codec"] = _codec_status()
+        out["transport"] = http_transport.transport_status()
         for m in self.models:
             # an engine's own block, only where that engine is deployed
             # (models/ecommerce: "ecomm", its rule reads and fallbacks)
@@ -1212,6 +1214,7 @@ class QueryAPI:
             "hbmHardCapMb": self.registry.hard_cap_mb,
             "oversubscribed": self.registry.oversubscribed(),
             "codec": _codec_status(),
+            "transport": http_transport.transport_status(),
         }
 
     def _readyz(self) -> Response:
@@ -1596,20 +1599,17 @@ def serve(api: QueryAPI, host: str = "localhost", port: int = 8000,
     event loop that lifted ingest throughput serves /queries.json
     concurrency — and both transports expose the identical lifecycle
     used below."""
-    from predictionio_tpu.data.api.http import (
-        install_sigterm_handler, make_server,
-    )
     server = None
     for attempt in range(bind_retries):
         try:
-            server = make_server(api, host, port)
+            server = http_transport.make_server(api, host, port)
             break
         except OSError:
             if attempt == bind_retries - 1:
                 raise
             logger.warning("Bind failed; retrying in 1s...")
             time.sleep(1)
-    install_sigterm_handler(api.drain)
+    http_transport.install_sigterm_handler(api.drain)
     threading.Thread(target=server.serve_forever, daemon=True).start()
     logger.info("Engine server online at http://%s:%s", host, port)
     try:

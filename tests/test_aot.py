@@ -566,7 +566,8 @@ def test_pio_aot_0_wire_byte_identical(memory_storage, monkeypatch):
     assert set(info_off) == {
         "status", "engineInstance", "algorithms", "requestCount",
         "avgServingSec", "lastServingSec", "degradedCount", "draining",
-        "serverStartTime", "generation", "batching", "codec"}
+        "serverStartTime", "generation", "batching", "codec",
+        "transport"}
     assert not devicewatch.serving_warmup_done()
     _, rz_off = api_off.handle("GET", "/readyz")
     assert "aotPrograms" not in rz_off

@@ -716,3 +716,100 @@ def test_codec_counters_on_status_and_metrics(trained):
             assert line in text.splitlines(), line
     finally:
         api.close()
+
+
+# the transport under the query server (data/api/http.py: one head parse
+# and one write a reply), counted beside the codec
+# ---------------------------------------------------------------------------
+
+_TRANSPORT_KEYS = {"mode", "requests", "writes", "protocolErrors"}
+
+
+@pytest.mark.parametrize("transport", ["threaded", "async"])
+def test_transport_counters_on_status_and_metrics(trained, monkeypatch,
+                                                  transport):
+    """`GET /` `transport`: one write a reply, and the names /metrics
+    carries are declared."""
+    import http.client
+
+    from predictionio_tpu.common import declarations
+    storage, _app_id, _iid = trained
+    monkeypatch.setenv("PIO_TRANSPORT", transport)
+    api = QueryAPI(storage=storage)
+    server, port = serve_background(api)
+    try:
+        before = api.handle("GET", "/")[1]["transport"]
+        assert set(before) == _TRANSPORT_KEYS
+        assert before["mode"] == transport
+        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=30)
+        for k in range(100):
+            conn.request("POST", "/queries.json",
+                         json.dumps({"user": f"u{k % 8}", "num": 1 + k % 5}))
+            resp = conn.getresponse()
+            assert resp.status == 200 and resp.read()
+        conn.request("GET", "/")
+        over_http = json.loads(conn.getresponse().read())["transport"]
+        conn.close()
+        # its own reply is counted as it goes out, after the page is made
+        assert over_http["requests"] - before["requests"] == 100
+        after = api.handle("GET", "/")[1]["transport"]
+        assert after["requests"] - before["requests"] == 101
+        assert after["writes"] - before["writes"] == 101
+        assert after["protocolErrors"] == before["protocolErrors"]
+        text = api.handle("GET", "/metrics")[1]
+        for name, value in (("pio_transport_requests_total", "requests"),
+                            ("pio_transport_writes_total", "writes")):
+            assert name in declarations.METRICS
+            assert f"{name} {after[value]}" in text.splitlines()
+        assert "pio_transport_protocol_errors_total" in declarations.METRICS
+    finally:
+        server.shutdown()
+        api.close()
+
+
+@pytest.mark.parametrize("transport", ["threaded", "async"])
+def test_transport_protocol_errors_counted_by_code(trained, transport):
+    import socket
+    storage, _app_id, _iid = trained
+    api = QueryAPI(storage=storage)
+    server, port = serve_background(api, transport=transport)
+
+    def by_code():
+        text = api.handle("GET", "/metrics")[1]
+        return {c: sum(
+            float(line.rsplit(" ", 1)[1]) for line in text.splitlines()
+            if line.startswith(
+                f'pio_transport_protocol_errors_total{{code="{c}"}}'))
+            for c in ("400", "501")}
+
+    try:
+        info0 = api.handle("GET", "/")[1]["transport"]
+        codes0 = by_code()
+        for request, status in (
+                (b"POST /queries.json HTTP/1.1\r\nno colon here\r\n\r\n",
+                 b"HTTP/1.1 400 Bad header line\r\n"),
+                (b"POST /queries.json HTTP/1.1\r\nContent-Length: x\r\n\r\n",
+                 b"HTTP/1.1 400 Bad Content-Length\r\n"),
+                (b"PATCH /queries.json HTTP/1.1\r\n\r\n",
+                 b"HTTP/1.1 501 Unsupported method ('PATCH')\r\n")):
+            sock = socket.create_connection(("127.0.0.1", port), timeout=30)
+            sock.sendall(request)
+            reply = b""
+            while True:       # an error reply ends the connection
+                got = sock.recv(65536)
+                if not got:
+                    break
+                reply += got
+            sock.close()
+            assert reply.startswith(status), reply[:80]
+        codes = by_code()
+        assert codes["400"] - codes0["400"] == 2
+        assert codes["501"] - codes0["501"] == 1
+        info = api.handle("GET", "/")[1]["transport"]
+        assert info["protocolErrors"] - info0["protocolErrors"] == 3
+        # a refusal is a request answered in one write too
+        assert info["requests"] - info0["requests"] == 3
+        assert info["writes"] - info0["writes"] == 3
+    finally:
+        server.shutdown()
+        api.close()

@@ -354,6 +354,8 @@ class TestMultiTenantDeploy:
                 assert "queueDepth" in block and "modelBytes" in block
             assert info["modelBytesTotal"] == sum(
                 t["modelBytes"] for t in info["tenants"].values())
+            assert set(info["transport"]) == {
+                "mode", "requests", "writes", "protocolErrors"}
             status, ready = api.handle("GET", "/readyz")
             assert status == 200 and ready["status"] == "ready"
             assert ready["generations"] == {"a": 1, "b": 1, "c": 1}
@@ -541,7 +543,7 @@ def test_legacy_wire_shape_without_engines_conf(mt_trained):
             "status", "engineInstance", "algorithms", "requestCount",
             "avgServingSec", "lastServingSec", "degradedCount",
             "draining", "serverStartTime", "generation", "batching",
-            "aot", "codec"}
+            "aot", "codec", "transport"}
         status, ready = api.handle("GET", "/readyz")
         assert status == 200
         assert "generations" not in ready and "queueDepths" not in ready

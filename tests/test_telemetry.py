@@ -744,7 +744,7 @@ def test_responses_byte_identical_with_telemetry_on_and_off(memory_storage):
             "status", "engineInstance", "algorithms", "requestCount",
             "avgServingSec", "lastServingSec", "degradedCount", "draining",
             "serverStartTime", "generation", "batching", "aot",
-            "codec"}
+            "codec", "transport"}
     finally:
         telemetry.set_enabled(None)
         api.close()
