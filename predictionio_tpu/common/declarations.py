@@ -516,6 +516,23 @@ METRICS: Dict[str, str] = {
     "pio_ecomm_exclude_width_flushes_total":
         "device flushes by the declared exclusion width they were "
         "padded to",
+    "pio_simprod_queries_total":
+        "similar-product queries answered, by any layout "
+        "(models/similarproduct/als_algorithm.py)",
+    "pio_simprod_query_items_total":
+        "item names the queries carried in `items`",
+    "pio_simprod_unknown_items_total":
+        "query items dropped: unknown to the model or untrained",
+    "pio_simprod_excluded_items_total":
+        "item indices (the query's own items + its black list) handed "
+        "to the device program as exclusions",
+    "pio_simprod_host_fallbacks_total":
+        "queries answered by the host kernels while a device layout is "
+        "deployed (whiteList, more query items than the declared width, "
+        "exclusion list past the largest declared width)",
+    "pio_simprod_exclude_width_flushes_total":
+        "similar-product device flushes by the declared exclusion width "
+        "they were padded to",
     # ----------------------------------------------------------------- AOT
     "pio_aot_programs_total": "AOT program builds by status",
     "pio_aot_prebuild_seconds": "AOT prebuild wall time",

@@ -34,7 +34,7 @@ from predictionio_tpu.controller import (DataSource as BaseDataSource,
 from predictionio_tpu.controller.base import Algorithm
 from predictionio_tpu.data import store
 from predictionio_tpu.data.bimap import BiMap
-from predictionio_tpu.models.similarproduct.als_algorithm import (
+from predictionio_tpu.models.item_rules import (
     build_category_masks, candidate_mask)
 from predictionio_tpu.ops import als
 from predictionio_tpu.ops.topk import host_topk

@@ -41,9 +41,13 @@ from predictionio_tpu.models.ecommerce.data_source import TrainingData
 from predictionio_tpu.models.ecommerce.engine import (
     Item, ItemScore, PredictedResult, Query,
 )
+from predictionio_tpu.models import item_rules
+from predictionio_tpu.models.item_rules import (
+    build_category_masks, candidate_mask, category_words,
+)
 from predictionio_tpu.ops import als, topk
 from predictionio_tpu.serving.protocol import (
-    bucket_for, device_rows, host_serves_faster,
+    bucket_for, device_layout_or_host, device_rows,
 )
 
 logger = logging.getLogger("predictionio_tpu.ecommerce")
@@ -94,34 +98,6 @@ def stats() -> Dict[str, Any]:
             str(w): int(_M_WIDTH_FLUSHES.labels(width=str(w)).value)
             for w in topk.EXCLUDE_WIDTHS},
     }
-
-
-def category_words(items: Dict[int, Item], n_items: int,
-                   category_masks: Optional[Dict[str, np.ndarray]] = None
-                   ) -> Tuple[Dict[str, Tuple[int, "np.uint32"]],
-                              np.ndarray]:
-    """Every item's categories as bits, for ops/topk.py
-    masked_topk_rows: -> (category -> (word, bit mask), (w, n_items)
-    uint32). Bit 0 of word 0 is set on every item (topk.RULE_ANY_BIT:
-    what a query with no categories asks for, so that an item with no
-    category still answers it); category j, in name order, is bit
-    j + 1. ``category_masks`` (similarproduct build_category_masks of
-    the same items) is used where the model carries it."""
-    from predictionio_tpu.models.similarproduct.als_algorithm import (
-        build_category_masks,
-    )
-    if category_masks is None:
-        category_masks = build_category_masks(items, n_items)
-    names = sorted(category_masks)
-    words = np.zeros((-(-(len(names) + 1) // 32), n_items), np.uint32)
-    words[0] = topk.RULE_ANY_BIT
-    bits: Dict[str, Tuple[int, np.uint32]] = {}
-    for j, name in enumerate(names):
-        word, bit = divmod(j + 1, 32)
-        bits[name] = (word, np.uint32(1 << bit))
-        words[word] |= np.where(np.asarray(category_masks[name]),
-                                bits[name][1], np.uint32(0))
-    return bits, words
 
 
 @dataclass(frozen=True)
@@ -196,22 +172,18 @@ class ECommModel:
 
 
 @dataclass
-class RuleDevice:
-    """What the device holds of a deployed ECommModel: both factor
-    matrices, the items' category bits, and the eligibility array of the
-    constraint as last read (``constraint_key`` says which $set)."""
-    user_factors: Any            # (n_users, r) float32, device
-    item_factors: Any            # (n_items, r) float32, device
-    rule_words: Any              # (w, n_items) uint32, device
-    category_bits: Dict[str, Tuple[int, "np.uint32"]]
-    eligible: Any                # (n_items,) bool, device
+class RuleDevice(item_rules.RuleDevice):
+    """What the device holds of a deployed ECommModel: the rule arrays
+    every device layout with rules has (models/item_rules.py: item
+    factors, the items' category bits, the eligibility array, here of
+    the constraint as last read: ``constraint_key`` says which $set)
+    and the user factors a flush's rows are gathered from."""
+    user_factors: Any = None     # (n_users, r) float32, device
     constraint_key: Any = None
     lock: Any = dataclasses.field(default_factory=threading.Lock)
 
-    def nbytes(self) -> int:
-        return int(sum(a.nbytes for a in (
-            self.user_factors, self.item_factors, self.rule_words,
-            self.eligible)))
+    def rule_arrays(self):
+        return (self.user_factors, *super().rule_arrays())
 
     def topk(self, eligible, want, exclude):
         """device_rows' ``topk_fn`` for one flush's rule arguments."""
@@ -246,13 +218,6 @@ def _place(model: ECommModel) -> RuleDevice:
             np.asarray(model.product_features, np.float32)),
         rule_words=jax.device_put(words), category_bits=bits,
         eligible=_place_eligible(model, ()))
-
-
-def _rule_arguments(dev: RuleDevice, bucket: int, longest: int):
-    """topk.blank_rule_arguments at this layout's shapes."""
-    return topk.blank_rule_arguments(
-        bucket, int(dev.rule_words.shape[0]), longest,
-        int(dev.item_factors.shape[0]))
 
 
 class ECommAlgorithm(Algorithm):
@@ -305,9 +270,6 @@ class ECommAlgorithm(Algorithm):
         item_trained = np.zeros(len(item_vocab), dtype=bool)
         item_trained[np.unique(i_idx)] = True
         items = {item_vocab(k): v for k, v in data.items.items()}
-        from predictionio_tpu.models.similarproduct.als_algorithm import (
-            build_category_masks,
-        )
         V = np.asarray(V)
         V_hat = V / np.maximum(
             np.linalg.norm(V, axis=1, keepdims=True), 1e-12)
@@ -432,36 +394,22 @@ class ECommAlgorithm(Algorithm):
 
     # ------------------------------------------------------ serving layout
     def prepare_serving(self, model: ECommModel) -> ECommModel:
-        """On an accelerator: the device layout, always (module
-        docstring), and a layout that fails RAISES, as the
-        recommendation engine's does: a deploy that quietly served some
-        other way would pass every check without its layout ever having
-        reached the chip. On the CPU backend, where a tiny model serves
-        faster from host BLAS than through a dispatch, a real bucket-1
-        query is timed and the host layout kept when it is slow
-        (serving/protocol.py host_serves_faster: the recommendation
-        engine's rule). With ``weightedItems`` the host layout stays: the device
-        program takes no weights."""
-        import jax
-
+        """On an accelerator the device layout (module docstring),
+        always; on the CPU backend by the probe every rule engine uses
+        (serving/protocol.py device_layout_or_host). With
+        ``weightedItems`` the host layout stays: the device program
+        takes no weights."""
         if self.ap.weightedItems:
             logger.info("weightedItems is on: serving from host arrays")
             return dataclasses.replace(model, device=None)
-        on_chip = jax.default_backend() != "cpu"
-        try:
-            dev = _place(model)
-            if not on_chip:
-                run = dev.topk(dev.eligible, *_rule_arguments(dev, 1, 0))
-                ix, k = np.zeros(1, np.int32), min(10, len(model.item_vocab))
-                if host_serves_faster(lambda: run(ix, k), logger):
-                    dev = None
-        except Exception:
-            if on_chip:
-                raise
-            logger.exception("device serving layout failed; serving from "
-                             "host arrays")
-            dev = None
-        return dataclasses.replace(model, device=dev)
+
+        def probe(dev):
+            run = dev.topk(dev.eligible, *dev.rule_arguments(1, 0))
+            ix, k = np.zeros(1, np.int32), min(10, len(model.item_vocab))
+            return lambda: run(ix, k)
+
+        return dataclasses.replace(model, device=device_layout_or_host(
+            lambda: _place(model), probe, logger))
 
     def aot_serving_programs(self, model: ECommModel, buckets,
                              declared: bool = False):
@@ -520,16 +468,12 @@ class ECommAlgorithm(Algorithm):
             if rows:
                 with waterfall.stage("rules.constraint"):
                     eligible = self._eligible(model, dev)
-                want, exclude = _rule_arguments(
-                    dev, bucket_for(len(rows)),
+                want, exclude = dev.rule_arguments(
+                    bucket_for(len(rows)),
                     max(len(gone) for *_, gone in rows))
                 for r, (_qx, query, _ix, gone) in enumerate(rows):
-                    if query.categories is not None:
-                        want[r] = 0
-                        for name in query.categories:
-                            word, bit = dev.category_bits.get(name, (0, 0))
-                            want[r, word] |= bit
-                    exclude[r, :len(gone)] = list(gone)
+                    dev.fill_rule_row(want, exclude, r, query.categories,
+                                      gone)
                 _M_EXCLUDED.inc(sum(len(gone) for *_, gone in rows))
                 _M_WIDTH_FLUSHES.labels(
                     width=str(exclude.shape[1])).inc()
@@ -564,9 +508,6 @@ class ECommAlgorithm(Algorithm):
         unavailable items, recent views for unknown users) stay per query
         in both paths. Returns (query_vec, use_hat, mask) or None for the
         empty-result paths."""
-        from predictionio_tpu.models.similarproduct.als_algorithm import (
-            candidate_mask,
-        )
         white = None
         if query.whiteList is not None:
             white = {model.item_vocab.get(x) for x in query.whiteList}

@@ -1,4 +1,4 @@
-"""ALSAlgorithm: implicit ALS item vectors + fused cosine top-K on device.
+"""ALSAlgorithm: implicit ALS item vectors + summed-cosine top-K.
 
 Parity: scala-parallel-similarproduct/multi/src/main/scala/
 ALSAlgorithm.scala (train :57-120, predict :122-160, cosine :214-231,
@@ -6,44 +6,99 @@ isCandidateItem :233+) and LikeAlgorithm.scala (like/dislike ratings,
 latest event wins). The per-item RDD lookup + driver-side cosine loop
 becomes one matmul: sum of cosines against Q query vectors equals
 (V_hat @ sum(q_hat)) where hats are L2-normalized rows.
+
+Serving layouts (``prepare_serving``): on an accelerator the normalized
+item factors, one word array of category bits an item and one
+eligibility array (trained) live on the device, and a flush is ONE
+dispatch of ops/topk.py itemset_topk_rows: a query sends the indices of
+its items (padded to ``topk.QUERY_WIDTH``), a row of category bits and a
+short list of excluded item indices (its own items + its black list),
+never a mask as long as the catalog. A query the device program has no
+argument for (whiteList, more items than the declared width, an
+exclusion list past ``topk.EXCLUDE_WIDTHS``) is answered by the host
+layout's code and counted (``hostFallbacks``); the host copy of the
+factors stays for it (KNOWN_ISSUES.md). On the CPU backend a tiny model
+keeps the host layout: one BLAS product + argpartition.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import logging
+import math
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from predictionio_tpu.common import telemetry, waterfall
 from predictionio_tpu.controller import Algorithm, Params
 from predictionio_tpu.data.bimap import BiMap
+from predictionio_tpu.models import item_rules
+from predictionio_tpu.models.item_rules import (   # noqa: F401  (their
+    build_category_masks, candidate_mask,          # old home: importers)
+)
 from predictionio_tpu.models.similarproduct.data_source import TrainingData
 from predictionio_tpu.models.similarproduct.engine import (
-    Item, ItemScore, PredictedResult, Query,
+    ItemScore, PredictedResult, Query,
 )
 from predictionio_tpu.ops import als, topk
+from predictionio_tpu.serving.protocol import (
+    bucket_for, device_layout_or_host, device_rows,
+)
 
 logger = logging.getLogger("predictionio_tpu.similarproduct")
 
+_REG = telemetry.registry()
+_M_QUERIES = _REG.counter(
+    "pio_simprod_queries_total",
+    "Similar-product queries answered, by any layout").child()
+_M_QUERY_ITEMS = _REG.counter(
+    "pio_simprod_query_items_total",
+    "Item names the queries carried in `items`").child()
+_M_UNKNOWN_ITEMS = _REG.counter(
+    "pio_simprod_unknown_items_total",
+    "Query items dropped: unknown to the model or untrained").child()
+_M_EXCLUDED = _REG.counter(
+    "pio_simprod_excluded_items_total",
+    "Item indices (the query's own items + its black list) handed to "
+    "the device program as exclusions").child()
+_M_HOST_FALLBACKS = _REG.counter(
+    "pio_simprod_host_fallbacks_total",
+    "Queries answered by the host kernels while a device layout is "
+    "deployed (whiteList, more query items than the declared width, "
+    "exclusion list past the largest declared width)").child()
+_M_WIDTH_FLUSHES = _REG.counter(
+    "pio_simprod_exclude_width_flushes_total",
+    "Device flushes by the declared exclusion width they were padded "
+    "to", labelnames=("width",))
 
-def topk_to_result(model, query_vec, mask: "np.ndarray",
-                   num: int) -> PredictedResult:
-    """Masked host top-K -> PredictedResult, dropping scores <= 0
-    (the reference keeps only positive scores, ALSAlgorithm.scala:167).
-    Host numpy serving: the factors live in host RAM after training, and
-    one BLAS matvec + argpartition beat per-query device dispatch on the
-    early rounds' remote device by orders of magnitude (273 ms -> <1 ms
-    p50 there; not measured on the attached chip)."""
-    if not mask.any():
-        return PredictedResult(())
-    k = min(num, mask.shape[0])
-    vals, idx = topk.host_masked_topk(model.product_features, query_vec,
-                                      mask, k)
+
+def stats() -> Dict[str, Any]:
+    """`GET /`'s ``simprod`` block: the process-wide counters /metrics
+    has as ``pio_simprod_*``, and the widths the device program is
+    compiled for."""
+    return {
+        "queries": int(_M_QUERIES.value),
+        "queryItems": int(_M_QUERY_ITEMS.value),
+        "unknownItems": int(_M_UNKNOWN_ITEMS.value),
+        "excludedItems": int(_M_EXCLUDED.value),
+        "hostFallbacks": int(_M_HOST_FALLBACKS.value),
+        "queryWidth": topk.QUERY_WIDTH,
+        "excludeWidths": {
+            str(w): int(_M_WIDTH_FLUSHES.labels(width=str(w)).value)
+            for w in topk.EXCLUDE_WIDTHS},
+    }
+
+
+def _result(model, vals, idx) -> PredictedResult:
+    """(score, index) rows -> PredictedResult, dropping scores <= 0
+    (the reference keeps only positive scores, ALSAlgorithm.scala:167)
+    and non-finite ones (``topk.NEG_INF``: no candidate left)."""
     inv = model.item_vocab.inverse()
     return PredictedResult(tuple(
         ItemScore(item=inv(int(ix)), score=float(s))
-        for s, ix in zip(vals, idx) if s > 0 and np.isfinite(s)))
+        for s, ix in zip(vals, idx) if 0 < s < math.inf))
 
 
 @dataclass(frozen=True)
@@ -58,58 +113,90 @@ class ALSAlgorithmParams(Params):
 
 @dataclass
 class ALSModel:
-    """productFeatures + itemStringIntMap + items (ALSModel,
-    ALSAlgorithm.scala:31-55). `trained_mask` excludes items with no
+    """productFeatures + itemStringIntMap + the items' categories
+    (ALSModel, ALSAlgorithm.scala:31-55). `product_features` holds UNIT
+    rows (train normalizes once). `trained_mask` excludes items with no
     interactions — the analogue of ids absent from MLlib's
-    productFeatures RDD. `category_masks` indexes items by category so
-    query-time filters are boolean vector ops, not per-item Python."""
-    product_features: "np.ndarray"      # (n_items, rank)
+    productFeatures RDD. Upstream's `items: Map[Int, Item]` is carried
+    as arrays: `rule_words` + `category_bits` (models/item_rules.py
+    category_words), one word of category bits an item whatever the
+    catalog's length. The device layout keeps the words resident; the
+    host code reads a query's category vector off them."""
+    product_features: "np.ndarray"      # (n_items, rank), unit rows
     item_vocab: BiMap
-    items: Dict[int, Item]              # int index -> Item
     trained_mask: "np.ndarray"          # (n_items,) bool
-    category_masks: Dict[str, "np.ndarray"] = None
+    rule_words: "np.ndarray"            # (w, n_items) uint32
+    category_bits: item_rules.CategoryBits
+    #: serve-time-only state (ItemSetDevice) when prepare_serving chose
+    #: the device layout; never persisted (train's output has None, and
+    #: a pickle from before the field lacks it: read with getattr)
+    device: Optional["ItemSetDevice"] = None
+
+    def __setstate__(self, state: Dict[str, Any]) -> None:
+        """A pickle from before the categories were words carried one
+        Item an index (`items`) and one boolean vector a category
+        (`category_masks`): made into words once, here at load."""
+        if "rule_words" not in state:
+            state = dict(state)
+            state["category_bits"], state["rule_words"] = \
+                item_rules.category_words(
+                    state.pop("items"), len(state["item_vocab"]),
+                    state.pop("category_masks", None))
+        self.__dict__.update(state)
 
     def __str__(self) -> str:
-        return (f"ALSModel(productFeatures: [{len(self.items)}], "
+        return (f"ALSModel(productFeatures: [{len(self.item_vocab)}], "
                 f"itemStringIntMap: [{len(self.item_vocab)}])")
 
+    def serving_layout(self) -> Dict[str, Any]:
+        """`GET /` ``batching``: where the arrays that answer the
+        flushes live."""
+        dev = getattr(self, "device", None)
+        if dev is None:
+            return {"layout": "host", "shards": 0, "perShardBytes": 0}
+        return {"layout": "items+rules", "shards": 1,
+                "perShardBytes": dev.nbytes(),
+                "queryWidth": topk.QUERY_WIDTH,
+                "excludeWidths": list(topk.EXCLUDE_WIDTHS)}
 
-def build_category_masks(items: Dict[int, Item],
-                         n_items: int) -> Dict[str, np.ndarray]:
-    masks: Dict[str, np.ndarray] = {}
-    for ix, item in items.items():
-        for cat in item.categories or ():
-            masks.setdefault(cat, np.zeros(n_items, dtype=bool))[ix] = True
-    return masks
+    def topk_rows(self) -> Optional[int]:
+        """The score row the deployed device programs select from."""
+        dev = getattr(self, "device", None)
+        return None if dev is None else dev.n_items
+
+    def hbm_bytes(self) -> int:
+        """serving/registry.py model_hbm_bytes: what the device holds."""
+        dev = getattr(self, "device", None)
+        return 0 if dev is None else dev.nbytes()
+
+    def status_block(self) -> Tuple[str, Dict[str, Any]]:
+        return "simprod", stats()
 
 
-def candidate_mask(n_items: int,
-                   trained: np.ndarray,
-                   category_masks: Dict[str, np.ndarray],
-                   categories,
-                   white: Optional[set],
-                   black: set,
-                   exclude: set) -> np.ndarray:
-    """isCandidateItem as one boolean vector (ALSAlgorithm.scala:233+).
+@dataclass
+class ItemSetDevice(item_rules.RuleDevice):
+    """What the device holds of a deployed ALSModel: the rule arrays of
+    models/item_rules.py RuleDevice, `item_factors` the UNIT rows a
+    flush's query items are gathered from and scored against."""
 
-    Inputs are host numpy after train/load (deploy no longer device_puts
-    pushes every numeric leaf); the mask is host-side scratch, so coerce.
-    """
-    mask = np.array(trained, dtype=bool)
-    if categories is not None:
-        cat_mask = np.zeros(n_items, dtype=bool)
-        for c in categories:
-            m = category_masks.get(c)
-            if m is not None:
-                cat_mask |= np.asarray(m)
-        mask &= cat_mask
-    if white is not None:
-        white_mask = np.zeros(n_items, dtype=bool)
-        white_mask[sorted(white)] = True
-        mask &= white_mask
-    for ix in black | exclude:
-        mask[ix] = False
-    return mask
+    def topk(self, want, exclude):
+        """device_rows' ``topk_fn`` for one flush's rule arguments."""
+        return lambda query_ixs, k: topk.itemset_topk_rows(
+            self.item_factors, self.rule_words, self.eligible,
+            query_ixs, want, exclude, k=k)
+
+
+def _place(model: ALSModel) -> ItemSetDevice:
+    """The device layout of ``model``."""
+    import jax
+
+    return ItemSetDevice(
+        item_factors=jax.device_put(
+            np.asarray(model.product_features, np.float32)),
+        rule_words=jax.device_put(
+            np.asarray(model.rule_words, np.uint32)),
+        category_bits=model.category_bits,
+        eligible=jax.device_put(np.array(model.trained_mask, dtype=bool)))
 
 
 class ALSAlgorithm(Algorithm):
@@ -165,64 +252,183 @@ class ALSAlgorithm(Algorithm):
             lambda_=self.ap.lambda_, alpha=1.0, seed=int(seed))
         trained = np.zeros(len(item_vocab), dtype=bool)
         trained[np.unique(i_idx)] = True
-        items = {item_vocab(k): v for k, v in data.items.items()}
+        bits, words = item_rules.category_words(
+            {item_vocab(k): v for k, v in data.items.items()},
+            len(item_vocab))
         # pre-normalize once: sum-of-cosines per item is then one matvec
         V = np.asarray(V)
         V_hat = V / np.maximum(
             np.linalg.norm(V, axis=1, keepdims=True), 1e-12)
         return ALSModel(product_features=V_hat, item_vocab=item_vocab,
-                        items=items, trained_mask=trained,
-                        category_masks=build_category_masks(
-                            items, len(item_vocab)))
+                        trained_mask=trained, rule_words=words,
+                        category_bits=bits)
+
+    # ------------------------------------------------------ serving layout
+    def prepare_serving(self, model: ALSModel) -> ALSModel:
+        """On an accelerator the device layout (module docstring),
+        always; on the CPU backend by the probe every rule engine uses
+        (serving/protocol.py device_layout_or_host)."""
+        def probe(dev):
+            run = dev.topk(*dev.rule_arguments(1, 0))
+            ixs = np.full((1, topk.QUERY_WIDTH), dev.n_items, np.int32)
+            ixs[0, 0] = 0
+            return lambda: run(ixs, min(10, dev.n_items))
+
+        return dataclasses.replace(model, device=device_layout_or_host(
+            lambda: _place(model), probe, logger))
+
+    def aot_serving_programs(self, model: ALSModel, buckets,
+                             declared: bool = False):
+        """This model's device programs from declared shapes
+        (serving/aot.py): itemset_topk_rows per (bucket, exclusion
+        width, k), bucket 1 always among them for ``predict``. Nothing
+        on the host layout; ``declared=True`` (the `pio train`
+        cache-artifact export) enumerates regardless."""
+        from predictionio_tpu.serving import aot
+
+        dev = getattr(model, "device", None)
+        if dev is None and not declared:
+            return ()
+        n_items, rank = (int(d) for d in np.shape(model.product_features))
+        return aot.specs_itemset_topk_rows(
+            n_items, rank, int(np.shape(model.rule_words)[0]),
+            sorted({1, *buckets}),
+            aot.serving_ks(n_items), device=dev)
 
     # ------------------------------------------------------------ serving
-    def _plan(self, model: ALSModel, query: Query):
-        """Per-query host prep shared by predict and predict_batch: encode
-        the query items, build the sum-of-normalized-vectors query vector
-        and the candidate mask. None when no query item has a trained
-        vector (the reference's empty-result path)."""
-        query_ixs = {model.item_vocab.get(i) for i in query.items}
-        query_ixs.discard(None)
-        query_ixs = {ix for ix in query_ixs if model.trained_mask[ix]}
-        if not query_ixs:
+    def _query_ixs(self, model: ALSModel, query: Query,
+                   count: bool = True) -> List[int]:
+        """The query's items the model has a vector for, as sorted
+        distinct indices (upstream's queryList is a Set): unknown and
+        untrained ones dropped and, unless the device layout counted
+        them before it handed the query to the host code, counted."""
+        ixs = [model.item_vocab.get(i) for i in query.items]
+        known = sorted({ix for ix in ixs
+                        if ix is not None and model.trained_mask[ix]})
+        if count:
+            _M_QUERY_ITEMS.inc(len(ixs))
+            _M_UNKNOWN_ITEMS.inc(sum(
+                1 for ix in ixs
+                if ix is None or not model.trained_mask[ix]))
+        if not known:
             logger.info("No productFeatures vector for query items %s.",
                         query.items)
+        return known
+
+    def _plan(self, model: ALSModel, query: Query, count: bool = True):
+        """Per-query prep of the host layout: encode the query items,
+        build the sum-of-normalized-vectors query vector and the
+        candidate mask. None when no query item has a trained vector
+        (the reference's empty-result path)."""
+        query_ixs = self._query_ixs(model, query, count)
+        if not query_ixs:
             return None
         V_hat = np.asarray(model.product_features)
-        q = np.sum(V_hat[sorted(query_ixs)], axis=0)
+        q = np.sum(V_hat[query_ixs], axis=0)
         mask = candidate_mask(
             n_items=len(model.item_vocab),
             trained=model.trained_mask,
-            category_masks=model.category_masks or {},
-            categories=query.categories,
+            category_masks={}, categories=None,
             white=self._encode_set(model, query.whiteList),
             black=self._encode_set(model, query.blackList) or set(),
-            exclude=query_ixs,
+            exclude=set(query_ixs),
         )
+        if query.categories is not None:
+            mask &= item_rules.category_mask_of_words(
+                model.category_bits, model.rule_words, query.categories)
         return q, mask
 
     def predict(self, model: ALSModel, query: Query) -> PredictedResult:
-        """Sum-of-cosines against the query items' vectors, filtered and
-        top-K'd on device (replaces the reference's driver-side
+        """Sum-of-cosines against the query items' vectors, filtered
+        and top-K'd (replaces the reference's driver-side
         productFeatures scan, ALSAlgorithm.scala:122-212): with rows
-        pre-normalized, sum_q cos(q, v) == V_hat @ sum(q_hat)."""
-        plan = self._plan(model, query)
-        if plan is None:
-            return PredictedResult(())
-        q, mask = plan
-        return topk_to_result(model, q, mask, query.num)
+        pre-normalized, sum_q cos(q, v) == V_hat @ sum(q_hat). A flush
+        of one, on the bucket-1 program where the device layout is
+        deployed."""
+        return self.predict_batch(model, [query])[0]
 
     def predict_batch(self, model: ALSModel,
                       queries) -> List[PredictedResult]:
-        """Serving micro-batch: the per-query matvec becomes ONE
-        (B, rank) @ (rank, n_items) BLAS matmul over the stacked query
-        vectors; masking/top-K/positive-score filtering stay per row,
-        identical to predict()'s pipeline."""
+        """Serving micro-batch, by the layout prepare_serving chose."""
         queries = list(queries)
+        _M_QUERIES.inc(len(queries))
+        dev = getattr(model, "device", None)
+        if dev is not None:
+            return self._predict_batch_device(model, dev, queries)
+        return self._predict_batch_host(model, queries)
+
+    def _predict_batch_device(self, model: ALSModel, dev: ItemSetDevice,
+                              queries) -> List[PredictedResult]:
+        """One flush on the device layout. `rules` (host): item names
+        to indices (`rules.items`), then the flush's arguments: a (b, q)
+        array of query-item indices, a (b, w) array of wanted-category
+        bits and a (b, E) array of excluded indices (the query's own
+        items and its black list), padded to the flush's bucket and to
+        the declared widths. Then the shared device half (`pad`,
+        `execute` > `enqueue`, `device_get`) and `unpack`."""
+        out: List[Optional[PredictedResult]] = [None] * len(queries)
+        n_items = dev.n_items
+        item_ix = model.item_vocab.get
+        host: List[int] = []
+        rows: List[Tuple[int, Query, List[int], set]] = []
+        with waterfall.stage("rules"):
+            with waterfall.stage("rules.items"):
+                for qx, query in enumerate(queries):
+                    ixs = self._query_ixs(model, query)
+                    if not ixs or min(query.num, n_items) <= 0:
+                        out[qx] = PredictedResult(())
+                        continue
+                    gone = {item_ix(x) for x in query.blackList or ()}
+                    gone.discard(None)
+                    gone.update(ixs)
+                    if query.whiteList is not None \
+                            or len(ixs) > topk.QUERY_WIDTH \
+                            or len(gone) > topk.EXCLUDE_WIDTHS[-1]:
+                        host.append(qx)
+                    else:
+                        rows.append((qx, query, ixs, gone))
+            if rows:
+                want, exclude = dev.rule_arguments(
+                    bucket_for(len(rows)),
+                    max(len(gone) for *_, gone in rows))
+                items = np.full((len(rows), topk.QUERY_WIDTH), n_items,
+                                np.int32)
+                for r, (_qx, query, ixs, gone) in enumerate(rows):
+                    items[r, :len(ixs)] = ixs
+                    dev.fill_rule_row(want, exclude, r, query.categories,
+                                      gone)
+                _M_EXCLUDED.inc(sum(len(gone) for *_, gone in rows))
+                _M_WIDTH_FLUSHES.labels(
+                    width=str(exclude.shape[1])).inc()
+        if rows:
+            k = min(max(q.num for _qx, q, _ixs, _g in rows), n_items)
+            vals, idx = device_rows(dev.topk(want, exclude), items, k,
+                                    fill=n_items)
+            waterfall.note("rules", int(exclude.shape[1]))
+            with waterfall.stage("unpack"):
+                for (qx, query, _ixs, _g), rvals, ridx in zip(
+                        rows, vals.tolist(), idx.tolist()):
+                    n = min(query.num, k)
+                    out[qx] = _result(model, rvals[:n], ridx[:n])
+        if host:
+            _M_HOST_FALLBACKS.inc(len(host))
+            answers = self._predict_batch_host(
+                model, [queries[qx] for qx in host], count=False)
+            for qx, res in zip(host, answers):
+                out[qx] = res
+        return out
+
+    def _predict_batch_host(self, model: ALSModel, queries,
+                            count: bool = True) -> List[PredictedResult]:
+        """The host layout: the per-query matvec becomes ONE
+        (B, rank) @ (rank, n_items) BLAS matmul over the stacked query
+        vectors; masking/top-K/positive-score filtering stay per row.
+        ``count=False``: the device layout's fallback, whose query
+        items were counted when it sorted the flush."""
         out: List[Optional[PredictedResult]] = [None] * len(queries)
         plans = []
         for qx, query in enumerate(queries):
-            plan = self._plan(model, query)
+            plan = self._plan(model, query, count)
             if plan is None or not plan[1].any():
                 out[qx] = PredictedResult(())
             else:
@@ -235,11 +441,8 @@ class ALSAlgorithm(Algorithm):
             [m for _qx, _query, (_q, m) in plans],
             [min(query.num, m.shape[0])
              for _qx, query, (_q, m) in plans])
-        inv = model.item_vocab.inverse()
         for (qx, _query, _plan), (vals, idx) in zip(plans, rows):
-            out[qx] = PredictedResult(tuple(
-                ItemScore(item=inv(int(ix)), score=float(s))
-                for s, ix in zip(vals, idx) if s > 0 and np.isfinite(s)))
+            out[qx] = _result(model, vals, idx)
         return out
 
     @staticmethod

@@ -3,7 +3,8 @@
 The serving hot path: replaces `MatrixFactorizationModel.recommendProducts`
 (invoked at tests/pio_tests/engines/recommendation-engine/src/main/scala/
 ALSAlgorithm.scala:95-112) and the cosine-similarity scoring loops of the
-similarproduct/ecommerce templates with one fused matmul + mask + top-k.
+similarproduct/ecommerce templates with one fused matmul + mask + top-k
+(masked_topk_rows: a user a row; itemset_topk_rows: a set of items a row).
 
 The serving kernels select with :func:`stable_topk` (a total order: score
 descending, index ascending), which sorts a whole score row only when it
@@ -421,6 +422,26 @@ def _exclude(scores: jnp.ndarray, exclude_ixs: jnp.ndarray) -> jnp.ndarray:
     return lax.fori_loop(0, b, row, scores)
 
 
+def _rules_and_select(scores: jnp.ndarray, rule_words: jnp.ndarray,
+                      eligible: jnp.ndarray, want_words: jnp.ndarray,
+                      exclude_ixs: jnp.ndarray, k: int
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """`mask` > `exclude` > `select` on a flush's (b, n_items) scores:
+    the stages every program with business rules runs, one function for
+    the e-commerce engine's :func:`masked_topk_rows` and the
+    similar-product engine's :func:`itemset_topk_rows` (what each
+    stage does: masked_topk_rows). Nothing here knows what a row of
+    the flush was gathered from."""
+    with jax.named_scope("mask"):
+        shared = rule_words[None, :, :] & want_words[:, :, None]
+        ok = eligible[None, :] & jnp.any(shared != 0, axis=1)
+        scores = jnp.where(ok, scores, NEG_INF)
+    with jax.named_scope("exclude"):
+        scores = _exclude(scores, exclude_ixs)
+    with jax.named_scope("select"):
+        return stable_topk(scores, k)
+
+
 @partial(jax.jit, static_argnames=("k",))
 def masked_topk_rows(
     user_factors: jnp.ndarray,   # (n_users, r) device-resident
@@ -436,7 +457,7 @@ def masked_topk_rows(
     `select` (the e-commerce template's isCandidateItem, on the device):
     the same gather, the same one fp32 matmul, the same
     :func:`stable_topk` (score descending, ties by lowest index), and
-    between them
+    between them (:func:`_rules_and_select`)
 
     - `mask`: a score becomes ``NEG_INF`` where the item is not
       ``eligible`` (untrained, or on the constraint's unavailable list:
@@ -460,14 +481,61 @@ def masked_topk_rows(
         Q = jnp.take(user_factors, user_ixs, axis=0)
     with jax.named_scope("score"):
         scores = fp32_matmul(Q, item_factors.T)
-    with jax.named_scope("mask"):
-        shared = rule_words[None, :, :] & want_words[:, :, None]
-        ok = eligible[None, :] & jnp.any(shared != 0, axis=1)
-        scores = jnp.where(ok, scores, NEG_INF)
-    with jax.named_scope("exclude"):
-        scores = _exclude(scores, exclude_ixs)
-    with jax.named_scope("select"):
-        return stable_topk(scores, k)
+    return _rules_and_select(scores, rule_words, eligible, want_words,
+                             exclude_ixs, k)
+
+
+#: the declared width q of a query's item list in
+#: :func:`itemset_topk_rows`: a flush pads every row's list to it, so
+#: the programs of a deploy stay (padding bucket x exclusion width x k).
+#: A query that names more items (distinct, known, trained) is not cut:
+#: it is answered on the host (models/similarproduct counts it as a
+#: host fallback; KNOWN_ISSUES.md).
+QUERY_WIDTH = 8
+
+
+@partial(jax.jit, static_argnames=("k",))
+def itemset_topk_rows(
+    item_factors_hat: jnp.ndarray,  # (n_items, r) unit rows, resident
+    rule_words: jnp.ndarray,        # (w, n_items) uint32 device-resident
+    eligible: jnp.ndarray,          # (n_items,) bool device-resident
+    query_ixs: jnp.ndarray,         # (b, q) int32, padded with n_items
+    want_words: jnp.ndarray,        # (b, w) uint32
+    exclude_ixs: jnp.ndarray,       # (b, E) int32, padded with n_items
+    k: int = 10,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """A flush of the similar-product template's queries, each a SET of
+    items: score = the sum over the row's query items of the cosine
+    between their vectors and the candidate's. The rows of
+    ``item_factors_hat`` have unit length (ALSAlgorithm.train stores
+    them so), so that sum is one product of the summed query rows
+    against the same matrix:
+
+    - `gather`: the (b, q) query rows of the resident matrix, an entry
+      of ``n_items`` or more (padding) contributing exactly 0, summed
+      over q left to right: the same bits in every bucket;
+    - `score`: one fp32 matmul against the matrix the rows came from;
+    - `mask` > `exclude` > `select`: :func:`_rules_and_select`, the
+      code :func:`masked_topk_rows` runs. The caller puts the row's own
+      query items on its exclusion list beside its black list: a
+      query's items are never among its answers.
+
+    q is :data:`QUERY_WIDTH`, E one of :data:`EXCLUDE_WIDTHS`. Padding
+    rows: all-padding query items (a zero vector: every score 0, which
+    the caller drops), any bits, all-padding exclusions."""
+    n = item_factors_hat.shape[0]
+    with jax.named_scope("gather"):
+        there = query_ixs < n
+        rows = jnp.take(item_factors_hat, jnp.where(there, query_ixs, 0),
+                        axis=0)                             # (b, q, r)
+        rows = jnp.where(there[:, :, None], rows, 0.0)
+        Q = rows[:, 0]
+        for j in range(1, query_ixs.shape[1]):
+            Q = Q + rows[:, j]
+    with jax.named_scope("score"):
+        scores = fp32_matmul(Q, item_factors_hat.T)
+    return _rules_and_select(scores, rule_words, eligible, want_words,
+                             exclude_ixs, k)
 
 
 def host_masked_topk_batch(factors, query_vecs, masks, ks, weights=None):
@@ -485,19 +553,3 @@ def host_masked_topk_batch(factors, query_vecs, masks, ks, weights=None):
     for row, mask, k in zip(scores, masks, ks):
         out.append(host_topk(np.where(np.asarray(mask), row, -np.inf), k))
     return out
-
-
-@partial(jax.jit, static_argnames=("k",))
-def cosine_topk(
-    query_vec: jnp.ndarray,
-    item_factors: jnp.ndarray,
-    mask: Optional[jnp.ndarray] = None,
-    k: int = 10,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Cosine-similarity top-K (similarproduct template scoring)."""
-    qn = query_vec / jnp.maximum(jnp.linalg.norm(query_vec), 1e-12)
-    norms = jnp.linalg.norm(item_factors, axis=1)
-    scores = fp32_matmul(item_factors, qn) / jnp.maximum(norms, 1e-12)
-    if mask is not None:
-        scores = jnp.where(mask, scores, NEG_INF)
-    return jax.lax.top_k(scores, k)

@@ -335,6 +335,55 @@ def _masked_rows_primer(topk, device, n_words, bucket, width, k):
     return prime
 
 
+def specs_itemset_topk_rows(n_items: int, rank: int, n_words: int,
+                             buckets: Iterable[int], ks: Iterable[int],
+                             device: Any = None) -> List[ProgramSpec]:
+    """The similar-product engine's batched device programs: one per
+    (bucket, exclusion width of ops/topk.py EXCLUDE_WIDTHS, k), every
+    one at the one declared query width (topk.QUERY_WIDTH).
+    ``device`` (models/similarproduct ItemSetDevice: the live resident
+    arrays) attaches prime closures."""
+    from predictionio_tpu.ops import topk
+    out = []
+    for b in buckets:
+        for width in topk.EXCLUDE_WIDTHS:
+            for k in ks:
+                shape = (n_items, rank, n_words, int(b), int(width), int(k))
+                out.append(ProgramSpec(
+                    name="itemset_topk_rows",
+                    key=("itemset_topk_rows", topk.QUERY_WIDTH, *shape),
+                    lower=_itemset_rows_lowerer(topk, *shape),
+                    prime=(_itemset_rows_primer(topk, device, *shape[3:])
+                           if device is not None else None)))
+    return out
+
+
+def _itemset_rows_lowerer(topk, n_items, rank, n_words, bucket, width, k):
+    def lower():
+        import jax
+        import numpy as np
+        return topk.itemset_topk_rows.lower(
+            jax.ShapeDtypeStruct((n_items, rank), np.float32),
+            jax.ShapeDtypeStruct((n_words, n_items), np.uint32),
+            jax.ShapeDtypeStruct((n_items,), np.bool_),
+            jax.ShapeDtypeStruct((bucket, topk.QUERY_WIDTH), np.int32),
+            jax.ShapeDtypeStruct((bucket, n_words), np.uint32),
+            jax.ShapeDtypeStruct((bucket, width), np.int32), k=k)
+    return lower
+
+
+def _itemset_rows_primer(topk, device, bucket, width, k):
+    def prime():
+        import jax
+        import numpy as np
+        # a flush with no query in it: every row's item list padding,
+        # every bit wanted, every exclusion padding
+        jax.device_get(device.topk(*device.rule_arguments(bucket, width))(
+            np.full((bucket, topk.QUERY_WIDTH), device.n_items, np.int32),
+            k))
+    return prime
+
+
 def _topk_users_lowerer(topk, n_users, n_items, rank, bucket, k):
     def lower():
         import jax
@@ -682,10 +731,11 @@ def _register_builtin() -> None:
                       "predict only, off the serving latency path")
     register_jit("topk_scores_batch", topk.topk_scores_batch, kind="eval",
                  note="batch_predict/eval path, not request serving")
-    register_jit("cosine_topk", topk.cosine_topk, kind="serving",
-                 note="similarproduct serves through host_masked_topk_"
-                      "batch (host BLAS); kept registered so a future "
-                      "device wiring must enumerate it")
+    register_jit("itemset_topk_rows", topk.itemset_topk_rows,
+                 kind="serving",
+                 note="enumerated per (bucket, exclusion width, k) by "
+                      "specs_itemset_topk_rows (the similar-product "
+                      "engine's device layout)")
     register_jit("als_train_scan", als._train_explicit_jit, kind="training",
                  note="enumerated from declared shapes by "
                       "training_program_specs (bucket_units shape oracle)")

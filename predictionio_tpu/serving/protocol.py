@@ -129,14 +129,44 @@ def host_serves_faster(dispatch, log) -> bool:
     return False
 
 
-def device_rows(topk_fn, ixs, k: int):
+def device_layout_or_host(place, probe, log):
+    """The rule engines' layout rule (models/ecommerce,
+    models/similarproduct): ``place()`` builds the device layout. On an
+    accelerator that is the layout, always, and a layout that fails
+    RAISES: a deploy that quietly served some other way would pass
+    every check without its layout ever having reached the chip. On the
+    CPU backend, where a tiny model serves faster from host BLAS than
+    through a dispatch, ``probe(layout)`` (one real bucket-1 query, a
+    callable) is timed by :func:`host_serves_faster` and None (the
+    host layout) returned when it is slow or placing failed."""
+    import jax
+
+    on_chip = jax.default_backend() != "cpu"
+    try:
+        dev = place()
+        if not on_chip and host_serves_faster(probe(dev), log):
+            dev = None
+    except Exception:
+        if on_chip:
+            raise
+        log.exception("device serving layout failed; serving from "
+                      "host arrays")
+        dev = None
+    return dev
+
+
+def device_rows(topk_fn, ixs, k: int, fill: int = 0):
     """The device half of a ``predict_batch``, the same for every device
     layout of every engine (recommendation: replicated, quantized,
-    sharded; e-commerce: replicated with rules): pad the batch's user indices up to a
-    serving bucket (index 0 is in-bounds — KNOWN_ISSUES #5), make the ONE
-    dispatch ``topk_fn(padded_ixs, k)``, fetch, and hand back the real
-    rows of the ``(bucket, k)`` values and indices, still arrays: the
-    `unpack` stage turns them into Python numbers, once a flush.
+    sharded; e-commerce: replicated with rules; similar product: item
+    factors with rules): pad the batch's indices up to a serving
+    bucket, make the ONE dispatch ``topk_fn(padded_ixs, k)``, fetch, and
+    hand back the real rows of the ``(bucket, k)`` values and indices,
+    still arrays: the `unpack` stage turns them into Python numbers,
+    once a flush. ``ixs`` is (b,), a user a row, or (b, q), a padded
+    list of items a row; a padding row holds ``fill``: index 0 where
+    the program wants an in-bounds row (KNOWN_ISSUES #5), the list's
+    own padding value where it takes one.
     Waterfall stages, drill-downs inside
     `dispatch` (and, on the batcher's worker, host spans in a profiler
     capture): `pad`; `execute` round `enqueue` (the call that returns the
@@ -150,12 +180,13 @@ def device_rows(topk_fn, ixs, k: int):
     from predictionio_tpu.common import waterfall
 
     with waterfall.stage("pad"):
-        bucket = bucket_for(len(ixs))
-        pix = np.zeros(bucket, dtype=np.int32)
-        pix[:len(ixs)] = ixs
+        ixs = np.asarray(ixs)
+        n = len(ixs)
+        pix = np.full((bucket_for(n),) + ixs.shape[1:], fill, np.int32)
+        pix[:n] = ixs
     with waterfall.stage("execute"):
         with waterfall.stage("enqueue"):
             on_device = topk_fn(pix, k)
         with waterfall.stage("device_get"):
             vals, idx = jax.device_get(on_device)
-    return vals[:len(ixs)], idx[:len(ixs)]
+    return vals[:n], idx[:n]

@@ -186,7 +186,8 @@ def model_hbm_bytes(models: Iterable[Any]) -> int:
     of a replicated array, one block of a row-sharded one
     (parallel/serve_dist.py). A model that keeps host copies beside
     its device arrays says what the device holds itself
-    (``hbm_bytes()``: models/ecommerce ECommModel)."""
+    (``hbm_bytes()``: models/ecommerce ECommModel,
+    models/similarproduct ALSModel)."""
     total = 0
     seen: set = set()
 

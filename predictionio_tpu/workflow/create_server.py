@@ -337,7 +337,8 @@ def _topk_selection(models: List[Any]) -> Optional[Dict[str, str]]:
         rows = getattr(m, "topk_rows", None)
         if rows is not None:
             # a model that says itself which row its programs select
-            # from (models/ecommerce: ops/topk.py masked_topk_rows)
+            # from (models/ecommerce: ops/topk.py masked_topk_rows;
+            # models/similarproduct: itemset_topk_rows)
             n = rows()
             if n is None:
                 continue
@@ -372,7 +373,9 @@ def _serving_layout(models: List[Any]) -> Dict[str, Any]:
     has that shape. ``perShardBytes`` is what one device holds of them:
     a shard's factor bytes, or the registry's estimate of the model. A
     model of another shape names its own layout (models/ecommerce:
-    "replicated+rules" with its declared ``excludeWidths``, or "host")."""
+    "replicated+rules" with its declared ``excludeWidths``;
+    models/similarproduct: "items+rules" with ``queryWidth`` beside
+    them; or "host")."""
     import numpy as np
 
     for m in models:
@@ -1142,7 +1145,8 @@ class QueryAPI:
         out["transport"] = http_transport.transport_status()
         for m in self.models:
             # an engine's own block, only where that engine is deployed
-            # (models/ecommerce: "ecomm", its rule reads and fallbacks)
+            # (models/ecommerce: "ecomm", its rule reads and fallbacks;
+            # models/similarproduct: "simprod")
             block = getattr(m, "status_block", None)
             if block is not None:
                 name, value = block()

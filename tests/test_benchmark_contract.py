@@ -361,6 +361,15 @@ def _lowered_masked_topk_rows(_mp):
         _s((4, topk.EXCLUDE_WIDTHS[0]), np.int32), k=K)
 
 
+def _lowered_itemset_topk_rows(_mp):
+    from predictionio_tpu.ops import topk
+    return topk.itemset_topk_rows.lower(
+        _s((300, 8), np.float32), _s((1, 300), np.uint32),
+        _s((300,), np.bool_), _s((4, topk.QUERY_WIDTH), np.int32),
+        _s((4, 1), np.uint32),
+        _s((4, topk.EXCLUDE_WIDTHS[0]), np.int32), k=K)
+
+
 def _lowered_trainer(entry, kernel):
     """The trainer as `pio train` reaches it: als.train_explicit on a
     tiny layout, with the jitted entry point lowered on the arguments
@@ -393,6 +402,8 @@ _ENTRY_POINTS = {
     "topk_for_users_sharded": ("jit_topk_for_users_sharded",
                                _lowered_topk_for_users_sharded),
     "masked_topk_rows": ("jit_masked_topk_rows", _lowered_masked_topk_rows),
+    "itemset_topk_rows": ("jit_itemset_topk_rows",
+                          _lowered_itemset_topk_rows),
     "train_hybrid": ("jit__train_hybrid_jit",
                      _lowered_trainer("_train_hybrid_jit", "hybrid")),
     "train_csrb": ("jit__train_csrb_jit",
@@ -430,32 +441,53 @@ def _cell_program_patterns():
     return out
 
 
-def test_the_ecomm_cells_patterns_and_the_other_cells_keep_apart():
+#: the cells whose engine applies business rules on the device: cell ->
+#: (its program, configuration, engine factory, `GET /` block, layout,
+#: the rule spans of a flush)
+RULE_CELLS = {
+    "serve.ecomm-amazon-r128.closed128": dict(
+        program="masked_topk_rows", config="ecomm-als-amazon-r128",
+        block="ecomm", layout="replicated+rules",
+        spans={"rules", "rules.seen", "rules.constraint"}),
+    "serve.simprod-amazon14-r128.closed128": dict(
+        program="itemset_topk_rows", config="simprod-als-amazon14-r128",
+        block="simprod", layout="items+rules",
+        spans={"rules", "rules.items"}),
+}
+ECOMM_CELL = "serve.ecomm-amazon-r128.closed128"
+
+
+@pytest.mark.parametrize("cell", sorted(RULE_CELLS))
+def test_the_rule_cells_patterns_and_the_other_cells_keep_apart(cell):
     """PERF.md 7.7: a pattern is searched over every module name of a
-    capture. The e-commerce cell's pattern finds its own program's
-    module and no other cell's; theirs do not find it."""
+    capture. A rule cell's pattern finds its own program's module and
+    no other cell's; theirs do not find it."""
+    program = RULE_CELLS[cell]["program"]
     pats = _cell_program_patterns()
-    mine = pats.pop(ECOMM_CELL)
-    assert mine == {"masked_topk_rows"}
+    mine = pats.pop(cell)
+    assert mine == {program}
     others = set().union(*pats.values())
     assert others and not mine & others
     modules = {name: module for name, (module, _l) in _ENTRY_POINTS.items()}
     for pat in mine:
         assert [n for n, mod in modules.items() if re.search(pat, mod)] \
-            == ["masked_topk_rows"]
+            == [program]
     for pat in others:
-        assert not re.search(pat, modules["masked_topk_rows"]), pat
+        assert not re.search(pat, modules[program]), pat
+
+
+def test_the_ecomm_cells_patterns_and_the_other_cells_keep_apart():
+    """The name this case had when the e-commerce cell was the only
+    one with rules; the parametrized case above holds both."""
+    assert _cell_program_patterns()[ECOMM_CELL] == {"masked_topk_rows"}
 
 
 # ---------------------------------------------------------------------------
-# the e-commerce cell: every counter path and span name its metric
+# the cells with rules: every counter path and span name their metric
 # files name is one a live deploy has (ISSUE 34's two cases, PERF.md 7.8)
 # ---------------------------------------------------------------------------
 
-ECOMM_CELL = "serve.ecomm-amazon-r128.closed128"
-
-
-def _ecomm_metric_terms(kind):
+def _metric_terms(cell, kind):
     """The `counter` / `traced_counter` paths (kind "counter") or the
     `span_s` / `span_n` patterns (kind "span") of the cell's metric
     files."""
@@ -475,26 +507,36 @@ def _ecomm_metric_terms(kind):
                 walk(v)
 
     for m in BENCHMARK["per_layer"]:
-        if ECOMM_CELL in m.get("workloads", ()):
+        if cell in m.get("workloads", ()):
             walk(_json(BENCH, "metrics", m["name"] + ".json"))
     return sorted(found)
 
 
-@pytest.fixture(scope="module")
-def ecomm_served():
+def _ecomm_metric_terms(kind):
+    return _metric_terms(ECOMM_CELL, kind)
+
+
+def _rule_cell_terms(kind):
+    return [(cell, term) for cell in sorted(RULE_CELLS)
+            if cell != ECOMM_CELL for term in _metric_terms(cell, kind)]
+
+
+@contextlib.contextmanager
+def _rule_cell_served(cell):
     """The cell's adapter at rehearsal size (its own `models`: the
-    ECommModel, the events, the constraint), deployed by QueryAPI with
-    batching and telemetry on; a few queries of its own generator
-    answered on the batcher's worker with the spans recorded; -> the
-    `GET /` page before and after, the span names, the replies."""
+    engine's model and whatever it reads while it serves), deployed by
+    QueryAPI with batching and telemetry on; a few queries of its own
+    generator answered on the batcher's worker with the spans recorded;
+    -> the `GET /` page before and after, the span names, the replies."""
     from predictionio_tpu.common import profiling
     from predictionio_tpu.data.storage import EngineInstance, Model
-    from predictionio_tpu.models.ecommerce import ECommerceEngine
     from predictionio_tpu.workflow import model_io
     from predictionio_tpu.workflow.create_server import QueryAPI, ServerConfig
 
-    cfg = _json(ROOT, _CONFIG_FILE["ecomm-als-amazon-r128"])
+    cfg = _json(ROOT, _CONFIG_FILE[RULE_CELLS[cell]["config"]])
     variant = _json(ROOT, cfg["engine_dir"], "engine.json")
+    module, _, factory = variant["engineFactory"].partition(":")
+    engine = getattr(importlib.import_module(module), factory)()
     mp = pytest.MonkeyPatch()
     mp.setenv("PIO_SERVE_DEVICE_MS", "1e9")
     telemetry.set_enabled(True)
@@ -516,6 +558,12 @@ def ecomm_served():
             spec = {"config": cfg, "traffic": _json(
                 BENCH, "traffic", "closed128.json")}
             model = adapter.rehearsal_model(cfg["model"], 4000)
+            # `models` is the deploy child's half of an adapter and may
+            # hold the process it runs in to the configuration's layout
+            # (adapters/simprod_als.py wraps `prepare_serving`): undone
+            # with the rest
+            for algo in set(engine.algorithm_class_map.values()):
+                mp.setattr(algo, "prepare_serving", algo.prepare_serving)
             models = adapter.models(cfg, model, 5, storage, variant)
             asked = adapter.queries(spec, model, 5, 48)
             wire = adapter.wire(spec)
@@ -533,7 +581,7 @@ def ecomm_served():
             storage.get_model_data_models().insert(Model(
                 id=instance_id, models=model_io.serialize_models(
                     models, check_finite=True)))
-            api = QueryAPI(storage=storage, engine=ECommerceEngine(),
+            api = QueryAPI(storage=storage, engine=engine,
                            config=ServerConfig(batching="on"))
             try:
                 _st, before = api.handle("GET", "/")
@@ -555,6 +603,39 @@ def ecomm_served():
         mp.undo()
 
 
+@pytest.fixture(scope="module")
+def ecomm_served():
+    with _rule_cell_served(ECOMM_CELL) as served:
+        yield served
+
+
+@pytest.fixture(scope="module")
+def rule_cells_served():
+    """cell -> what `_rule_cell_served` saw, for the rule cells that
+    came after the e-commerce one."""
+    out = {}
+    for cell in sorted(RULE_CELLS):
+        if cell != ECOMM_CELL:
+            with _rule_cell_served(cell) as served:
+                out[cell] = served
+    return out
+
+
+def _counter_ends(served, path):
+    """benchmark/reduce.py `_counter` walks the dotted path into `GET /`
+    and wants a number that does not run backwards."""
+    ends = []
+    for page in (served["before"], served["after"]):
+        node = page
+        for key in path.split("."):
+            assert isinstance(node, dict) and key in node, (path, key)
+            node = node[key]
+        assert isinstance(node, (int, float)) and not isinstance(node, bool)
+        ends.append(node)
+    assert ends[0] <= ends[1]
+    return ends
+
+
 def test_ecomm_cell_deploys_on_the_device_layout_and_answers(ecomm_served):
     b = ecomm_served["after"]["batching"]
     assert b["layout"] == "replicated+rules" and b["excludeWidths"]
@@ -563,17 +644,7 @@ def test_ecomm_cell_deploys_on_the_device_layout_and_answers(ecomm_served):
 
 @pytest.mark.parametrize("path", _ecomm_metric_terms("counter"))
 def test_ecomm_counter_path_is_on_the_status_page(ecomm_served, path):
-    """benchmark/reduce.py `_counter` walks the dotted path into `GET /`
-    and wants a number that does not run backwards."""
-    ends = []
-    for page in (ecomm_served["before"], ecomm_served["after"]):
-        node = page
-        for key in path.split("."):
-            assert isinstance(node, dict) and key in node, (path, key)
-            node = node[key]
-        assert isinstance(node, (int, float)) and not isinstance(node, bool)
-        ends.append(node)
-    assert ends[0] <= ends[1]
+    ends = _counter_ends(ecomm_served, path)
     if path == "ecomm.queries":
         assert ends[1] - ends[0] == len(ecomm_served["replies"])
     if path == "ecomm.hostFallbacks":
@@ -592,6 +663,41 @@ def test_ecomm_flush_emits_the_rule_spans_and_the_shared_stages(
         ecomm_served):
     assert {"rules", "rules.seen", "rules.constraint", "pad", "execute",
             "enqueue", "device_get", "unpack"} <= ecomm_served["spans"]
+
+
+@pytest.mark.parametrize("cell", [c for c in sorted(RULE_CELLS)
+                                  if c != ECOMM_CELL])
+def test_rule_cell_deploys_on_the_device_layout_and_answers(
+        rule_cells_served, cell):
+    served, about = rule_cells_served[cell], RULE_CELLS[cell]
+    b = served["after"]["batching"]
+    assert b["layout"] == about["layout"] and b["excludeWidths"]
+    # the layout the cell's adapter holds its deploy to
+    cfg = _json(ROOT, _CONFIG_FILE[about["config"]])
+    assert cfg["serving"]["deploy_layout"] == b["layout"]
+    assert b["perShardBytes"] > 0 and b["topkSelection"]
+    assert all(served["whole"]) and any(served["replies"])
+    assert about["spans"] | {"pad", "execute", "enqueue", "device_get",
+                             "unpack"} <= served["spans"]
+
+
+@pytest.mark.parametrize("cell,path", _rule_cell_terms("counter"))
+def test_rule_cell_counter_path_is_on_the_status_page(
+        rule_cells_served, cell, path):
+    ends = _counter_ends(rule_cells_served[cell], path)
+    block = RULE_CELLS[cell]["block"]
+    if path == block + ".queries":
+        assert ends[1] - ends[0] == len(rule_cells_served[cell]["replies"])
+    if path == block + ".hostFallbacks":
+        assert ends[1] == ends[0]
+
+
+@pytest.mark.parametrize("cell,pattern", _rule_cell_terms("span"))
+def test_rule_cell_span_is_one_annotate_emits(rule_cells_served, cell,
+                                              pattern):
+    assert [n for n in rule_cells_served[cell]["spans"]
+            if re.search(pattern, n)], (
+        pattern, sorted(rule_cells_served[cell]["spans"]))
 
 
 # ---------------------------------------------------------------------------

@@ -185,6 +185,47 @@ def test_masked_topk_rows_compiles_at_the_benchmark_cells_shape(
     assert factors < mem.argument_size_in_bytes < 1.003 * factors
 
 
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_itemset_topk_rows_compiles_at_the_benchmark_cells_shape(
+        one_chip, no_compile_cache, bucket):
+    """The similar-product cell's program (BENCHMARK.json,
+    simprod-als-amazon14-r128: 9,350,000 unit rows of rank 128 on ONE
+    chip, one uint32 rule word and one eligibility flag an item) at its
+    real size: a 2.39 GB score matrix at bucket 64, four times the
+    largest any other one-chip program makes. It compiles and fits; no
+    sort is as long as the catalog (`pick` sorts 18,261 chunk maxima a
+    row); no scatter and no flat copy of the scores for the exclusions.
+    The temporaries: from 5 M items up the TPU compiler holds (8, n)
+    row groups beside the score matrix, two of them under the rules
+    (1.25 of the scores, where 2,441,053 items hold 1.004): under 1.3,
+    so a second copy of the scores would fail this. With the resident
+    arrays: under half of the chip's 16 GB."""
+    from predictionio_tpu.ops import topk
+    n_items, rank, words = 9_350_000, 128, 1
+    compiled = topk.itemset_topk_rows.lower(
+        _s((n_items, rank), jnp.float32, one_chip),
+        _s((words, n_items), jnp.uint32, one_chip),
+        _s((n_items,), jnp.bool_, one_chip),
+        _s((bucket, topk.QUERY_WIDTH), jnp.int32, one_chip),
+        _s((bucket, words), jnp.uint32, one_chip),
+        _s((bucket, topk.EXCLUDE_WIDTHS[0]), jnp.int32, one_chip),
+        k=K).compile()
+    text = compiled.as_text()
+    assert not [line for line in text.splitlines()
+                if " sort(" in line and f",{n_items}]" in line]
+    assert topk.chunk_plan(n_items, K) == (512, 18_261)
+    assert " scatter(" not in text
+    if bucket > 1:      # bucket 1's scores are a flat row as they are
+        assert f"f32[{bucket * n_items}]" not in text
+    scores_bytes = 4 * max(bucket, 8) * n_items     # 8 sublanes a tile
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.3 * scores_bytes
+    factors = 4 * n_items * rank
+    assert factors < mem.argument_size_in_bytes < 1.011 * factors
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 8e9
+
+
 # ---------------------------------------------------------------------------
 # the trainer
 # ---------------------------------------------------------------------------

@@ -32,13 +32,81 @@ def test_topk_batch_matches_loop():
         np.testing.assert_array_equal(np.asarray(bi)[row], np.asarray(si))
 
 
-def test_cosine_topk_scale_invariant():
-    V = np.array([[10.0, 0], [0, 0.1], [3, 3]], dtype=np.float32)
-    q = np.array([5.0, 0.0], dtype=np.float32)
-    vals, idx = topk.cosine_topk(q, V, k=3)
-    # cosine ignores magnitude: item0 (parallel) wins with score 1
-    assert int(np.asarray(idx)[0]) == 0
-    np.testing.assert_allclose(float(np.asarray(vals)[0]), 1.0, rtol=1e-5)
+def test_cosine_topk_scale_invariant(monkeypatch):
+    """Cosine ignores magnitude. The similar-product engine's `train`
+    brings the trainer's raw item factors to unit rows, once, and
+    ops/topk.py itemset_topk_rows sums the query's rows and takes one
+    product: scaling an item's raw factors changes no reply."""
+    from predictionio_tpu.models.similarproduct import (
+        als_algorithm as simprod)
+    from predictionio_tpu.models.similarproduct.data_source import (
+        TrainingData, ViewEvent)
+    from predictionio_tpu.models.similarproduct.engine import Item, Query
+
+    raw = np.array([[10.0, 0], [0, 0.1], [3, 3], [4, 0.5]],
+                   dtype=np.float32)
+    scaled = raw * np.array([[0.01], [300.0], [7.0], [1.0]], np.float32)
+    data = TrainingData(
+        users={"u": None}, items={f"i{i}": Item() for i in range(4)},
+        view_events=[ViewEvent("u", f"i{i}", 0.0) for i in range(4)])
+    algo = simprod.ALSAlgorithm(simprod.ALSAlgorithmParams(rank=2, seed=1))
+    # the trainer kernels stand aside for the factors under test; the
+    # device layout, whatever the CPU backend's probe would time
+    monkeypatch.setattr(simprod.als, "prepare_ratings",
+                        lambda *a, **kw: None)
+    monkeypatch.setenv("PIO_SERVE_DEVICE_MS", "1e9")
+    replies = []
+    for V in (raw, scaled):
+        monkeypatch.setattr(simprod.als, "train_implicit",
+                            lambda *a, V=V, **kw: (None, V))
+        model = algo.prepare_serving(algo.train(None, data))
+        assert model.device is not None
+        np.testing.assert_allclose(
+            np.linalg.norm(model.product_features, axis=1), 1, rtol=1e-6)
+        res = algo.predict(model, Query(items=("i3",), num=3))
+        replies.append(([s.item for s in res.itemScores],
+                        [s.score for s in res.itemScores]))
+    (items, vals), (items2, vals2) = replies
+    # item 0 is the most parallel to item 3, whatever its length; item 1
+    # is nearly at right angles to it, and still above 0
+    assert items == items2 == ["i0", "i2", "i1"]
+    np.testing.assert_allclose(vals, vals2, rtol=1e-6)
+    np.testing.assert_allclose(vals[0], 4 / np.hypot(4, 0.5), rtol=1e-6)
+
+
+#: StableHLO text of jit_masked_topk_rows at one shape, as commit
+#: 6315a47 (before `mask` > `exclude` > `select` moved into
+#: _rules_and_select for itemset_topk_rows to share) lowered it
+MASKED_TOPK_ROWS_PIN = {
+    "jax": "0.9.0", "chars": 18743,
+    "sha256": "d56912824b2d9ac50b44d4b2bf9659f5"
+              "de66038383dc36735faa00d6665e051d"}
+
+
+def test_masked_topk_rows_lowers_to_the_pinned_text():
+    """The e-commerce engine's program is what it was, text for text,
+    after its rule stages became the function the similar-product
+    program calls too: the e-commerce cell's trace metrics key on this
+    module, and its replies are held bit for bit. Lowering text depends
+    on the jax that lowers: under another one, take the pin again from
+    that commit before trusting a difference."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    if jax.__version__ != MASKED_TOPK_ROWS_PIN["jax"]:
+        pytest.skip(f"pinned under jax {MASKED_TOPK_ROWS_PIN['jax']}")
+    S = jax.ShapeDtypeStruct
+    b, n_users, n, r, w, E = 16, 3000, 50_000, 32, 2, 128
+    text = topk.masked_topk_rows.lower(
+        S((n_users, r), jnp.float32), S((n, r), jnp.float32),
+        S((w, n), jnp.uint32), S((n,), jnp.bool_), S((b,), jnp.int32),
+        S((b, w), jnp.uint32), S((b, E), jnp.int32), k=10).as_text()
+    assert "jit_masked_topk_rows" in text
+    assert len(text) == MASKED_TOPK_ROWS_PIN["chars"]
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == MASKED_TOPK_ROWS_PIN["sha256"]
 
 
 def test_topk_for_users_tie_breaks_lowest_index():
