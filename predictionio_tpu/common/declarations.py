@@ -424,6 +424,9 @@ METRICS: Dict[str, str] = {
     "pio_batcher_queries_total": "queries admitted into batches",
     "pio_batcher_rejected_total":
         "queries rejected by admission control (503)",
+    "pio_batcher_overlapped_total":
+        "flushes whose callback began while the other lane's flush was "
+        "in flight",
     "pio_batcher_queue_wait_seconds_total": "summed per-query queue wait",
     "pio_batcher_flush_seconds": "flush (device dispatch) latency per batch",
     "pio_batcher_queue_depth": "current admission queue depth",

@@ -110,7 +110,7 @@ class ServerConfig:
     #: 503 + Retry-After instead of letting latency grow without bound.
     batch_max_queue: int = 256
     #: graceful-drain budget (SIGTERM / drain()): how long to wait for
-    #: the batcher worker to finish every admitted in-flight batch
+    #: the batcher lanes to finish every admitted in-flight batch
     #: before the server exits anyway.
     drain_grace_s: float = 30.0
     #: AOT prebuild (serving/aot.py): "auto"/"on" eagerly compile every
@@ -964,8 +964,10 @@ class QueryAPI:
             return None
 
         def flush(queries):
-            # degraded tracking rides the worker thread for the whole
-            # batch: a failed side-channel lookup during any query of the
+            # degraded tracking rides the flushing lane's thread for the
+            # whole batch (thread-local: the other lane's flush, which may
+            # run at the same time, keeps its own flag): a failed
+            # side-channel lookup during any query of the
             # flush taints every result of that flush (conservative — the
             # lookups run inside predict_batch where per-query attribution
             # is not visible from here; KNOWN_ISSUES documents this)
@@ -1018,7 +1020,7 @@ class QueryAPI:
 
     def drain(self, grace_s: Optional[float] = None) -> None:
         """Graceful shutdown: stop admitting queries (/readyz -> 503,
-        /queries.json -> 503 + Retry-After), let the batcher worker
+        /queries.json -> 503 + Retry-After), let the batcher lanes
         finish EVERY already-admitted batch, then request stop. Safe to
         call more than once; every admitted in-flight request gets its
         real answer — zero are dropped."""

@@ -347,18 +347,19 @@ def test_bucket_padding_never_changes_results(trained, monkeypatch):
 
 def _gated_batcher(api):
     """Wrap the deployed batcher's flush so batches block on a gate —
-    deterministic queue buildup for the admission-control tests. The
-    `entered` event proves the worker is busy inside a flush (i.e. the
-    next submits can only queue, not be picked up)."""
+    deterministic queue buildup for the admission-control tests. Each
+    batch that reaches the callback releases `entered` once: two
+    acquires prove both lanes busy inside a flush (i.e. the next
+    submits can only queue, not be picked up)."""
     import threading
 
-    entered = threading.Event()
+    entered = threading.Semaphore(0)
     gate = threading.Event()
     batcher = api._batcher
     real = batcher._flush_fn
 
     def gated(items):
-        entered.set()
+        entered.release()
         gate.wait(30)
         return real(items)
 
@@ -378,7 +379,11 @@ def test_admission_control_503_retry_after(trained):
         threads = [threading.Thread(
             target=_post, args=(api, {"user": "u1", "num": 2}))]
         threads[0].start()
-        assert entered.wait(10)    # worker provably busy in a flush
+        assert entered.acquire(timeout=10)
+        threads.append(threading.Thread(
+            target=_post, args=(api, {"user": "u1", "num": 2})))
+        threads[1].start()         # a full batch of one: the second lane
+        assert entered.acquire(timeout=10)   # both lanes busy in a flush
         for _ in range(2):         # fill the queue to max_queue
             t = threading.Thread(
                 target=_post, args=(api, {"user": "u1", "num": 2}))
@@ -426,9 +431,12 @@ def test_admission_control_503_over_http(trained):
 
         threads = [threading.Thread(target=post_http)]
         threads[0].start()
-        assert entered.wait(10)    # worker provably busy in a flush
+        assert entered.acquire(timeout=10)
         threads.append(threading.Thread(target=post_http))
-        threads[1].start()         # fills the 1-slot queue
+        threads[1].start()         # a full batch of one: the second lane
+        assert entered.acquire(timeout=10)   # both lanes busy in a flush
+        threads.append(threading.Thread(target=post_http))
+        threads[2].start()         # fills the 1-slot queue
         deadline = time.time() + 10
         while time.time() < deadline:
             with api._batcher._cond:

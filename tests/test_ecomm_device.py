@@ -414,6 +414,52 @@ def test_device_layout_answers_as_the_host_layout_does(both_layouts):
     assert n_full > 100        # the rules did not empty the catalog
 
 
+def test_flush_callback_is_reentrant_and_flags_only_its_own_flush(
+        monkeypatch):
+    """`pio deploy`'s flush callback on two lanes at once, on the device
+    layout: each flush answers as it does alone, and a seen-events read
+    that fails in one of them flags that flush's replies degraded and
+    not the other's (the flag is the lane's own)."""
+    import threading
+
+    from tests.test_serving_batcher import at_once
+
+    def as_query(q):
+        return Query(user=q["user"], num=q["num"],
+                     categories=q.get("categories"),
+                     whiteList=q.get("whiteList"),
+                     blackList=q.get("blackList"))
+
+    queries = [as_query(q) for q in _mixed_queries(40, seed=3)]
+    batches = [[q for q in queries if q.user != "u5"][:4],
+               [q for q in queries if q.user != "u5"][4:]]
+    batches[0].append(Query(user="u5", num=K))
+    with _deployed("1e9") as (api, _storage, _app, _gone):
+        flush = api._batcher._flush_fn
+        alone = [flush(b) for b in batches]
+        assert not any(bad for r in alone for _p, bad in r)
+        for got, due in zip(at_once(flush, batches), alone):
+            assert all(g == due for g in got)
+        # both flushes inside predict_batch before u5's read fails
+        real = store.find_target_ids
+        inside = threading.Barrier(2)
+        first = threading.local()
+
+        def reads(*a, **kw):
+            if not getattr(first, "done", False):
+                first.done = True
+                inside.wait(30)
+            if kw.get("entity_id") == "u5":
+                raise OSError("seen-events store unreachable")
+            return real(*a, **kw)
+
+        monkeypatch.setattr(store, "find_target_ids", reads)
+        tainted, clean = at_once(flush, batches, rounds=1)
+    assert [bad for _p, bad in tainted[0]] == [True] * len(batches[0])
+    assert [bad for _p, bad in clean[0]] == [False] * len(batches[1])
+    assert [p for p, _bad in clean[0]] == [p for p, _bad in alone[1]]
+
+
 def test_nothing_compiles_after_warm_up(both_layouts):
     before, after = both_layouts["compiles"]
     assert after == before

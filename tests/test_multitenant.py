@@ -420,13 +420,13 @@ class TestMultiTenantDeploy:
 def _gate_tenant_batcher(api, name):
     """tests/test_create_server.py's _gated_batcher, aimed at one
     tenant's OWN batcher."""
-    entered = threading.Event()
+    entered = threading.Semaphore(0)
     gate = threading.Event()
     batcher = api.registry.get(name).batcher
     real = batcher._flush_fn
 
     def gated(items):
-        entered.set()
+        entered.release()
         gate.wait(30)
         return real(items)
 
@@ -447,11 +447,15 @@ def test_tenant_saturation_is_isolated(mt_trained):
         threads = [threading.Thread(
             target=_resp, args=(api, {"user": "u1", "num": 2}, "key-a"))]
         threads[0].start()
-        assert entered.wait(10)          # a's worker provably mid-flush
-        t = threading.Thread(
-            target=_resp, args=(api, {"user": "u1", "num": 2}, "key-a"))
-        t.start()
-        threads.append(t)                # fills a's 1-slot queue
+        assert entered.acquire(timeout=10)
+        for _ in range(2):               # a's second lane, then its queue
+            t = threading.Thread(
+                target=_resp, args=(api, {"user": "u1", "num": 2},
+                                    "key-a"))
+            t.start()
+            threads.append(t)
+            if len(threads) == 2:        # both of a's lanes mid-flush
+                assert entered.acquire(timeout=10)
         batcher = api.registry.get("a").batcher
         deadline = time.time() + 10
         while time.time() < deadline:

@@ -507,6 +507,22 @@ def test_device_layout_answers_as_the_host_layout_does(layouts, carried):
     assert after["queryWidth"] == Q
 
 
+def test_predict_batch_is_reentrant(layouts):
+    """Two flushes at once on the device layout (the batcher's two
+    lanes), at two buckets, the host fallbacks among them: each answers
+    as it does alone."""
+    from tests.test_serving_batcher import at_once
+
+    algo, dev = layouts["algo"], layouts["train", "device"]
+    queries = [_as_query(q) for q in _mixed_queries(64, seed=5)]
+    batches = [queries[:3], queries[3:64]]
+    alone = [algo.predict_batch(dev, b) for b in batches]
+    for got, due in zip(at_once(lambda b: algo.predict_batch(dev, b),
+                                batches), alone):
+        assert all([_pairs(r) for r in g] == [_pairs(r) for r in due]
+                   for g in got)
+
+
 def test_predict_is_the_row_of_predict_batch(layouts, monkeypatch):
     """`predict` on the device layout is a flush of one, on the
     bucket-1 program, and no host kernel answers a query the program

@@ -391,7 +391,8 @@ def test_batcher_stats_are_registry_backed(memory_storage):
         b = info["batching"]
         assert set(b) == {"enabled", "maxBatchSize", "maxDelayMs",
                           "maxQueue", "buckets", "queueDepth", "batches",
-                          "queries", "rejected", "batchSizeHist",
+                          "overlapped", "queries", "rejected",
+                          "batchSizeHist",
                           "bucketHist", "avgQueueWaitMs", "avgFlushMs",
                           "topkSelection", "layout", "shards",
                           "perShardBytes"}
@@ -399,6 +400,7 @@ def test_batcher_stats_are_registry_backed(memory_storage):
         # the same numbers, straight from the registry instruments
         assert int(api._batcher._m_queries.value) == 3
         assert int(api._batcher._m_batches.value) == b["batches"]
+        assert int(api._batcher._m_overlapped.value) == b["overlapped"]
         _st, payload, _h = api.handle("GET", "/metrics")
         types, samples = parse_prometheus(payload)
         assert types["pio_batcher_queries_total"] == "counter"
