@@ -428,6 +428,9 @@ METRICS: Dict[str, str] = {
         "flushes whose callback began while the other lane's flush was "
         "in flight",
     "pio_batcher_queue_wait_seconds_total": "summed per-query queue wait",
+    "pio_batcher_wake_seconds_total":
+        "summed per-query wait of a request thread to run again after its "
+        "lane set it done (the request side of the GIL line)",
     "pio_batcher_flush_seconds": "flush (device dispatch) latency per batch",
     "pio_batcher_queue_depth": "current admission queue depth",
     "pio_batcher_batch_size": "batches by exact flush size",
@@ -494,6 +497,14 @@ METRICS: Dict[str, str] = {
         "socket writes the HTTP transport made for replies; equal to "
         "pio_transport_requests_total: one write a reply (an injected "
         "abort has none, an Expect: 100-continue two)",
+    "pio_transport_cpu_seconds_total":
+        "CPU seconds of the threads that answer requests (a connection's "
+        "thread; the async transport's executor), read from their CPU "
+        "clocks at scrape time",
+    "pio_host_span_seconds_total":
+        "exclusive wall seconds of the host's counted spans (the batcher's "
+        "lanes, the training phases) by span",
+    "pio_host_spans_total": "calls of the host's counted spans by span",
     "pio_transport_protocol_errors_total":
         "requests refused by the transport itself, by status code: 400 "
         "(request line, header line, Content-Length), 414, 431, 501, 505",

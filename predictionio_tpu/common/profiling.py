@@ -44,19 +44,42 @@ format (same ``capture.json`` next to the same xprof layout).
 Overhead caveat (KNOWN_ISSUES #10): a running capture taxes every
 dispatch; on the CPU backend the device timeline is host threads only.
 
-Host stages in a capture: :func:`annotate` is the one primitive that
-puts the program's own stages on the profiler's clock, beside the device
-ops. Three callers, one `with` each: the batcher's worker thread names
-its whole loop (``idle_wait``/``fill_wait``/``form_batch``/``flush``/
-``wake``, serving/batcher.py), ``waterfall.stage`` opens the same
-annotation on that thread for the stages inside the flush
-(``supplement``, ``dispatch``, ``pad``, ``execute``/``enqueue``/
-``device_get``, ``unpack``, ``merge``), and ``WorkflowContext.phase``
-names the training phases (``phase.read`` .. ``phase.persist``). Only a
-thread that feeds a device queue annotates: request threads never do
-(hundreds of them opening spans would hide the feeder's in any reader
-that attributes a device gap to the innermost span around it). With no
-capture running an annotation is a flag test in the profiler.
+Host stages, counted always and on the profiler's clock in a capture:
+:class:`annotate` is the one primitive that names the program's own
+stages. Three callers, one `with` each: the batcher's lanes name their
+whole loop (``idle_wait``/``fill_wait``/``form_batch``/``flush``/
+``wake``, serving/batcher.py), ``waterfall.stage`` opens the same span
+on a lane for the stages inside the flush (``supplement``,
+``dispatch``, ``pad``, ``execute``/``enqueue``/``device_get``,
+``unpack``, ``merge``, the engines' ``rules.*``), and
+``WorkflowContext.phase`` names the training phases (``phase.read`` ..
+``phase.persist``). Every span is counted whether or not a capture
+runs: calls and exclusive wall seconds (``time.perf_counter``, a nested
+span's time taken out of its parent's), in a table of the thread that
+opened it, summed over threads when read (:func:`span_totals`: `GET /`
+``hostSpans``; ``pio_host_span_seconds_total`` / ``pio_host_spans_total``
+at scrape time). Where jax is imported the span is also a
+``TraceAnnotation``, which records nothing while no capture runs. Only
+a thread that feeds a device queue opens spans: request threads never
+do (hundreds of them would hide the feeder's in any reader that
+attributes a device gap to the innermost span around it); their CPU and
+wake-up waits are plain counters instead (data/api/http.py,
+serving/batcher.py).
+
+CPU seconds are a thread's, not a span's (:class:`ThreadCPU`): the
+threads of a group (a batcher's lanes, the request threads) are read
+from their CPU clocks when a page is built, and read no clock on their
+own path. A thread-CPU clock read is a system call where a ``perf_counter``
+read is not, and on a host that traps system calls (20 us a read on the
+chip's host, against 0.6 here) two a request cost a tenth of a query's
+host time; a blocked thread runs up no CPU, so its clock says what its
+work cost without a read round each stage. Wall less CPU over a lane's
+work is its time off the CPU: the GIL line plus any wait for a core.
+
+The whole process (:func:`host_status`, `GET /` ``host``): CPU seconds,
+threads, and the seconds its threads were runnable with no core free
+(``/proc/self/task/*/schedstat``), which tells a wait for the GIL from a
+wait for a core; a kernel without schedstat (gVisor's) gives none.
 
 jax is imported lazily — importing this module from a daemon that never
 profiles costs nothing, and a capture attempt on a stripped runtime
@@ -65,7 +88,6 @@ degrades to a clean 503.
 
 from __future__ import annotations
 
-import contextlib
 import datetime as _dt
 import json
 import logging
@@ -75,7 +97,7 @@ import tempfile
 import threading
 import time
 import uuid
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 logger = logging.getLogger("predictionio_tpu.profiling")
 
@@ -96,23 +118,210 @@ _captures: List[Dict[str, Any]] = []
 #: as the traced window.
 DEVICE_TRACE_PREFIX = "bench:"
 
-_NO_ANNOTATION = contextlib.nullcontext()
+
+class ThreadSpans:
+    """One thread's counted spans: ``table`` maps a span's name to
+    ``[calls, exclusive wall s]``, written by that thread alone, and
+    ``top`` is its innermost open span."""
+
+    __slots__ = ("table", "top")
+
+    def __init__(self):
+        self.table: Dict[str, List[float]] = {}
+        self.top: Optional["annotate"] = None
 
 
-def annotate(name: str):
-    """Context manager: the block as host span ``name`` on the profiler's
-    clock, so a capture (POST /debug/profile, ``pio train --profile``,
-    the benchmark's traced run) shows what the host was doing beside
-    each device op and each idle gap. A ``jax.profiler.TraceAnnotation``
-    when jax is already imported in this process; otherwise a shared
-    null context — a daemon that never touches jax imports nothing
-    here. With no capture running the annotation records nothing."""
-    # getattr: the module is in sys.modules, still without its names,
-    # while another thread is half way through importing jax
-    span = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
-    if span is None:
-        return _NO_ANNOTATION
-    return span(DEVICE_TRACE_PREFIX + name)
+_spans_tls = threading.local()
+_threads_lock = threading.Lock()
+#: every thread's table, kept after the thread ends so no total falls
+_threads: List[ThreadSpans] = []
+
+
+def thread_spans() -> ThreadSpans:
+    """The calling thread's counted spans, made at its first span."""
+    spans = getattr(_spans_tls, "spans", None)
+    if spans is None:
+        spans = _spans_tls.spans = ThreadSpans()
+        with _threads_lock:
+            _threads.append(spans)
+    return spans
+
+
+class annotate:
+    """Context manager: the block as host span ``name``, counted in the
+    calling thread's table (module docstring) and, when jax is already
+    imported in this process, a ``jax.profiler.TraceAnnotation`` on the
+    profiler's clock, so a capture (POST /debug/profile, ``pio train
+    --profile``, the benchmark's traced run) shows what the host was
+    doing beside each device op and each idle gap. A daemon that never
+    touches jax imports nothing here."""
+
+    __slots__ = ("name", "_spans", "_up", "_trace", "_t0", "_inner")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self) -> "annotate":
+        spans = getattr(_spans_tls, "spans", None) or thread_spans()
+        self._spans = spans
+        self._up, spans.top = spans.top, self
+        self._inner = 0.0
+        # getattr: the module is in sys.modules, still without its
+        # names, while another thread is half way through importing jax
+        cls = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation",
+                      None)
+        self._trace = None if cls is None else cls(
+            DEVICE_TRACE_PREFIX + self.name)
+        if self._trace is not None:
+            self._trace.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        wall = time.perf_counter() - self._t0
+        if self._trace is not None:
+            self._trace.__exit__(*exc)
+        spans = self._spans
+        up = spans.top = self._up
+        if up is not None:
+            up._inner += wall
+        rec = spans.table.get(self.name)
+        if rec is None:
+            rec = spans.table[self.name] = [0, 0.0]
+        rec[0] += 1
+        rec[1] += wall - self._inner
+
+
+def span_totals(threads: Optional[Iterable[ThreadSpans]] = None
+                ) -> Dict[str, Dict[str, float]]:
+    """``{name: {"n", "wallSeconds"}}`` of the counted spans of
+    ``threads`` (default: every thread that opened one, gone or alive),
+    exclusive seconds summed over them. Lock-free against the writers: a
+    table is copied whole under the GIL and each number only grows."""
+    if threads is None:
+        with _threads_lock:
+            threads = list(_threads)
+    out: Dict[str, Dict[str, float]] = {}
+    for spans in threads:
+        for name, (n, wall) in spans.table.copy().items():
+            tot = out.setdefault(name, {"n": 0, "wallSeconds": 0.0})
+            tot["n"] += n
+            tot["wallSeconds"] += wall
+    return out
+
+
+class ThreadCPU:
+    """The CPU seconds of a group of threads, read from each one's CPU
+    clock (``time.pthread_getcpuclockid``) when :meth:`seconds` is asked,
+    never on the threads' own path (module docstring). A thread joins
+    on itself; one that leaves, or ends without leaving, keeps its last
+    reading, so the sum never falls."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        #: clock id of each live thread -> its last reading
+        self._clocks: Dict[int, float] = {}
+        self._ended = 0.0
+
+    def join(self) -> None:
+        """Count the calling thread from now on (again: no change)."""
+        clock = time.pthread_getcpuclockid(threading.get_ident())
+        with self._lock:
+            self._clocks.setdefault(clock, 0.0)
+
+    def leave(self) -> None:
+        """The calling thread is about to end: its CPU stays counted."""
+        clock = time.pthread_getcpuclockid(threading.get_ident())
+        final = time.clock_gettime(clock)
+        with self._lock:
+            if self._clocks.pop(clock, None) is not None:
+                self._ended += final
+
+    def seconds(self) -> float:
+        with self._lock:
+            clocks = list(self._clocks)
+        now: Dict[int, Optional[float]] = {}
+        for clock in clocks:
+            try:
+                now[clock] = time.clock_gettime(clock)
+            except OSError:                 # ended without leave()
+                now[clock] = None
+        with self._lock:
+            for clock, value in now.items():
+                last = self._clocks.get(clock)
+                if last is None:
+                    continue                # left meanwhile: in _ended
+                if value is None:
+                    self._ended += self._clocks.pop(clock)
+                else:
+                    self._clocks[clock] = max(last, value)
+            return self._ended + sum(self._clocks.values())
+
+
+def collect_spans() -> List[str]:
+    """Scrape-time `/metrics` lines of :func:`span_totals`: nothing is
+    written to the registry as a span closes."""
+    totals = span_totals()
+    if not totals:
+        return []
+    names = sorted(totals)
+    return (["# HELP pio_host_span_seconds_total Exclusive wall seconds of "
+             "the host's counted spans",
+             "# TYPE pio_host_span_seconds_total counter"]
+            + [f'pio_host_span_seconds_total{{span="{n}"}} '
+               f'{totals[n]["wallSeconds"]!r}' for n in names]
+            + ["# HELP pio_host_spans_total Calls of the host's counted "
+               "spans", "# TYPE pio_host_spans_total counter"]
+            + [f'pio_host_spans_total{{span="{n}"}} {totals[n]["n"]}'
+               for n in names])
+
+
+# ---------------------------------------------------------------------------
+# the whole process (`GET /` host)
+# ---------------------------------------------------------------------------
+
+_TASKS = "/proc/self/task"
+_host_lock = threading.Lock()
+#: run-queue seconds: the last reading of each live task, and the last
+#: readings of the tasks gone, so the sum never falls
+_run_queue_last: Dict[int, float] = {}
+_run_queue_gone = 0.0
+
+
+def _run_queue_s(tid: int) -> float:
+    """Field 2 of a task's ``schedstat``: nanoseconds runnable with no
+    core, as seconds; 0.0 where the kernel gives none."""
+    try:
+        with open(f"{_TASKS}/{tid}/schedstat", "rb") as f:
+            fields = f.read().split()
+        return int(fields[1]) * 1e-9
+    except (OSError, IndexError, ValueError):
+        return 0.0
+
+
+def host_status() -> Dict[str, Any]:
+    """`GET /`'s ``host`` block, read as the page is built: the
+    process's CPU seconds, its threads, and ``runQueueSeconds``, the
+    seconds its threads were runnable with no core free, a task gone
+    since the last page with its last reading (left out where the
+    kernel gives no schedstat: every reading 0)."""
+    global _run_queue_gone
+    try:
+        tids = [int(t) for t in os.listdir(_TASKS)]
+    except (OSError, ValueError):
+        tids = []
+    readings = {tid: _run_queue_s(tid) for tid in tids}
+    with _host_lock:
+        for tid in [t for t in _run_queue_last if t not in readings]:
+            _run_queue_gone += _run_queue_last.pop(tid)
+        for tid, waited in readings.items():
+            _run_queue_last[tid] = max(waited, _run_queue_last.get(tid, 0.0))
+        waited = _run_queue_gone + sum(_run_queue_last.values())
+    out: Dict[str, Any] = {"cpuSeconds": time.process_time(),
+                           "threads": len(tids) or threading.active_count()}
+    if waited > 0:
+        out["runQueueSeconds"] = waited
+    return out
 
 
 class CaptureBusy(Exception):

@@ -256,10 +256,21 @@ def current() -> Optional[TraceContext]:
     return getattr(_tls, "ctx", None)
 
 
-@contextlib.contextmanager
+#: what activate() and span() hand back with nothing to record: the
+#: block runs untouched, one getattr the whole cost of tracing-off
+_PASS_THROUGH = contextlib.nullcontext()
+
+
 def activate(ctx: Optional[TraceContext]):
     """Install ``ctx`` as this thread's context for the block (None is
     allowed and simply clears it — callers never need to branch)."""
+    if ctx is None and getattr(_tls, "ctx", None) is None:
+        return _PASS_THROUGH
+    return _activate(ctx)
+
+
+@contextlib.contextmanager
+def _activate(ctx: Optional[TraceContext]):
     prev = getattr(_tls, "ctx", None)
     _tls.ctx = ctx
     try:
@@ -309,7 +320,6 @@ def _wall_now() -> float:
     return _dt.datetime.now(_dt.timezone.utc).timestamp()
 
 
-@contextlib.contextmanager
 def span(name: str, service: str = ""):
     """Record a child span of the active context around the block.
 
@@ -318,8 +328,12 @@ def span(name: str, service: str = ""):
     nested spans and outbound RPC headers chain correctly."""
     ctx = getattr(_tls, "ctx", None)
     if ctx is None:
-        yield None
-        return
+        return _PASS_THROUGH
+    return _span(name, service, ctx)
+
+
+@contextlib.contextmanager
+def _span(name: str, service: str, ctx: TraceContext):
     child = TraceContext(ctx.trace_id, _new_id())
     prev = ctx
     _tls.ctx = child

@@ -56,7 +56,8 @@ from concurrent.futures import ThreadPoolExecutor
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable, Dict, Optional, Tuple
 
-from predictionio_tpu.common import devicewatch, resilience, telemetry, tracing
+from predictionio_tpu.common import (devicewatch, profiling, resilience,
+                                     telemetry, tracing)
 
 
 def transport_mode(explicit: Optional[str] = None) -> str:
@@ -255,6 +256,9 @@ _M_REQUESTS = telemetry.registry().counter(
 _M_WRITES = telemetry.registry().counter(
     "pio_transport_writes_total",
     "Socket writes made for replies: one a reply").child()
+#: the threads that answer requests: a connection's thread (threaded),
+#: the executor's (async); read from their CPU clocks, never per request
+_REQUEST_CPU = profiling.ThreadCPU()
 _M_PROTOCOL_ERRORS = telemetry.registry().counter(
     "pio_transport_protocol_errors_total",
     "Requests the transport itself refused, by status code",
@@ -264,12 +268,26 @@ _M_PROTOCOL_ERRORS = telemetry.registry().counter(
 def transport_status() -> Dict[str, object]:
     """`GET /`'s transport block: the process-wide counters /metrics
     has. `writes == requests` says one write a reply (an injected abort
-    is a request with none, an `Expect: 100-continue` one with two)."""
+    is a request with none, an `Expect: 100-continue` one with two).
+    `cpuSeconds` is the CPU of the threads that answer requests, read
+    from their clocks as the page is built: the threaded transport's
+    connection threads, the async one's executor (its loop thread's
+    parse and write are not in it). A thread blocked on its socket or in
+    the batcher runs up next to none."""
     return {"mode": transport_mode(),
             "requests": int(_M_REQUESTS.value),
             "writes": int(_M_WRITES.value),
+            "cpuSeconds": _REQUEST_CPU.seconds(),
             "protocolErrors": int(sum(
                 v for _n, _l, v in _M_PROTOCOL_ERRORS.samples()))}
+
+
+def _collect_cpu():
+    """Scrape-time `/metrics` lines of the request threads' CPU."""
+    return ["# HELP pio_transport_cpu_seconds_total CPU seconds of the "
+            "threads that answer requests",
+            "# TYPE pio_transport_cpu_seconds_total counter",
+            f"pio_transport_cpu_seconds_total {_REQUEST_CPU.seconds()!r}"]
 
 
 def _status_phrase(code: int) -> str:
@@ -459,6 +477,7 @@ class _Handler(socketserver.StreamRequestHandler):
     disable_nagle_algorithm = True
 
     def handle(self):
+        _REQUEST_CPU.join()
         try:
             while self._one_request():
                 pass
@@ -467,6 +486,8 @@ class _Handler(socketserver.StreamRequestHandler):
             # fresh one, or a mid-request kill); the work is done — losing
             # the response write is their failure mode, not ours
             pass
+        finally:
+            _REQUEST_CPU.leave()    # the thread ends with its connection
 
     def _one_request(self) -> bool:
         """Read, dispatch and answer one request; False to hang up."""
@@ -567,7 +588,8 @@ class AsyncHTTPServer:
         self.daemon_threads = True   # lifecycle-surface parity (no-op)
         self._pipeline = _pipeline_window()
         self._executor = ThreadPoolExecutor(
-            max_workers=_executor_workers(), thread_name_prefix="pio-http")
+            max_workers=_executor_workers(), thread_name_prefix="pio-http",
+            initializer=_REQUEST_CPU.join)
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._stop_event: Optional[asyncio.Event] = None
         self._conns: set = set()
@@ -760,6 +782,7 @@ def make_server(api, host: str = "localhost", port: int = 0,
     (SSLConfiguration.scala role); pass tls=False to force plaintext.
     Both transports expose the same lifecycle surface
     (serve_forever/shutdown/server_close/server_address)."""
+    telemetry.registry().register_collector(_collect_cpu)
     if transport_mode(transport) == "async":
         return AsyncHTTPServer(api, host, port, tls=tls)
     handler = type("BoundHandler", (_Handler,), {"api": api})

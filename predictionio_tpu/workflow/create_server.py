@@ -31,8 +31,8 @@ import urllib.request
 from typing import Any, Dict, List, Optional, Tuple
 
 from predictionio_tpu.common import (
-    devicewatch, history, journal, resilience, slo, telemetry, tracing,
-    waterfall,
+    devicewatch, history, journal, profiling, resilience, slo, telemetry,
+    tracing, waterfall,
 )
 from predictionio_tpu.controller.engine import Engine, EngineParams
 from predictionio_tpu.controller.persistent_model import PersistentModelManifest
@@ -75,6 +75,15 @@ def _codec_status() -> Dict[str, Any]:
     return {**json_extractor.stats(), "replyChecks": {
         "folded": int(_M_REPLY_FOLDED.value),
         "walked": int(_M_REPLY_WALKED.value)}}
+
+
+def _host_status() -> Dict[str, Any]:
+    """`GET /`'s process-wide host seconds (common/profiling.py): the
+    counted spans of the batcher's lanes and the training phases
+    (`hostSpans`), and the process's CPU, threads and run-queue wait
+    (`host`)."""
+    return {"hostSpans": profiling.span_totals(),
+            "host": profiling.host_status()}
 
 
 #: distinguishes concurrently-live QueryAPI instances in the process
@@ -454,6 +463,9 @@ class QueryAPI:
         # device observability: compile watchdog + HBM/live-array gauges
         # on this daemon's /metrics and /debug/device.json (idempotent)
         devicewatch.install()
+        # the host's counted spans on /metrics, derived at scrape time
+        # (idempotent: the registry dedupes the callable)
+        telemetry.registry().register_collector(profiling.collect_spans)
         # SLO engine: this server's configured targets win over any
         # default install from a sibling daemon in the process
         slo.install(slo.SLOConfig.from_env(
@@ -1145,6 +1157,7 @@ class QueryAPI:
                            if batcher is not None else {"enabled": False})
         out["codec"] = _codec_status()
         out["transport"] = http_transport.transport_status()
+        out.update(_host_status())
         for m in self.models:
             # an engine's own block, only where that engine is deployed
             # (models/ecommerce: "ecomm", its rule reads and fallbacks;
@@ -1221,6 +1234,7 @@ class QueryAPI:
             "oversubscribed": self.registry.oversubscribed(),
             "codec": _codec_status(),
             "transport": http_transport.transport_status(),
+            **_host_status(),
         }
 
     def _readyz(self) -> Response:

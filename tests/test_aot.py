@@ -567,7 +567,7 @@ def test_pio_aot_0_wire_byte_identical(memory_storage, monkeypatch):
         "status", "engineInstance", "algorithms", "requestCount",
         "avgServingSec", "lastServingSec", "degradedCount", "draining",
         "serverStartTime", "generation", "batching", "codec",
-        "transport"}
+        "transport", "hostSpans", "host"}
     assert not devicewatch.serving_warmup_done()
     _, rz_off = api_off.handle("GET", "/readyz")
     assert "aotPrograms" not in rz_off

@@ -730,7 +730,8 @@ def test_codec_counters_on_status_and_metrics(trained):
 # and one write a reply), counted beside the codec
 # ---------------------------------------------------------------------------
 
-_TRANSPORT_KEYS = {"mode", "requests", "writes", "protocolErrors"}
+_TRANSPORT_KEYS = {"mode", "requests", "writes", "protocolErrors",
+                   "cpuSeconds"}
 
 
 @pytest.mark.parametrize("transport", ["threaded", "async"])

@@ -355,7 +355,8 @@ class TestMultiTenantDeploy:
             assert info["modelBytesTotal"] == sum(
                 t["modelBytes"] for t in info["tenants"].values())
             assert set(info["transport"]) == {
-                "mode", "requests", "writes", "protocolErrors"}
+                "mode", "requests", "writes", "protocolErrors", "cpuSeconds"}
+            assert {"hostSpans", "host"} <= set(info)
             status, ready = api.handle("GET", "/readyz")
             assert status == 200 and ready["status"] == "ready"
             assert ready["generations"] == {"a": 1, "b": 1, "c": 1}
@@ -547,7 +548,7 @@ def test_legacy_wire_shape_without_engines_conf(mt_trained):
             "status", "engineInstance", "algorithms", "requestCount",
             "avgServingSec", "lastServingSec", "degradedCount",
             "draining", "serverStartTime", "generation", "batching",
-            "aot", "codec", "transport"}
+            "aot", "codec", "transport", "hostSpans", "host"}
         status, ready = api.handle("GET", "/readyz")
         assert status == 200
         assert "generations" not in ready and "queueDepths" not in ready

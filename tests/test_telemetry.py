@@ -394,8 +394,8 @@ def test_batcher_stats_are_registry_backed(memory_storage):
                           "overlapped", "queries", "rejected",
                           "batchSizeHist",
                           "bucketHist", "avgQueueWaitMs", "avgFlushMs",
-                          "topkSelection", "layout", "shards",
-                          "perShardBytes"}
+                          "wakeSeconds", "lanes", "topkSelection", "layout",
+                          "shards", "perShardBytes"}
         assert b["queries"] == 3
         # the same numbers, straight from the registry instruments
         assert int(api._batcher._m_queries.value) == 3
@@ -746,7 +746,7 @@ def test_responses_byte_identical_with_telemetry_on_and_off(memory_storage):
             "status", "engineInstance", "algorithms", "requestCount",
             "avgServingSec", "lastServingSec", "degradedCount", "draining",
             "serverStartTime", "generation", "batching", "aot",
-            "codec", "transport"}
+            "codec", "transport", "hostSpans", "host"}
     finally:
         telemetry.set_enabled(None)
         api.close()
